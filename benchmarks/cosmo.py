@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import compile_program
+from repro.core.interpreters import resolve_interpret
 from repro.core.programs import cosmo_program, _ulap, _flux_x, _flux_y, _ustage
 from repro.core.unfused import build_unfused
 
@@ -45,7 +46,8 @@ def stella_like(u):
 PALLAS_MAX_ROWS = 96  # interpret mode unrolls the grid at trace time
 
 
-def run(sizes=((8, 64, 64), (16, 128, 128), (8, 256, 512)), interpret=True):
+def run(sizes=((8, 64, 64), (16, 128, 128), (8, 256, 512)), interpret=None):
+    interpret = resolve_interpret(interpret)
     prog = cosmo_program()
     gen = compile_program(prog, backend="jax")
     unfused = build_unfused(prog, per_pass_jit=True).fn      # leg A: autovec
@@ -79,8 +81,7 @@ def run(sizes=((8, 64, 64), (16, 128, 128), (8, 256, 512)), interpret=True):
             ),
         })
     # Pallas leg (single streamed (k, j) grid; bounded size off-TPU —
-    # interpret mode unrolls the grid at trace time, pass
-    # interpret=False on a TPU runtime)
+    # interpret mode unrolls the grid at trace time)
     nk, nj, ni = min(sizes)
     if interpret:
         nk, nj = min(nk, 4), min(nj, PALLAS_MAX_ROWS)

@@ -56,8 +56,7 @@ predictions can be compared against reality PR over PR
 (``scripts/bench_trend.py`` prints that trajectory).
 
 Off-TPU the legs run in interpret mode on bounded sizes (the grid
-unrolls at trace time); pass ``interpret=False`` on a TPU runtime for
-real timings, and feed measured split-schedule wins back into
+unrolls at trace time); on a TPU they compile for the chip.  Feed measured split-schedule wins back into
 ``repro.core.engine.register_pallas_split_win`` so ``backend="auto"``
 routes them to the stencil executor.
 
@@ -75,6 +74,7 @@ import json
 import sys
 import tempfile
 import time
+from typing import Optional
 
 import jax
 import numpy as np
@@ -82,6 +82,7 @@ import numpy as np
 from repro.core import (clear_compile_cache, compile_program, scan_plan,
                         sizes_from_arrays, vmem_bytes)
 from repro.core.codegen_jax import CodegenError
+from repro.core.interpreters import resolve_interpret
 from repro.core.programs import (cosmo_program, energy3d_program,
                                  heat3d_program,
                                  heat3d_residual_norm_program,
@@ -110,7 +111,8 @@ CASES = [
 ]
 
 
-def run(interpret: bool = True):
+def run(interpret: Optional[bool] = None):
+    interpret = resolve_interpret(interpret)
     rng = np.random.default_rng(7)
     rows = []
     for name, build, out, shape, dbuf in CASES:
@@ -172,7 +174,7 @@ INTERP_CASES = [
 ]
 
 
-def run_interpreters(interpret: bool = True):
+def run_interpreters(interpret: Optional[bool] = None):
     """Per-interpreter legs: every registered plan interpreter runs the
     same program, timed against the legacy fused-JAX emitter
     (``backend="jax"``) as the in-suite baseline — the cost of
@@ -181,6 +183,7 @@ def run_interpreters(interpret: bool = True):
     get a ``*_layout`` leg with the LayoutApply pass on (auto mode),
     bit-identity-checked against their untransformed leg and recorded
     with the post-transform analyzer summary."""
+    interpret = resolve_interpret(interpret)
     from repro.core.interpreters import (get_interpreter,
                                          registered_interpreters)
 
@@ -296,11 +299,10 @@ def main(argv=None) -> None:
     ap.add_argument("--json", action="store_true",
                     help="emit a machine-readable record (per-leg wall "
                          "time + backend) instead of the CSV rows")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="run with interpret=False (TPU runtimes only)")
     args = ap.parse_args(argv)
-    rows = run(interpret=not args.no_interpret)
-    interp_legs = run_interpreters(interpret=not args.no_interpret)
+    interpret = resolve_interpret(None)
+    rows = run(interpret=interpret)
+    interp_legs = run_interpreters(interpret=interpret)
     cache_legs = run_plan_cache()
     if args.json:
         legs = [{k: r[k] for k in ("name", "us_per_call", "backend",
@@ -317,7 +319,7 @@ def main(argv=None) -> None:
         import jaxlib
         import platform
         json.dump({"suite": "lifted",
-                   "interpret": not args.no_interpret,
+                   "interpret": interpret,
                    "env": {"jax": jax.__version__,
                            "jaxlib": jaxlib.__version__,
                            "python": platform.python_version()},

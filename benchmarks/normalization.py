@@ -10,14 +10,15 @@ that fall out of cache.
 A fourth leg drives the same split schedule through the Pallas stencil
 executor (``backend="pallas"``: two stencil calls with a carried
 accumulator).  Off-TPU it runs in interpret mode — grid steps unroll at
-trace time — so it is timed on a bounded size; on a TPU runtime pass
-``interpret=False`` for the streamed form."""
+trace time — so it is timed on a bounded size; on a TPU it compiles for
+the chip."""
 from __future__ import annotations
 
 import jax
 import numpy as np
 
 from repro.core import compile_program
+from repro.core.interpreters import resolve_interpret
 from repro.core.programs import normalization_program
 from repro.core.unfused import build_unfused
 
@@ -26,7 +27,8 @@ from .common import mk, pallas_leg_row, time_fn
 PALLAS_MAX_ROWS = 192  # interpret mode unrolls the grid at trace time
 
 
-def run(sizes=((256, 256), (1024, 1024), (4096, 2048)), interpret=True):
+def run(sizes=((256, 256), (1024, 1024), (4096, 2048)), interpret=None):
+    interpret = resolve_interpret(interpret)
     prog = normalization_program()
     gen = compile_program(prog, backend="jax")
     unfused = build_unfused(prog, per_pass_jit=True).fn     # leg A: autovec

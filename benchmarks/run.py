@@ -13,7 +13,11 @@ import sys
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+
     from . import cosmo, hydro, kernels_bench, lifted, normalization
+
+    enable_compile_cache()
 
     suites = [
         ("normalization", normalization.run),

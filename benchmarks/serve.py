@@ -18,6 +18,10 @@ heat3d by default):
   compile wall-clock, disk-hit count, plus steady-state requests/s
   and p50/p99 once warm.
 
+A TPU belongs to one process at a time, so this process never opens a
+JAX backend: the in-process legs run in one spawned child, which exits
+before the worker legs spawn theirs.
+
 ::
 
     PYTHONPATH=src python -m benchmarks.serve --json
@@ -30,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import multiprocessing as mp
 import sys
 import tempfile
 import time
@@ -131,9 +136,10 @@ def _worker_leg(name: str, sizes: dict, *, mode: str, cache_dir,
             "disk_hits": snap["compiles"]["disk_hits"]}
 
 
-def run(n_requests: int = 64, backend: str = "interp_jax") -> list:
-    """All serving legs: serial/batched per program, then cold/warm
-    worker starts per program over one shared cache dir each."""
+def _throughput_legs(n_requests: int, backend: str) -> list:
+    """Serial and batched legs for every program, in this process."""
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     legs = []
     for name, sizes in PROGRAMS:
         serial = _throughput_leg(name, sizes, mode="serial",
@@ -143,6 +149,17 @@ def run(n_requests: int = 64, backend: str = "interp_jax") -> list:
         batched["vs_serial"] = (batched["requests_per_s"]
                                 / serial["requests_per_s"])
         legs += [serial, batched]
+    return legs
+
+
+def run(n_requests: int = 64, backend: str = "interp_jax") -> list:
+    """All serving legs: serial/batched per program in one spawned
+    child, then cold/warm worker starts per program over one shared
+    cache dir each."""
+    with mp.get_context("spawn").Pool(1) as pool:
+        legs = pool.apply(_throughput_legs, (n_requests, backend))
+        pool.close()
+        pool.join()
     for name, sizes in PROGRAMS:
         with tempfile.TemporaryDirectory() as d:
             cold = _worker_leg(name, sizes, mode="cold", cache_dir=d,

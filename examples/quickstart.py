@@ -35,7 +35,9 @@ in_cell[j+1], in_cell[j+0], in_cell[j+0]] -> out:0
       goals: lap<-laplace_cell
     --- vmem estimate ---
       laplace5_n0:
-        in_cell: 3 x pad(Ni+0) x 4B
+        in_cell: sub(3) x pad(Ni+0) x 4B
+        stream cell: 2 x 8 x pad(Ni+0) x 4B
+        out laplace_cell: 2 x 8 x pad(Ni+0) x 4B
     --- vectorization ---
       access classes: aligned=2 shifted=4
       redundant-load ratio: 1.67
@@ -86,11 +88,11 @@ def main():
 
     # backend="pallas": the same schedule on the TPU stencil executor —
     # rolling buffers in VMEM, one streamed row per grid step.  Off-TPU
-    # we validate in interpret mode on a small grid (the grid unrolls at
-    # trace time); on a TPU runtime pass interpret=False, and
-    # double_buffer=True for the explicit two-slot input-DMA pipeline.
+    # it runs in interpret mode, so we validate on a small grid (the
+    # grid unrolls at trace time); on a TPU it compiles with Mosaic.
+    # double_buffer=True selects the explicit two-slot input-DMA pipeline.
     small = cell[:24, :]
-    gen_p = compile_program(prog, backend="pallas", interpret=True)
+    gen_p = compile_program(prog, backend="pallas")
     perr = float(jnp.abs(
         gen_p.fn(cell=small)["lap"]
         - build_unfused(prog).fn(cell=small)["lap"]).max())

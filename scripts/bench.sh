@@ -9,15 +9,12 @@
 # Without an argument the PR number is inferred as one past the number
 # of PR entries already recorded in CHANGES.md (i.e. "this PR").
 # Off-TPU the legs run in interpret mode on bounded sizes; on a TPU
-# runtime export BENCH_NO_INTERPRET=1 for real timings.
+# they compile for the chip.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 PR="${1:-$(($(grep -c '^- PR' CHANGES.md) + 1))}"
 FLAGS=(--json)
-if [[ "${BENCH_NO_INTERPRET:-0}" == "1" ]]; then
-    FLAGS+=(--no-interpret)
-fi
 LIFTED="$(mktemp)"
 SERVE="$(mktemp)"
 trap 'rm -f "$LIFTED" "$SERVE"' EXIT

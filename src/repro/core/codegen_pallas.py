@@ -621,12 +621,13 @@ class PallasGenerated:
 
 
 def generate_pallas(plan: StoragePlan, idag: IDAG, *, dtype=jnp.float32,
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     double_buffer: bool = False) -> PallasGenerated:
     """Plan + interpret: emit the Pallas execution of a storage plan.
 
-    ``interpret=True`` runs the kernel bodies on CPU for validation; on
-    a TPU runtime pass False.  ``double_buffer=True`` switches the
+    ``interpret=True`` runs the kernel bodies on CPU for validation;
+    the default ``None`` does so exactly where the default backend is
+    not a TPU.  ``double_buffer=True`` switches the
     interpreter's input streaming from BlockSpec row fetches to the
     explicit two-slot async-DMA pipeline (see
     :func:`repro.kernels.stencil2d.kernel.build_call`)."""
@@ -640,8 +641,8 @@ def generate_pallas(plan: StoragePlan, idag: IDAG, *, dtype=jnp.float32,
 
 
 def compile_program_pallas(
-    program: Program, *, dtype=jnp.float32, interpret: bool = True,
-    double_buffer: bool = False
+    program: Program, *, dtype=jnp.float32,
+    interpret: Optional[bool] = None, double_buffer: bool = False
 ) -> PallasGenerated:
     """Engine pipeline + Pallas emission (standalone entry point; prefer
     :func:`repro.core.engine.compile_program` with ``backend='pallas'``,

@@ -65,7 +65,8 @@ from .codegen_pallas import (PallasGenerated, PallasUnsupported,
 from .dataflow import build_dataflow
 from .fusion import fuse_inest_dag
 from .infer import infer
-from .interpreters import get_interpreter, registered_interpreters
+from .interpreters import (get_interpreter, registered_interpreters,
+                           resolve_interpret)
 from .layoutapply import render_apply, resolve_apply_mode
 from .layoutapply import apply_layout as run_layout_pass
 from .plan import KernelPlan
@@ -408,7 +409,7 @@ def compile_program(
     backend: str = "auto",
     *,
     dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     double_buffer: bool = False,
     use_cache: bool = True,
     plan_cache_dir=None,
@@ -421,7 +422,10 @@ def compile_program(
 
     ``interpret`` and ``double_buffer`` only affect the Pallas backend
     (CPU validation vs TPU execution, and BlockSpec streaming vs the
-    explicit two-slot DMA pipeline).  Results are memoized; pass
+    explicit two-slot DMA pipeline).  ``interpret=None`` resolves to
+    interpret mode exactly where the default backend is not a TPU
+    (:func:`repro.core.interpreters.resolve_interpret`), and the cache
+    keys hold the resolved value.  Results are memoized; pass
     ``use_cache=False`` to force a rebuild.
 
     ``plan_cache_dir`` names a durable on-disk plan cache
@@ -480,6 +484,7 @@ def compile_program(
                 f"unknown backend {backend!r}; expected 'auto', 'jax' or a "
                 f"registered interpreter: {registered_interpreters()}"
             ) from None
+    interpret = resolve_interpret(interpret)
     check = resolve_check_mode(check_plans)
     apply_mode = resolve_apply_mode(apply_layout)
     if plan_cache_dir is None:
@@ -622,7 +627,8 @@ def compile_batched(
     return BatchedGenerated(gen, fn, backend=backend, jitted=jit)
 
 
-def explain(program: Program, *, dtype=jnp.float32, interpret: bool = True,
+def explain(program: Program, *, dtype=jnp.float32,
+            interpret: Optional[bool] = None,
             double_buffer: bool = False, verbose: bool = False,
             dim_sizes=None, apply_layout: Optional[str] = None) -> str:
     """Human-readable transformation report (the paper's debugging output).
@@ -653,7 +659,8 @@ def explain(program: Program, *, dtype=jnp.float32, interpret: bool = True,
     idag, plan = _build_plan(program)
     schedule = plan.schedule
     dag = schedule.dag
-    gen = _pallas_auto_probe(plan, idag, dtype=dtype, interpret=interpret,
+    gen = _pallas_auto_probe(plan, idag, dtype=dtype,
+                             interpret=resolve_interpret(interpret),
                              double_buffer=double_buffer,
                              dim_sizes=dim_sizes)
     backend = "pallas" if gen is not None else "jax"

@@ -19,9 +19,9 @@ semantics exactly — clamped row/plane streaming (edge rows repeat
 during warm-up/drain), floor-mod slot rotation, predicated accumulator
 combines over rows *and* outer tiles, predicated absolute-row seating
 of producer planes, identity-filled output rows — so the output
-contract matches the Pallas ``build_call`` bit-for-bit in shape:
-row outputs ``(*grid, steps_j, ni)``, carried accumulators
-``(1, width)``, kept-prefix accumulators ``(*grid[:n_kept], width)``,
+contract matches the Pallas ``build_call`` (which pads its rows to a
+sublane tile): row outputs ``(*grid, steps_j, ni)``, carried accumulators
+``(1, width)``, kept-prefix accumulators ``(*grid[:n_kept], 1, width)``,
 and the shared host half
 (:func:`repro.core.interpreters.execute_plan`) assembles them with the
 identical trim/seat rules.
@@ -176,7 +176,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             if out.acc is not None:
                 a = acc_of[out.acc]
                 wa = acc_w[out.acc]
-                shape = (*gsz[:a.n_kept], wa) if a.n_kept else (1, wa)
+                shape = (*gsz[:a.n_kept], 1, wa)
             else:
                 shape = (*gsz, steps_j, out.lane_block or ni)
             st0[("out", oi)] = jnp.zeros(shape, dtype)
@@ -401,14 +401,10 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                     a = acc_of[out.acc]
                     row = st[("acc", out.acc)]
                     wa = acc_w[out.acc]
-                    if a.n_kept:
-                        st[("out", oi)] = lax.dynamic_update_slice(
-                            st[("out", oi)],
-                            row.reshape((1,) * a.n_kept + (wa,)),
-                            tuple(outer_ids[:a.n_kept]) + (0,))
-                    else:
-                        st[("out", oi)] = lax.dynamic_update_slice(
-                            st[("out", oi)], row.reshape(1, wa), (0, 0))
+                    st[("out", oi)] = lax.dynamic_update_slice(
+                        st[("out", oi)],
+                        row.reshape((1,) * (a.n_kept + 1) + (wa,)),
+                        tuple(outer_ids[:a.n_kept]) + (0, 0))
             return st
 
         st = lax.fori_loop(0, total_steps, body, st0)

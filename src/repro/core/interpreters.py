@@ -26,9 +26,10 @@ Two interpreters self-register on first use:
   legacy hand-written ``codegen_jax`` emitter on the plan-covered path.
 
 Every ``build_call`` must honor the **output contract** of the Pallas
-reference implementation — row outputs ``(*grid, steps_j, ni)``,
-carried accumulators ``(1, width)``, kept-prefix accumulators
-``(*grid[:n_kept], width)`` — because the host half here
+reference implementation — row outputs ``(*grid, rows, ni)`` with
+``rows >= steps_j`` (rows past ``steps_j`` are padding), carried
+accumulators ``(1, width)``, kept-prefix accumulators
+``(*grid[:n_kept], 1, width)`` — because the host half here
 (:func:`execute_plan`: size resolution through axiom shape contracts,
 environment threading, and the :func:`_assemble` trim/seat/lane-reduce
 rules) is shared by every interpreter verbatim.
@@ -42,13 +43,24 @@ from __future__ import annotations
 
 import importlib
 from dataclasses import dataclass, field
-from typing import Callable
+from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 
 from .plan import (PLAN_FEATURES, CallPlan, KernelPlan, OutputPlan,
                    PallasUnsupported)
 from .runtime import lane_reduce
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Resolve an ``interpret`` flag: ``None`` means interpret mode
+    exactly where JAX's default backend is not a TPU, so a TPU never
+    runs the CPU interpreter unless asked to.  An explicit bool stands
+    (``False`` compiles for a described TPU from a CPU host)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 class PlanUnsupported(PallasUnsupported):
@@ -322,8 +334,9 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
         else None
     if out.kind == "acc":
         if out.n_kept:
-            # (*kept grid tiles, width): one combined row per kept tile
-            part = padded[_outer_trim(out, call, n_outs, out.n_kept)]
+            # (*kept grid tiles, 1, width): one combined row per kept tile
+            part = padded[_outer_trim(out, call, n_outs, out.n_kept)
+                          + (0,)]
             if reduce_fn is not None:
                 part = lane_reduce(reduce_fn,
                                    jnp.moveaxis(part, -1, 0),
@@ -365,7 +378,7 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
 
 
 def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
-                 dtype=jnp.float32, interpret: bool = True,
+                 dtype=jnp.float32, interpret: Optional[bool] = None,
                  double_buffer: bool = False):
     """Build the host callable executing a full :class:`KernelPlan` on
     the named registered interpreter.
@@ -378,9 +391,11 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     threads intermediate arrays through the environment.  The capability
     check runs here, so a plan outside the interpreter's declared
     feature set raises :class:`PlanUnsupported` before anything builds.
-    ``interpret``/``double_buffer`` are forwarded to ``build_call``;
-    interpreters that don't honor a flag accept and ignore it."""
+    ``interpret`` (resolved by :func:`resolve_interpret`) and
+    ``double_buffer`` are forwarded to ``build_call``; interpreters that
+    don't honor a flag accept and ignore it."""
     spec = get_interpreter(interpreter)
+    interpret = resolve_interpret(interpret)
     check_capabilities(spec, kplan)
     dim_sym = dict(kplan.dim_sizes)
     inner = kplan.loop_order[-1]

@@ -30,9 +30,11 @@ Four analyses over a validated :class:`~repro.core.plan.KernelPlan`:
    graph (an interval dataflow fixpoint), so only positions that feed
    a kept output are constrained.
 3. **VMEM footprint estimate** — :func:`vmem_bytes` mirrors the
-   interpreter's scratch allocation (``build_call``'s shapes,
-   lane-padded) and warns above a configurable budget
-   (:data:`DEFAULT_VMEM_BUDGET`, ~16 MiB/core on TPU).
+   interpreter's VMEM allocation (``build_call``'s scratch shapes and
+   the pipeline's 8-row stream blocks, padded to (8, 128) tiles) and
+   warns above a configurable budget (:data:`DEFAULT_VMEM_BUDGET`, the
+   compiler's default scoped limit).  ``build_call`` passes a larger
+   scoped limit (:func:`scoped_vmem_limit`) to a kernel that needs it.
 4. **Dead-store / unused-window detection** — windows, locals,
    accumulators, and cross-call outputs written but never read
    downstream: exactly the storage-elision opportunities the paper
@@ -74,8 +76,13 @@ from typing import Optional, Sequence
 
 from .plan import CallPlan, KernelPlan, StepPlan, WindowPlan
 
-#: Default VMEM budget for PC003: ~16 MiB/core (TPU v4/v5 VMEM size).
+#: Default VMEM budget for PC003 and ``backend="auto"``: the Mosaic
+#: compiler's default scoped VMEM limit on TPU v5e (16 MiB).
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
+
+#: Physical VMEM of one TPU v5e TensorCore (128 MiB): the most a
+#: kernel's scoped limit may be raised to.
+VMEM_CAPACITY = 128 * 1024 * 1024
 
 #: Environment override for the PC003 budget (bytes).
 VMEM_BUDGET_ENV = "REPRO_VMEM_BUDGET_BYTES"
@@ -86,8 +93,10 @@ CHECK_MODES = ("off", "warn", "error")
 #: Environment override for the engine's default check mode.
 CHECK_PLANS_ENV = "REPRO_CHECK_PLANS"
 
-#: Interpreter lane width (kept in sync with kernels/stencil2d).
+#: Interpreter lane width and stream-block rows (kept in sync with
+#: kernels/stencil2d).
 LANE = 128
+SUBLANE = 8
 
 #: Fixpoint iteration clamp half-width: requirement intervals are
 #: bounded to the grid range widened by this many positions, so cyclic
@@ -188,6 +197,13 @@ def pad_to_lane(w: int) -> int:
 
 
 _pad_to_lane = pad_to_lane
+
+
+def _pad_rows(rows: int, dtype_bytes: int) -> int:
+    """Rows a VMEM buffer occupies: a multiple of the sublane tile
+    (8 rows of 32-bit values, more for narrower ones)."""
+    tile = SUBLANE * max(1, 4 // dtype_bytes)
+    return -(-rows // tile) * tile
 
 
 # ---------------------------------------------------------------------------
@@ -758,14 +774,20 @@ def _call_sizes(kplan: KernelPlan, call: CallPlan, sizes: dict):
     return tuple(vals)
 
 
-def _call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
-               double_buffer: bool) -> dict:
-    """Per-buffer resident bytes for one call, mirroring the
-    interpreter's scratch shapes (``build_call``): rolling windows
-    ``stages x pad(width)``, plane windows
-    ``p_stages x rows x pad(width)``, accumulators ``1 x pad(width)``,
-    plus the two-slot DMA staging buffers when double-buffered."""
+def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
+              double_buffer: bool) -> dict:
+    """Per-buffer VMEM bytes of one call, plus their ``"total"``,
+    mirroring the interpreter's allocation (``build_call``): rolling
+    windows ``stages x pad(width)``, plane windows
+    ``p_stages x rows x pad(width)``, accumulators one padded row, and
+    the two buffers of every 8-row stream block (array inputs, either
+    the pipeline's or the explicit DMA slots; row and accumulator
+    outputs).  Rows are padded to the sublane tile."""
     ib = int(dtype_bytes)
+
+    def rows(n):
+        return _pad_rows(n, ib)
+
     report: dict = {}
     arr_ins = [i for i in call.inputs if not i.scalar]
     for i in arr_ins:
@@ -773,26 +795,41 @@ def _call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
         if i.plane:
             in_h = nj + i.j_hi - i.j_lo
             report[f"in_{i.name}"] = \
-                i.p_stages * in_h * _pad_to_lane(in_w) * ib
+                i.p_stages * rows(in_h) * _pad_to_lane(in_w) * ib
         else:
             report[f"in_{i.name}"] = \
-                i.stages * _pad_to_lane(in_w) * ib
+                rows(i.stages) * _pad_to_lane(in_w) * ib
+        blk = "dma" if double_buffer else "blk"
+        report[f"{blk}_{i.name}"] = 2 * rows(SUBLANE) * _pad_to_lane(in_w) * ib
     for w in call.windows:
         width = _pad_to_lane(ni + w.i_hi - w.i_lo + w.align_pad)
         if w.plane:
-            report[w.name] = w.p_stages * (nj + w.j_hi - w.j_lo) \
+            report[w.name] = w.p_stages * rows(nj + w.j_hi - w.j_lo) \
                 * width * ib
         else:
-            report[w.name] = w.stages * width * ib
+            report[w.name] = rows(w.stages) * width * ib
     for a in call.accs:
-        report[a.name] = _pad_to_lane(ni + a.w_off) * ib
+        report[a.name] = rows(1) * _pad_to_lane(ni + a.w_off) * ib
     for v in call.vloads:
         report[f"vec:{v.name}"] = \
             (v.carry + 1) * _pad_to_lane(ni + v.w_off) * ib
-    if double_buffer and arr_ins:
-        for i in arr_ins:
-            report[f"dma_{i.name}"] = 2 * (ni + i.i_hi - i.i_lo) * ib
+    acc_w = {a.name: ni + a.w_off for a in call.accs}
+    for o in call.outputs:
+        width = acc_w[o.acc] if o.acc is not None else ni
+        report[f"out_{o.name}"] = 2 * rows(SUBLANE) * _pad_to_lane(width) * ib
+    report["total"] = sum(report.values())
     return report
+
+
+def scoped_vmem_limit(need: int) -> Optional[int]:
+    """The scoped VMEM limit to compile a kernel needing ``need`` bytes
+    with: ``None`` (the compiler's default) while ``need`` and a quarter
+    more for the compiler's own scratch fit :data:`DEFAULT_VMEM_BUDGET`,
+    else that amount, capped at :data:`VMEM_CAPACITY`."""
+    want = need + need // 4
+    if want <= DEFAULT_VMEM_BUDGET:
+        return None
+    return min(want, VMEM_CAPACITY)
 
 
 def vmem_report(kplan: KernelPlan, sizes: dict, *, dtype_bytes: int = 4,
@@ -809,9 +846,7 @@ def vmem_report(kplan: KernelPlan, sizes: dict, *, dtype_bytes: int = 4,
         if resolved is None:
             continue
         *_, nj, ni = resolved
-        rep = _call_vmem(call, nj, ni, dtype_bytes, double_buffer)
-        rep["total"] = sum(rep.values())
-        out[call.name] = rep
+        out[call.name] = call_vmem(call, nj, ni, dtype_bytes, double_buffer)
     return out
 
 
@@ -827,8 +862,9 @@ def vmem_bytes(kplan: KernelPlan, sizes: dict, *, dtype_bytes: int = 4,
 
 def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
     """Symbolic per-nest VMEM formulas for ``explain(verbose=True)``:
-    one line per resident buffer with the lane-padded shape algebra,
-    usable without concrete sizes."""
+    one line per resident buffer with the padded shape algebra
+    (``sub`` rounds rows up to the sublane tile, ``pad`` lanes up to
+    128), usable without concrete sizes."""
     lines: list[str] = []
     ib = int(dtype_bytes)
     for call in kplan.calls:
@@ -842,19 +878,28 @@ def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
             if i.plane:
                 lines.append(
                     f"    in_{i.name}: {i.p_stages} x "
-                    f"(Nj{i.j_hi - i.j_lo:+d}) x {w} x {ib}B")
+                    f"sub(Nj{i.j_hi - i.j_lo:+d}) x {w} x {ib}B")
             else:
-                lines.append(f"    in_{i.name}: {i.stages} x {w} x {ib}B")
+                lines.append(
+                    f"    in_{i.name}: sub({i.stages}) x {w} x {ib}B")
+            lines.append(f"    stream {i.name}: 2 x {SUBLANE} x {w} x {ib}B")
         for wp in call.windows:
             w = f"pad(Ni{wp.i_hi - wp.i_lo:+d})"
             if wp.plane:
                 lines.append(
                     f"    {wp.name}: {wp.p_stages} x "
-                    f"(Nj{wp.j_hi - wp.j_lo:+d}) x {w} x {ib}B")
+                    f"sub(Nj{wp.j_hi - wp.j_lo:+d}) x {w} x {ib}B")
             else:
-                lines.append(f"    {wp.name}: {wp.stages} x {w} x {ib}B")
+                lines.append(
+                    f"    {wp.name}: sub({wp.stages}) x {w} x {ib}B")
         for a in call.accs:
-            lines.append(f"    {a.name}: 1 x pad(Ni{a.w_off:+d}) x {ib}B")
+            lines.append(
+                f"    {a.name}: sub(1) x pad(Ni{a.w_off:+d}) x {ib}B")
+        w_off = {a.name: a.w_off for a in call.accs}
+        for o in call.outputs:
+            off = w_off[o.acc] if o.acc is not None else 0
+            lines.append(f"    out {o.name}: 2 x {SUBLANE} x "
+                         f"pad(Ni{off:+d}) x {ib}B")
     return lines
 
 

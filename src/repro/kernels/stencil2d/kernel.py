@@ -19,16 +19,17 @@ which is exactly the fused nest's traversal order — VMEM scratch
 therefore carries state both across rows *and* across outer-tile
 boundaries.  Each grid step:
 
-1. streams exactly one new row per array input from HBM into that
-   input's VMEM window — either through the BlockSpec index map (the DMA
-   runs ``lead`` rows ahead of the canonical point), or, with
-   ``double_buffer=True``, through an explicitly double-buffered
-   ``make_async_copy`` pair that prefetches the next grid step's row
-   while the current one is being consumed.  Inputs read at non-zero
-   offsets in the *plane dim* (the outer identifier adjacent to the row
-   dim — ``u[k-1][j][i]`` stencils) use a *multi-plane window* instead
-   of a rolling row window: ``(p_stages, rows, width)`` VMEM where whole
-   planes stay resident across outer tiles and the streamed row lands in
+1. takes exactly one new row per array input into that input's VMEM
+   window, ``lead`` rows ahead of the canonical point.  Rows arrive from
+   HBM in 8-row (sublane-tile) groups that consecutive grid steps
+   revisit — Mosaic refuses one-row blocks — either through the
+   BlockSpec index map, or, with ``double_buffer=True``, through an
+   explicitly double-buffered ``make_async_copy`` pair that prefetches
+   the next group while the current rows are being consumed.  Inputs
+   read at non-zero offsets in the *plane dim* (the outer identifier
+   adjacent to the row dim — ``u[k-1][j][i]`` stencils) use a
+   *multi-plane window* instead of a rolling row window:
+   ``(p_stages, rows, width)`` VMEM where whole planes stay resident across outer tiles and the streamed row lands in
    the newest plane, ``p_lead`` tiles ahead (Fig. 9a/9b applied one loop
    level further out);
 2. executes every fused step at its software-pipeline lead, reading
@@ -48,9 +49,11 @@ boundaries.  Each grid step:
    kept-prefix tile (:attr:`~repro.core.plan.AccPlan.n_kept`); row-kept
    reductions carry nothing and emit one identity-padded partial row per
    step instead;
-3. writes one row per terminal output back to HBM; accumulator outputs
-   are dumped into a revisited block whose final grid step (per kept
-   tile) holds the fully-combined partial-accumulator row.
+3. writes one row per terminal output into its revisited 8-row output
+   block (the fill, then the value at its static lane offset), which
+   goes back to HBM when the grid moves to the next block; accumulator
+   outputs are dumped into a revisited block whose final grid step
+   (per kept tile) holds the fully-combined partial-accumulator row.
 
 Rolling windows are padded to the 128-wide TPU lane tile (the
 vector-length expansion of Fig. 9c).  Warm-up/drain grid steps compute
@@ -71,6 +74,10 @@ rules dictate.
 """
 from __future__ import annotations
 
+import functools
+import operator
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -78,9 +85,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.interpreters import (InterpreterSpec, register_interpreter,
                                   require_hazard_free, require_linked_fns)
-from ...core.plan import PLAN_FEATURES, CallPlan, KernelPlan, WindowPlan
+from ...core.plan import (PLAN_FEATURES, CallPlan, KernelPlan,
+                          PallasUnsupported, WindowPlan)
+from ...core.plancheck import VMEM_CAPACITY, call_vmem, scoped_vmem_limit
 
 LANE = 128
+#: Rows per streamed block: one f32 sublane tile.  Mosaic accepts a
+#: block only when its last two dims are multiples of (8, 128) or equal
+#: to the array's, so rows move between HBM and VMEM in 8-row groups
+#: that consecutive grid steps revisit.
+SUBLANE = 8
 
 
 def _pad_to_lane(w: int) -> int:
@@ -101,15 +115,25 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     outer extents (``(Nj, Ni)`` for a plain 2-D nest).  Returns
     ``(fn, steps_j)``; the call maps the input arrays to one padded
     output per ``call.outputs`` entry (a list when there are several).
-    Row-output row ``t`` holds iteration position ``t + x_lo + out.lead``;
-    carried-accumulator outputs are ``(1, width)`` and per-outer
-    accumulator outputs ``(*outer_sizes, width)``.
+    Row outputs are ``(*grid, rows, ni)`` with ``rows`` the grid's
+    ``steps_j`` rounded up to a whole sublane tile; row ``t`` holds
+    iteration position ``t + x_lo + out.lead``.  Carried-accumulator
+    outputs are ``(1, width)`` and kept-prefix accumulator outputs
+    ``(*kept grid, 1, width)``.
 
-    ``double_buffer=True`` replaces the BlockSpec row streaming with an
+    Each grid step computes one row.  Array inputs and row outputs move
+    in 8-row blocks that consecutive grid steps revisit: the step reads
+    (or writes) its row at ``row % 8`` inside the block.
+    ``double_buffer=True`` replaces the BlockSpec input streaming with an
     explicit two-slot async-DMA pipeline: array inputs stay in HBM
-    (``memory_space=ANY``) and each grid step waits on the row DMA
-    issued by the previous step while kicking off the copy for the next
-    one, so the input DMA overlaps the compute of the current row."""
+    (``memory_space=ANY``); whenever the next grid step needs a new
+    8-row group, the current step starts its copy into the other slot,
+    so the input DMA overlaps the compute of the current rows.
+
+    The VMEM the call needs (:func:`repro.core.plancheck.call_vmem`) is
+    passed on as the compiler's scoped VMEM limit when it exceeds the
+    default; a call that cannot fit the core's VMEM is refused with
+    :class:`~repro.core.plan.PallasUnsupported`."""
     n_out = call.n_outer
     if len(sizes) != n_out + 2:
         raise ValueError(
@@ -122,6 +146,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     o_hi = call.outer_hi_off
     gsz = [outer_sizes[d] + o_hi[d] - o_lo[d] for d in range(n_out)]
     steps_j = (nj + call.x_hi_off) - call.x_lo
+    out_rows = pl.cdiv(steps_j, SUBLANE) * SUBLANE
     total_steps = steps_j
     for s in gsz:
         total_steps *= s
@@ -139,6 +164,12 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     ispec_of = {i.name: i for i in arr_ins}
     in_h = {i.name: nj + (i.j_hi - i.j_lo) for i in arr_ins}
     in_w = {i.name: ni + (i.i_hi - i.i_lo) for i in arr_ins}
+    # rows per streamed input block (the whole array when it is shorter)
+    rb = {i.name: min(SUBLANE, in_h[i.name]) for i in arr_ins}
+    # lanes per explicit DMA: Mosaic copies whole lane tiles, reaching
+    # into the lane padding of the TPU's tiled HBM layout, which the
+    # interpreter's arrays do not have
+    dma_w = {n: w if interpret else _pad_to_lane(w) for n, w in in_w.items()}
     n_scratch = len(roll_wins) + len(plane_ins) + len(plane_wins) \
         + len(call.accs)
 
@@ -165,6 +196,34 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             idxs.append(jnp.clip(p - ilos[li], 0, n_planes - 1))
         return idxs
 
+    def _group(ispec, pos_outer, j_id):
+        """Where ``ispec``'s row for one grid step sits in HBM: its outer
+        source indices, the first row of its ``rb``-row group and the
+        row's offset inside the group.  On the TPU groups start on a
+        sublane tile, so the last one of an array whose rows are not a
+        multiple of 8 reaches into the tile padding, as the BlockSpec
+        pipeline's own ragged last block does; the interpreter, whose
+        arrays have no padding, shifts that group up instead."""
+        r = _row_pos(ispec, j_id + call.x_lo)
+        n = rb[ispec.name]
+        start = r - jax.lax.rem(r, n)
+        if interpret:
+            start = jnp.minimum(start, in_h[ispec.name] - n)
+        else:
+            start = pl.multiple_of(start, SUBLANE)
+        return _outer_src(ispec, pos_outer), start, r - start
+
+    def _decode(lin):
+        """Canonical outer positions and row-grid index of linear grid
+        step ``lin`` (TPU grids run with the last dimension fastest)."""
+        j_id = jax.lax.rem(lin, steps_j)
+        rest = jax.lax.div(lin, steps_j)
+        pos = [None] * n_out
+        for d in reversed(range(n_out)):
+            pos[d] = jax.lax.rem(rest, gsz[d]) + o_lo[d]
+            rest = jax.lax.div(rest, gsz[d])
+        return pos, j_id
+
     def kernel(*refs):
         nin = len(call.inputs)
         in_refs = refs[:nin]
@@ -178,12 +237,6 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         acc_of = {a.name: (r, a) for r, a in zip(
             scratch[len(roll_wins) + len(plane_ins) + len(plane_wins):],
             call.accs)}
-        dma_stage = {
-            i.name: r for i, r in zip(
-                arr_ins, scratch[n_scratch:n_scratch + len(arr_ins)])
-        } if double_buffer else {}
-        dma_sems = (scratch[n_scratch + len(arr_ins)]
-                    if double_buffer and arr_ins else None)
 
         outer_ids = [pl.program_id(d) for d in range(n_out)]
         opos = [outer_ids[d] + o_lo[d] for d in range(n_out)]
@@ -196,24 +249,15 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             the row at its absolute array index inside the newest plane
             (``p_lead`` tiles ahead, mod-``p_stages`` plane slot)."""
             if ispec.plane:
-                pref = plane_of[ispec.name]
                 slot = _mod(pos_outer[n_out - 1] + ispec.p_lead,
                             ispec.p_stages)
-                r_idx = _row_pos(ispec, xx)
-                pl.store(
-                    pref,
-                    (pl.dslice(slot, 1), pl.dslice(r_idx, 1),
-                     pl.dslice(0, in_w[ispec.name])),
-                    row[None, None, :],
-                )
+                plane_of[ispec.name][
+                    slot, _row_pos(ispec, xx),
+                    pl.ds(0, in_w[ispec.name])] = row
             else:
                 ref, w = ref_of[f"in_{ispec.name}"]
-                pl.store(
-                    ref,
-                    (pl.dslice(_mod(xx + ispec.lead, w.stages), 1),
-                     pl.dslice(0, bwidth[w.name])),
-                    row[None, :],
-                )
+                ref[_mod(xx + ispec.lead, w.stages),
+                    pl.ds(0, bwidth[w.name])] = row
 
         # 0. identity-initialize accumulators: carried accumulators
         # (n_kept == 0) once on the very first grid step, kept-prefix
@@ -230,58 +274,75 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
 
         # 1. stream one new row per array input into its VMEM window
         if double_buffer and arr_ins:
-            # Linear grid-step odometer: TPU grids run sequentially with
-            # the last dimension fastest, so `lin` enumerates steps in
-            # execution order and `lin + 1` is the next step to prefetch.
+            dma_stage = dict(zip(
+                (i.name for i in arr_ins),
+                scratch[n_scratch:n_scratch + len(arr_ins)]))
+            dma_sems = scratch[n_scratch + len(arr_ins)]
+            cur_slot = scratch[n_scratch + len(arr_ins) + 1]
+            # Linear grid-step odometer: `lin` enumerates steps in
+            # execution order; the neighbours decide whether the row
+            # group changes at this step (wait) or at the next (prefetch).
             lin = jid
             mult = steps_j
             for d in reversed(range(n_out)):
                 lin = lin + outer_ids[d] * mult
                 mult *= gsz[d]
-            nxt = lin + 1
-            nxt_j = jax.lax.rem(nxt, steps_j)
-            rest = jax.lax.div(nxt, steps_j)
-            nxt_outer = [None] * n_out
-            for d in reversed(range(n_out)):
-                nxt_outer[d] = jax.lax.rem(rest, gsz[d])
-                rest = jax.lax.div(rest, gsz[d])
-            nxt_pos = [nxt_outer[d] + o_lo[d] for d in range(n_out)]
-            slot = _mod(lin, 2)
+            prv = _decode(jnp.maximum(lin - 1, 0))
+            nxt = _decode(jnp.minimum(lin + 1, total_steps - 1))
 
-            def _copy(ai, ispec, pos_outer, j_id, to_slot):
-                """The row DMA descriptor for one input at one grid step
-                (start and wait must agree on shape)."""
-                pos = _row_pos(ispec, j_id + call.x_lo)
-                src = in_refs[ref_idx[ispec.name]]
-                src_idx = tuple(pl.ds(i, 1)
-                                for i in _outer_src(ispec, pos_outer))
-                src_idx += (pl.ds(pos, 1), slice(None))
+            def _copy(ai, ispec, group, to_slot):
+                """The group DMA descriptor for one input (start and
+                wait must agree on shape)."""
+                idx, start, _ = group
+                src_idx = tuple(pl.ds(i, 1) for i in idx)
+                src_idx += (pl.ds(start, rb[ispec.name]),
+                            pl.ds(0, dma_w[ispec.name]))
                 return pltpu.make_async_copy(
-                    src.at[src_idx],
-                    dma_stage[ispec.name].at[pl.ds(to_slot, 1)],
+                    in_refs[ref_idx[ispec.name]].at[src_idx],
+                    dma_stage[ispec.name].at[to_slot],
                     dma_sems.at[ai, to_slot],
                 )
 
-            @pl.when(lin == 0)
-            def _prime():
-                for ai, ispec in enumerate(arr_ins):
-                    _copy(ai, ispec, opos, jid, slot).start()
+            def _moved(g0, g1):
+                """Whether two steps' row groups differ."""
+                return functools.reduce(
+                    operator.or_,
+                    [a != b for a, b in zip(g0[0], g1[0])] + [g0[1] != g1[1]])
 
             for ai, ispec in enumerate(arr_ins):
-                a_out = ispec.n_outer
-                _copy(ai, ispec, opos, jid, slot).wait()
-                row = dma_stage[ispec.name][
-                    (slot,) + (0,) * a_out + (slice(None),)]
-                _store_window(ispec, row, opos, x)
+                here = _group(ispec, opos, jid)
+                fresh = (lin == 0) | _moved(here, _group(ispec, *prv))
 
-            @pl.when(nxt < total_steps)
-            def _prefetch():
-                for ai, ispec in enumerate(arr_ins):
-                    _copy(ai, ispec, nxt_pos, nxt_j, 1 - slot).start()
+                @pl.when(lin == 0)
+                def _prime(_ai=ai, _sp=ispec, _g=here):
+                    cur_slot[_ai] = 0
+                    _copy(_ai, _sp, _g, 0).start()
+
+                @pl.when((lin > 0) & fresh)
+                def _flip(_ai=ai):
+                    cur_slot[_ai] = 1 - cur_slot[_ai]
+
+                slot = cur_slot[ai]
+
+                @pl.when(fresh)
+                def _wait(_ai=ai, _sp=ispec, _g=here, _s=slot):
+                    _copy(_ai, _sp, _g, _s).wait()
+
+                ahead = _group(ispec, *nxt)
+
+                @pl.when((lin + 1 < total_steps) & _moved(ahead, here))
+                def _prefetch(_ai=ai, _sp=ispec, _g=ahead, _s=slot):
+                    _copy(_ai, _sp, _g, 1 - _s).start()
+
+                row = dma_stage[ispec.name][
+                    (slot,) + (0,) * ispec.n_outer
+                    + (here[2], pl.ds(0, in_w[ispec.name]))]
+                _store_window(ispec, row, opos, x)
         else:
             for ispec in arr_ins:
                 src = in_refs[ref_idx[ispec.name]]
-                row = src[(0,) * (ispec.n_outer + 1)]
+                r = jax.lax.rem(_row_pos(ispec, x), rb[ispec.name])
+                row = src[(0,) * ispec.n_outer + (r, slice(None))]
                 _store_window(ispec, row, opos, x)
 
         # 2. fused steps, in dataflow order, at their leads
@@ -291,8 +352,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             cur = None
             if step.acc is not None:
                 aref, _ = acc_of[step.acc]
-                wa = acc_w[step.acc]
-                cur = pl.load(aref, (pl.dslice(0, 1), pl.dslice(0, wa)))[0]
+                cur = aref[0, pl.ds(0, acc_w[step.acc])]
                 ins.append(cur)
             for rd in step.reads:
                 w = ni + rd.w_off
@@ -308,16 +368,11 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                     # streamed plane-window read: plane slot by mod-stage
                     # rotation in the plane dim, absolute row inside it
                     ispec = ispec_of[rd.src[3:]]
-                    pref = plane_of[ispec.name]
                     slot = _mod(opos[n_out - 1] + rd.p_off, ispec.p_stages)
                     r_idx = jnp.clip(x + rd.j_off - ispec.j_lo, 0,
                                      in_h[ispec.name] - 1)
-                    ins.append(
-                        pl.load(pref, (pl.dslice(slot, 1),
-                                       pl.dslice(r_idx, 1),
-                                       pl.dslice(rd.col0 - ispec.i_lo, w))
-                                )[0, 0]
-                    )
+                    ins.append(plane_of[ispec.name][
+                        slot, r_idx, pl.ds(rd.col0 - ispec.i_lo, w)])
                 elif rd.src in pwin_of:
                     # producer plane-window read: older planes resident,
                     # rows addressed absolutely (clamped on warm-up)
@@ -325,19 +380,12 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                     slot = _mod(opos[n_out - 1] + rd.p_off, pw.p_stages)
                     r_idx = jnp.clip(x + rd.j_off - pw.j_lo, 0,
                                      win_h[pw.name] - 1)
-                    ins.append(
-                        pl.load(pref, (pl.dslice(slot, 1),
-                                       pl.dslice(r_idx, 1),
-                                       pl.dslice(rd.col0 - pw.i_lo, w))
-                                )[0, 0]
-                    )
+                    ins.append(pref[slot, r_idx,
+                                    pl.ds(rd.col0 - pw.i_lo, w)])
                 else:
                     ref, b = ref_of[rd.src]
                     stage = _mod(x + rd.j_off, b.stages)
-                    ins.append(
-                        pl.load(ref, (pl.dslice(stage, 1),
-                                      pl.dslice(rd.col0 - b.i_lo, w)))[0]
-                    )
+                    ins.append(ref[stage, pl.ds(rd.col0 - b.i_lo, w)])
             vals = call.fns[step.fn_idx](*ins)
             if step.acc is not None:
                 # predicated combine: warm-up/drain rows *and* tiles
@@ -347,10 +395,8 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                 ok = (pos >= lo) & (pos < nj + hi)
                 for d, (vlo, vhi) in enumerate(step.valid_outer):
                     ok &= (opos[d] >= vlo) & (opos[d] < outer_sizes[d] + vhi)
-                new = jnp.where(ok, vals, cur)
                 aref, _ = acc_of[step.acc]
-                pl.store(aref, (pl.dslice(0, 1), pl.dslice(0, acc_w[step.acc])),
-                         new[None, :])
+                aref[0, pl.ds(0, acc_w[step.acc])] = jnp.where(ok, vals, cur)
                 continue
             if len(step.writes) == 1:
                 vals = (vals,)
@@ -370,29 +416,20 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                         @pl.when((r_idx >= 0) & (r_idx < win_h[pw.name]))
                         def _seat(_p=pref, _s=slot, _r=r_idx, _v=val,
                                   _c=step.out_col0 - pw.i_lo):
-                            pl.store(
-                                _p,
-                                (pl.dslice(_s, 1), pl.dslice(_r, 1),
-                                 pl.dslice(_c, _v.shape[0])),
-                                _v[None, None, :],
-                            )
+                            _p[_s, _r, pl.ds(_c, _v.shape[0])] = _v
                     elif wkind == "buf":
                         ref, b = ref_of[str(wtgt)]
                         stage = _mod(x + step.lead, b.stages)
-                        pl.store(
-                            ref,
-                            (pl.dslice(stage, 1),
-                             pl.dslice(step.out_col0 - b.i_lo, val.shape[0])),
-                            val[None, :],
-                        )
-                    else:  # 3. one output row for this grid step
-                        out_row = jnp.full(
-                            (ni,), call.outputs[int(wtgt)].fill, val.dtype)
-                        out_row = jax.lax.dynamic_update_slice(
-                            out_row, val, (step.out_col0,)
-                        )
+                        ref[stage, pl.ds(step.out_col0 - b.i_lo,
+                                         val.shape[0])] = val
+                    else:  # 3. one output row for this grid step: the
+                        # fill, then the value at its static lane offset
                         oref = o_refs[int(wtgt)]
-                        oref[(0,) * (n_out + 1) + (slice(None),)] = out_row
+                        orow = (0,) * n_out + (jax.lax.rem(jid, SUBLANE),)
+                        oref[orow + (slice(None),)] = jnp.full(
+                            (ni,), call.outputs[int(wtgt)].fill, val.dtype)
+                        oref[orow + (pl.ds(step.out_col0, val.shape[0]),)] \
+                            = val
 
         # 3b. dump accumulators into their revisited output blocks: the
         # final grid step (per kept tile for kept-prefix accumulators)
@@ -400,12 +437,8 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         for oi, out in enumerate(call.outputs):
             if out.acc is not None:
                 aref, a = acc_of[out.acc]
-                wa = acc_w[out.acc]
-                row = pl.load(aref, (pl.dslice(0, 1), pl.dslice(0, wa)))[0]
-                if a.n_kept:
-                    o_refs[oi][(0,) * a.n_kept + (slice(None),)] = row
-                else:
-                    o_refs[oi][0, :] = row
+                row = aref[0, pl.ds(0, acc_w[out.acc])]
+                o_refs[oi][(0,) * (a.n_kept + 1) + (slice(None),)] = row
 
     grid = (*gsz, steps_j)
     in_specs = []
@@ -413,36 +446,34 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     out_shape = []
     for ispec in call.inputs:
         if ispec.scalar:
-            in_specs.append(pl.BlockSpec((1, 1), lambda *ids: (0, 0)))
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
             continue
         if double_buffer:
-            in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
             continue
         in_specs.append(pl.BlockSpec(
-            (1,) * (ispec.n_outer + 1) + (in_w[ispec.name],),
+            (1,) * ispec.n_outer + (rb[ispec.name], in_w[ispec.name]),
             (lambda *ids, _sp=ispec:
              tuple(_outer_src(_sp, [ids[d] + o_lo[d] for d in range(n_out)]))
-             + (_row_pos(_sp, ids[n_out] + call.x_lo), 0)),
+             + (_row_pos(_sp, ids[n_out] + call.x_lo) // rb[_sp.name], 0)),
         ))
     for out in call.outputs:
         if out.acc is not None:
             a = next(a for a in call.accs if a.name == out.acc)
             wa = acc_w[out.acc]
-            if a.n_kept:
-                out_specs.append(pl.BlockSpec(
-                    (1,) * a.n_kept + (wa,),
-                    lambda *ids, _k=a.n_kept: tuple(ids[:_k]) + (0,)))
-                out_shape.append(
-                    jax.ShapeDtypeStruct((*gsz[:a.n_kept], wa), dtype))
-            else:
-                out_specs.append(pl.BlockSpec((1, wa), lambda *ids: (0, 0)))
-                out_shape.append(jax.ShapeDtypeStruct((1, wa), dtype))
+            k = a.n_kept
+            out_specs.append(pl.BlockSpec(
+                (1,) * k + (1, wa),
+                lambda *ids, _k=k: tuple(ids[:_k]) + (0, 0)))
+            out_shape.append(
+                jax.ShapeDtypeStruct((*gsz[:k], 1, wa), dtype))
         else:
             out_specs.append(pl.BlockSpec(
-                (1,) * (n_out + 1) + (ni,),
-                lambda *ids: tuple(ids) + (0,)))
+                (1,) * n_out + (SUBLANE, ni),
+                lambda *ids: tuple(ids[:n_out])
+                + (ids[n_out] // SUBLANE, 0)))
             out_shape.append(
-                jax.ShapeDtypeStruct((*gsz, steps_j, ni), dtype))
+                jax.ShapeDtypeStruct((*gsz, out_rows, ni), dtype))
 
     scratch_shapes = [
         pltpu.VMEM((w.stages, _pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
@@ -461,10 +492,18 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     ]
     if double_buffer and arr_ins:
         scratch_shapes += [
-            pltpu.VMEM((2,) + (1,) * i.n_outer + (in_w[i.name],), dtype)
+            pltpu.VMEM((2,) + (1,) * i.n_outer + (rb[i.name], dma_w[i.name]),
+                       dtype)
             for i in arr_ins
         ]
         scratch_shapes.append(pltpu.SemaphoreType.DMA((len(arr_ins), 2)))
+        scratch_shapes.append(pltpu.SMEM((len(arr_ins),), jnp.int32))
+    need = call_vmem(call, nj, ni, jnp.dtype(dtype).itemsize,
+                     double_buffer)["total"]
+    if need > VMEM_CAPACITY:
+        raise PallasUnsupported(
+            f"call {call.name} needs {need} B of VMEM at sizes {sizes}; "
+            f"a TPU core has {VMEM_CAPACITY} B")
     fn = pl.pallas_call(
         kernel,
         grid=grid,
@@ -472,7 +511,13 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
         scratch_shapes=scratch_shapes,
+        # VMEM scratch carries state across every grid dim, so no dim
+        # may be split across cores
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=scoped_vmem_limit(need)),
         interpret=interpret,
+        name=f"hfav_{call.name}",
     )
     return fn, steps_j
 
@@ -485,7 +530,8 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
 # ---------------------------------------------------------------------------
 
 def execute_plan(kplan: KernelPlan, *, dtype=jnp.float32,
-                 interpret: bool = True, double_buffer: bool = False):
+                 interpret: Optional[bool] = None,
+                 double_buffer: bool = False):
     """Build the host callable executing a full :class:`KernelPlan` on
     the Pallas stencil interpreter.
 
@@ -493,9 +539,11 @@ def execute_plan(kplan: KernelPlan, *, dtype=jnp.float32,
     (:func:`repro.core.interpreters.execute_plan` with
     ``interpreter="pallas"``): the returned function takes the
     program's external arrays as keyword arguments and returns
-    ``{store name: array}`` for every goal.  ``interpret=True`` runs
-    kernel bodies on CPU for validation; ``double_buffer=True`` selects
-    the explicit two-slot async-DMA input pipeline."""
+    ``{store name: array}`` for every goal.  ``interpret`` defaults to
+    interpret mode exactly where the default backend is not a TPU
+    (:func:`repro.core.interpreters.resolve_interpret`);
+    ``double_buffer=True`` selects the explicit two-slot async-DMA input
+    pipeline."""
     from ...core.interpreters import execute_plan as _execute_plan
     return _execute_plan(kplan, interpreter="pallas", dtype=dtype,
                          interpret=interpret, double_buffer=double_buffer)

@@ -15,12 +15,19 @@ Programs cross the process boundary *by name* (resolved against
 :data:`repro.core.programs.ALL_PROGRAMS` inside the child), because
 kernel rule callables are not reliably picklable; the spawn context is
 used unconditionally so workers never inherit a forked JAX runtime.
+
+A TPU belongs to one process at a time, and a process that opens the
+TPU backend takes every chip of its host.  So a worker is never started
+from a process that holds the TPU, and a pool of more than one worker on
+a TPU host is refused with an error instead of leaving the later
+workers to fail or hang on the chip.
 """
 from __future__ import annotations
 
 import multiprocessing as mp
 import os
 import pathlib
+import sys
 from typing import Optional
 
 
@@ -36,23 +43,42 @@ def _ensure_child_pythonpath() -> None:
             [src] + [p for p in parts if p])
 
 
+def _holds_tpu() -> bool:
+    """Whether this process has opened JAX's TPU backend (and with it
+    every chip of the host).  A process that has not imported JAX, or
+    has not initialized a backend, holds nothing."""
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized() \
+        and jax.default_backend() == "tpu"
+
+
 def _worker_main(conn, program_names, backend, cache_dir, quantum,
                  max_batch, max_wait_ms) -> None:
     """Child entry point: build a PlanServe over the named programs and
     answer ``("serve", name, arrays)`` / ``("metrics",)`` / ``("stop",)``
     messages until stopped.  Every reply is a ``(tag, payload)`` pair;
     request failures reply ``("error", message)`` instead of killing
-    the worker."""
+    the worker.  The ready message reports the pid and the devices the
+    child opened."""
     import traceback
 
+    import jax
+
     from repro.core.programs import ALL_PROGRAMS
+    from repro.jaxcache import enable_compile_cache
     from repro.serve.plans import PlanServe
     try:
+        enable_compile_cache()
         progs = {n: ALL_PROGRAMS[n]() for n in program_names}
         with PlanServe(progs, backend=backend, plan_cache_dir=cache_dir,
                        quantum=quantum, max_batch=max_batch,
                        max_wait_ms=max_wait_ms) as srv:
-            conn.send(("ready", os.getpid()))
+            conn.send(("ready", {"pid": os.getpid(),
+                                 "platform": jax.default_backend(),
+                                 "device_count": jax.device_count()}))
             while True:
                 msg = conn.recv()
                 if msg[0] == "stop":
@@ -77,11 +103,17 @@ def _worker_main(conn, program_names, backend, cache_dir, quantum,
 class ServeWorker:
     """One spawned serving process.  ``serve``/``metrics`` are
     synchronous request/reply over the pipe; ``close`` stops the child
-    and returns its final metrics snapshot."""
+    and returns its final metrics snapshot.  ``platform`` and
+    ``device_count`` are what the child's JAX opened."""
 
     def __init__(self, program_names, *, backend: str = "interp_jax",
                  cache_dir=None, quantum: int = 32, max_batch: int = 16,
                  max_wait_ms: float = 2.0):
+        if _holds_tpu():
+            raise RuntimeError(
+                "this process holds the TPU, so a spawned worker could "
+                "not open it: start ServeWorkers before touching JAX in "
+                "the parent, or serve in-process with PlanServe")
         _ensure_child_pythonpath()
         ctx = mp.get_context("spawn")
         self._conn, child = ctx.Pipe()
@@ -96,7 +128,9 @@ class ServeWorker:
         tag, payload = self._conn.recv()
         if tag != "ready":
             raise RuntimeError(f"worker failed to start: {payload}")
-        self.pid = payload
+        self.pid = payload["pid"]
+        self.platform = payload["platform"]
+        self.device_count = payload["device_count"]
 
     def _rpc(self, *msg):
         self._conn.send(msg)
@@ -143,13 +177,24 @@ class WorkerPool:
     """``n`` ServeWorkers over one shared cache dir, with round-robin
     request dispatch.  ``close`` returns every worker's final metrics
     snapshot (the benchmark aggregates compile/disk-hit counts across
-    the pool)."""
+    the pool).
+
+    The first worker starts alone.  If it opened a TPU, it holds every
+    chip of the host, so a pool of more than one worker is refused with
+    a ``RuntimeError`` before any other worker starts."""
 
     def __init__(self, n: int, program_names, **kwargs):
         if n < 1:
             raise ValueError(f"need at least one worker, got {n}")
-        self.workers = [ServeWorker(program_names, **kwargs)
-                        for _ in range(n)]
+        first = ServeWorker(program_names, **kwargs)
+        if n > 1 and first.platform == "tpu":
+            first.close()
+            raise RuntimeError(
+                f"WorkerPool(n={n}) on a TPU host: worker 0 holds all "
+                f"{first.device_count} chip(s) of the host, so no other "
+                f"worker could open one; run one worker per TPU host")
+        self.workers = [first] + [ServeWorker(program_names, **kwargs)
+                                  for _ in range(n - 1)]
         self._next = 0
 
     def serve(self, name: str, arrays: dict) -> dict:

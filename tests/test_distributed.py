@@ -24,7 +24,8 @@ def test_pipeline_parallel_matches_sequential():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import pipeline_apply
-mesh = jax.make_mesh((4,), ("stage",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 L, D = 8, 16
 rng = np.random.default_rng(0)
 params = {"w": jnp.asarray(rng.standard_normal((L, D, D)) * 0.2, jnp.float32),
@@ -66,7 +67,9 @@ ocfg = AdamWCfg(lr=1e-3, warmup_steps=1, total_steps=10)
 step = make_train_step(cfg, ocfg)
 p_ref, _, m_ref = jax.jit(step)(params, opt, batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 with use_mesh(mesh):
     pshard = shardings_of(param_specs(params, mesh), mesh)
     oshard = {"m": pshard, "v": pshard, "step": NamedSharding(mesh, P())}

@@ -364,6 +364,32 @@ def test_plan_cache_isolated_per_interpreter():
         clear_compile_cache()
 
 
+def test_interpret_default_resolves_from_backend(monkeypatch):
+    """``interpret=None`` means interpret mode exactly off the TPU, and
+    the caches key on the resolved value: on the CPU the default and an
+    explicit ``True`` are one entry, ``False`` is another."""
+    from repro.core import clear_compile_cache, compile_program
+    from repro.core import interpreters
+    from repro.core.interpreters import resolve_interpret
+
+    assert resolve_interpret(None) is True  # the test suite runs on CPU
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(interpreters.jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True
+    monkeypatch.undo()
+
+    prog = _plan_cache_prog(3.0, "interp_default")
+    clear_compile_cache()
+    try:
+        g = compile_program(prog, backend="pallas")
+        assert compile_program(prog, backend="pallas", interpret=True) is g
+        assert compile_program(prog, backend="pallas",
+                               interpret=False) is not g
+    finally:
+        clear_compile_cache()
+
+
 def test_plan_cache_lru_evicts_across_interpreters():
     """LRU eviction treats per-interpreter entries as ordinary
     citizens: filling the cap with a second interpreter's entries

@@ -170,21 +170,29 @@ def test_sizes_from_arrays_matches_runtime_resolution():
 
 def test_vmem_bytes_mirrors_scratch_shapes():
     """heat3d's only scratch is the 3-plane input window:
-    3 planes x 10 rows x pad(200->256) lanes x 4 B."""
+    3 planes x 10 rows (padded to 16) x pad(200->256) lanes x 4 B; the
+    input and output stream two 8-row blocks each."""
     kp = load_golden("heat3d")
     sizes = {"Nk": 8, "Nj": 10, "Ni": 200}
-    assert vmem_bytes(kp, sizes) == 3 * 10 * 256 * 4
+    block = 2 * 8 * 256 * 4
+    assert vmem_bytes(kp, sizes) == 3 * 16 * 256 * 4 + 2 * block
     rep = vmem_report(kp, sizes)
-    assert rep["heat3d_n0"]["in_u"] == 30720
-    assert rep["heat3d_n0"]["total"] == 30720
+    assert rep["heat3d_n0"]["in_u"] == 49152
+    assert rep["heat3d_n0"]["blk_u"] == block
+    assert rep["heat3d_n0"]["out_heat_u"] == block
+    assert rep["heat3d_n0"]["total"] == 81920
 
 
 def test_vmem_bytes_double_buffer_adds_staging():
+    """The explicit two-slot DMA staging takes the place of the
+    pipeline's two input blocks, at the same size."""
     kp = load_golden("cosmo")
     sizes = sizes_from_arrays(kp, {"u": (4, 12, 100)})
-    plain = vmem_bytes(kp, sizes)
-    dbuf = vmem_bytes(kp, sizes, double_buffer=True)
-    assert dbuf > plain  # the two-slot DMA staging rows
+    plain = vmem_report(kp, sizes)["cosmo_n0"]
+    dbuf = vmem_report(kp, sizes, double_buffer=True)["cosmo_n0"]
+    assert dbuf["dma_u"] == plain["blk_u"] == 2 * 8 * 128 * 4
+    assert "blk_u" not in dbuf and "dma_u" not in plain
+    assert dbuf["total"] == plain["total"]
 
 
 def test_vmem_budget_resolution(monkeypatch):
@@ -275,8 +283,8 @@ def test_explain_verbose_renders_vmem():
     out = explain(heat3d_program(), verbose=True,
                   dim_sizes={"Nk": 8, "Nj": 10, "Ni": 200})
     assert "--- vmem estimate ---" in out
-    assert "in_u: 3 x (Nj+0) x pad(Ni+0) x 4B" in out
-    assert "30720 B resident" in out
+    assert "in_u: 3 x sub(Nj+0) x pad(Ni+0) x 4B" in out
+    assert "81920 B resident" in out
 
 
 # ---------------------------------------------------------------------------

@@ -64,3 +64,34 @@ def test_worker_survives_bad_requests(tmp_path):
     ref = np.asarray(compile_program(laplace5_program(),
                                      backend="interp_jax").fn(cell=u)["lap"])
     np.testing.assert_array_equal(out["lap"], ref)
+
+
+def test_pool_refuses_second_worker_on_tpu_host(monkeypatch):
+    """A worker that opened a TPU holds every chip of its host: a pool
+    of two is refused before the second worker starts, and the first is
+    stopped."""
+    from repro.serve import workers
+
+    started = []
+
+    class FakeTpuWorker:
+        platform, device_count = "tpu", 1
+
+        def __init__(self, program_names, **kwargs):
+            started.append(self)
+            self.closed = False
+
+        def close(self):
+            self.closed = True
+
+    monkeypatch.setattr(workers, "ServeWorker", FakeTpuWorker)
+    with pytest.raises(RuntimeError, match="holds all 1 chip"):
+        workers.WorkerPool(2, ["laplace5"])
+    assert len(started) == 1 and started[0].closed
+
+
+def test_worker_reports_its_devices(tmp_path):
+    from repro.serve.workers import ServeWorker
+
+    with ServeWorker(["laplace5"], cache_dir=tmp_path) as w:
+        assert (w.platform, w.device_count) == ("cpu", 1)

@@ -1,0 +1,87 @@
+"""Compile the Pallas stencil kernels for a described TPU v5e.
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached, so these tests refuse what Mosaic would refuse
+on the chip (block shapes off the (8, 128) tile, unaligned DMA slices,
+unsupported primitives, VMEM over the scoped limit) without one.  They
+compile with ``interpret=False`` at Ni=1024 and check that each jitted
+program holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture: only the test
+worker that runs this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import compile_batched, compile_program
+from repro.core.programs import ALL_PROGRAMS
+
+#: (program, {size symbol: int}, double_buffer): the main path's
+#: programs at Ni=1024, each streaming mode on a 2-D and a 3-D grid
+CASES = [
+    ("laplace5", {"Nj": 1024, "Ni": 1024}, False),
+    ("cosmo", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
+    ("heat3d", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
+    ("normalization", {"Nj": 1024, "Ni": 1024}, False),
+    ("hydro1d", {"Nj": 1024, "Ni": 1024}, False),
+    ("plane_sum", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
+    # rows off the tile: the last DMA group reaches into its padding
+    ("laplace5", {"Nj": 1020, "Ni": 1024}, True),
+    ("heat3d", {"Nk": 8, "Nj": 254, "Ni": 1024}, True),
+    # the second nest streams a 1023-wide intermediate: whole lane tiles
+    ("normalization", {"Nj": 1020, "Ni": 1024}, True),
+]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _inputs(prog, sizes, sharding, batch=()):
+    """Shape-only inputs from the program's axiom extents."""
+    out = {}
+    for ax in prog.axioms:
+        exts = [ax.extents[d.rstrip("?")] for d in ax.term.ref.dims]
+        shape = tuple(sizes[e.size] + e.hi - e.lo for e in exts)
+        out[ax.term.ref.name] = jax.ShapeDtypeStruct(
+            batch + shape, jnp.float32, sharding=sharding)
+    return out
+
+
+@pytest.mark.parametrize("name,sizes,double_buffer", CASES,
+                         ids=[f"{n}-db{int(db)}" for n, _, db in CASES])
+def test_kernel_compiles_for_v5e(one_chip, name, sizes, double_buffer):
+    prog = ALL_PROGRAMS[name]()
+    gen = compile_program(prog, backend="pallas", interpret=False,
+                          double_buffer=double_buffer, use_cache=False)
+    compiled = jax.jit(lambda a: gen.fn(**a)).lower(
+        _inputs(prog, sizes, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_batched_kernel_compiles_for_v5e(one_chip):
+    """The serving path: the kernel vmapped over a batch of requests."""
+    prog = ALL_PROGRAMS["heat3d"]()
+    sizes = {"Nk": 8, "Nj": 256, "Ni": 1024}
+    bgen = compile_batched(prog, "pallas", jit=False, interpret=False,
+                           dim_sizes=sizes, use_cache=False)
+    compiled = jax.jit(bgen.fn).lower(
+        _inputs(prog, sizes, one_chip, batch=(4,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
