@@ -59,6 +59,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
+from ..trace import count, span
 from .codegen_jax import Generated, generate
 from .codegen_pallas import (PallasGenerated, PallasUnsupported,
                              plan_pallas)
@@ -182,10 +183,14 @@ def set_plan_cache_cap(cap: int) -> int:
 
 
 def _build_plan(program: Program):
-    idag = infer(program)
-    dag = build_dataflow(idag)
-    schedule = fuse_inest_dag(dag)
-    plan = analyze_storage(schedule)
+    with span("hfav.infer"):
+        idag = infer(program)
+    with span("hfav.dataflow"):
+        dag = build_dataflow(idag)
+    with span("hfav.fusion"):
+        schedule = fuse_inest_dag(dag)
+    with span("hfav.storage"):
+        plan = analyze_storage(schedule)
     return idag, plan
 
 
@@ -220,9 +225,11 @@ def _run_plancheck(kplan: KernelPlan, mode: str, *, dtype, double_buffer,
     additionally enables the VMEM budget check."""
     if mode == "off":
         return
-    diags = check_plan(kplan, sizes=dict(dim_sizes) if dim_sizes else None,
-                       dtype_bytes=jnp.dtype(dtype).itemsize,
-                       double_buffer=double_buffer, validate=False)
+    with span("hfav.plancheck"):
+        diags = check_plan(kplan,
+                           sizes=dict(dim_sizes) if dim_sizes else None,
+                           dtype_bytes=jnp.dtype(dtype).itemsize,
+                           double_buffer=double_buffer, validate=False)
     if not diags:
         return
     if mode == "error" and has_errors(diags):
@@ -273,6 +280,8 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
             bool(double_buffer) and "double_buffer" in spec.flags)
     if use_cache:
         hit = _PLAN_CACHE.get(pkey)
+        count("hfav.plan_cache.hit" if hit is not None
+              else "hfav.plan_cache.miss")
         if hit is not None:
             _PLAN_CACHE.move_to_end(pkey)
             if hit.plan is None and plan is not None:
@@ -287,9 +296,10 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
     # the registry (and runs the capability check, raising the typed
     # PlanUnsupported for plans outside the declared feature set)
     from .interpreters import execute_plan
-    fn = execute_plan(kplan, interpreter=interpreter, dtype=dtype,
-                      interpret=interpret, double_buffer=double_buffer)
-    gen = PallasGenerated(kplan, fn, plan, interpreter=interpreter)
+    with span("hfav.emit"):
+        fn = execute_plan(kplan, interpreter=interpreter, dtype=dtype,
+                          interpret=interpret, double_buffer=double_buffer)
+        gen = PallasGenerated(kplan, fn, plan, interpreter=interpreter)
     gen.base_plan = base_plan
     gen.layout_result = layout_result
     if use_cache:
@@ -307,7 +317,8 @@ def _emit_pallas(plan, idag, *, interpreter, dtype, interpret,
     The planner runs unconditionally (it is cheap and raises
     :class:`PallasUnsupported` for unsupported shapes); interpreter
     construction is memoized by :func:`_emit_plan`."""
-    kplan = plan_pallas(plan, idag)
+    with span("hfav.plan_pallas"):
+        kplan = plan_pallas(plan, idag)
     return _emit_plan(kplan, plan, interpreter=interpreter, dtype=dtype,
                       interpret=interpret, double_buffer=double_buffer,
                       use_cache=use_cache, check=check, dim_sizes=dim_sizes,
@@ -322,11 +333,13 @@ def _load_plan_from_disk(program: Program, backend: str,
     split schedules still require a registered win)."""
     from .plancache import PlanCache, program_plan_key
     try:
-        kplan = PlanCache(plan_cache_dir).get(program_plan_key(program))
+        with span("hfav.plan_disk", op="get"):
+            kplan = PlanCache(plan_cache_dir).get(program_plan_key(program))
     except OSError:  # uncreatable/unreadable cache dir: cold compile
         return None
     if kplan is None:
         return None
+    count("hfav.plan_disk.hit")
     if backend == "auto" and len(kplan.calls) != 1 \
             and program.name not in PALLAS_SPLIT_WINS:
         return None
@@ -341,11 +354,12 @@ def _store_plan_to_disk(program: Program, kplan: KernelPlan,
     idempotent for hot paths that revisit the same program."""
     from .plancache import PlanCache, program_plan_key
     try:
-        cache = PlanCache(plan_cache_dir)
-        key = program_plan_key(program)
-        if only_if_missing and cache.has(key):
-            return
-        cache.put(key, kplan)
+        with span("hfav.plan_disk", op="put"):
+            cache = PlanCache(plan_cache_dir)
+            key = program_plan_key(program)
+            if only_if_missing and cache.has(key):
+                return
+            cache.put(key, kplan)
     except OSError:
         pass
 
@@ -369,18 +383,20 @@ def _pallas_auto_probe(plan, idag, *, dtype, interpret, double_buffer,
     if not pallas_auto_viable(plan):
         return None
     try:
-        kplan = plan_pallas(plan, idag)
+        with span("hfav.plan_pallas"):
+            kplan = plan_pallas(plan, idag)
     except PallasUnsupported:
         return None
     if dim_sizes:
-        est = vmem_bytes(kplan, dict(dim_sizes),
-                         dtype_bytes=jnp.dtype(dtype).itemsize,
-                         double_buffer=double_buffer)
-        if est > vmem_budget(None):
-            return None
-        if auto_vec_reject(kplan, dict(dim_sizes),
-                           dtype_bytes=jnp.dtype(dtype).itemsize):
-            return None
+        with span("hfav.autoprobe"):
+            est = vmem_bytes(kplan, dict(dim_sizes),
+                             dtype_bytes=jnp.dtype(dtype).itemsize,
+                             double_buffer=double_buffer)
+            if est > vmem_budget(None):
+                return None
+            if auto_vec_reject(kplan, dict(dim_sizes),
+                               dtype_bytes=jnp.dtype(dtype).itemsize):
+                return None
     try:
         return _emit_plan(kplan, plan, interpreter="pallas", dtype=dtype,
                           interpret=interpret, double_buffer=double_buffer,
@@ -473,97 +489,107 @@ def compile_program(
     ones).  The resolved mode participates in the compile cache key,
     and the plan-level cache distinguishes the plans themselves
     (``applied_layout`` is structural), so modes never share entries;
-    the on-disk plan cache always stores the untransformed plan."""
-    if backend in ("auto", "jax"):
-        spec = None
-    else:
-        try:
-            spec = get_interpreter(backend)
-        except ValueError:
-            raise ValueError(
-                f"unknown backend {backend!r}; expected 'auto', 'jax' or a "
-                f"registered interpreter: {registered_interpreters()}"
-            ) from None
-    interpret = resolve_interpret(interpret)
-    check = resolve_check_mode(check_plans)
-    apply_mode = resolve_apply_mode(apply_layout)
-    if plan_cache_dir is None:
-        plan_cache_dir = os.environ.get(PLAN_CACHE_DIR_ENV) or None
-    sizes_key = tuple(sorted(dim_sizes.items())) if dim_sizes else None
-    # flags an interpreter does not honor are normalized out of the key
-    # (a pure-JAX interpreter compiles identically either way); for the
-    # legacy "jax" emitter only double_buffer is moot, matching the
-    # pre-registry key shape exactly — and apply_layout normalizes to
-    # "off" for layout-oblivious backends, where the pass never runs
-    key = (program_signature(program), backend, jnp.dtype(dtype).name,
-           bool(interpret) and (spec is None or "interpret" in spec.flags),
-           bool(double_buffer) and backend != "jax"
-           and (spec is None or "double_buffer" in spec.flags),
-           sizes_key,
-           apply_mode if spec is not None and spec.layout_aware
-           else "off")
-    if use_cache:
-        hit = _CACHE.get(key)
-        if hit is not None:
-            if plan_cache_dir is not None and isinstance(hit,
-                                                         PallasGenerated):
-                # the program compiled before this call named a cache
-                # dir: back-fill the L2 so the next process runs warm
-                # (always the untransformed plan — LayoutApply re-runs
-                # per compilation, so cached plans stay mode-agnostic)
-                _store_plan_to_disk(
-                    program,
-                    getattr(hit, "base_plan", None) or hit.kernel_plan,
-                    plan_cache_dir, only_if_missing=True)
-            return _attach_vec_report(hit, vec_report, dim_sizes, dtype)
-    if plan_cache_dir is not None and backend != "jax":
-        # disk-restored artifacts carry no StoragePlan, so they live
-        # under a marked key: a later compile *without* plan_cache_dir
-        # must rebuild the full artifact, not inherit the degraded one
-        dkey = key + ("disk",)
+    the on-disk plan cache always stores the untransformed plan.
+
+    The call runs under the span ``hfav.compile_program`` and each pass
+    under a child span, and the caches count their hits and misses
+    (:mod:`repro.trace`; docs/ARCHITECTURE.md, "Tracing")."""
+    with span("hfav.compile_program", program=program.name,
+              backend=backend):
+        if backend in ("auto", "jax"):
+            spec = None
+        else:
+            try:
+                spec = get_interpreter(backend)
+            except ValueError:
+                raise ValueError(
+                    f"unknown backend {backend!r}; expected 'auto', 'jax' or a "
+                    f"registered interpreter: {registered_interpreters()}"
+                ) from None
+        interpret = resolve_interpret(interpret)
+        check = resolve_check_mode(check_plans)
+        apply_mode = resolve_apply_mode(apply_layout)
+        if plan_cache_dir is None:
+            plan_cache_dir = os.environ.get(PLAN_CACHE_DIR_ENV) or None
+        sizes_key = tuple(sorted(dim_sizes.items())) if dim_sizes else None
+        # flags an interpreter does not honor are normalized out of the key
+        # (a pure-JAX interpreter compiles identically either way); for the
+        # legacy "jax" emitter only double_buffer is moot, matching the
+        # pre-registry key shape exactly — and apply_layout normalizes to
+        # "off" for layout-oblivious backends, where the pass never runs
+        key = (program_signature(program), backend, jnp.dtype(dtype).name,
+               bool(interpret) and (spec is None or "interpret" in spec.flags),
+               bool(double_buffer) and backend != "jax"
+               and (spec is None or "double_buffer" in spec.flags),
+               sizes_key,
+               apply_mode if spec is not None and spec.layout_aware
+               else "off")
         if use_cache:
-            hit = _CACHE.get(dkey)
+            hit = _CACHE.get(key)
+            count("hfav.compile_cache.hit" if hit is not None
+                  else "hfav.compile_cache.miss")
             if hit is not None:
-                return _attach_vec_report(hit, vec_report, dim_sizes,
-                                          dtype)
-        kplan = _load_plan_from_disk(program, backend, plan_cache_dir)
-        if kplan is not None:
-            gen = _emit_plan(kplan, None,
-                             interpreter="pallas" if backend == "auto"
-                             else backend,
-                             dtype=dtype, interpret=interpret,
-                             double_buffer=double_buffer,
-                             use_cache=use_cache, check=check,
-                             dim_sizes=dim_sizes, apply_mode=apply_mode)
+                if plan_cache_dir is not None and isinstance(hit,
+                                                             PallasGenerated):
+                    # the program compiled before this call named a cache
+                    # dir: back-fill the L2 so the next process runs warm
+                    # (always the untransformed plan — LayoutApply re-runs
+                    # per compilation, so cached plans stay mode-agnostic)
+                    _store_plan_to_disk(
+                        program,
+                        getattr(hit, "base_plan", None) or hit.kernel_plan,
+                        plan_cache_dir, only_if_missing=True)
+                return _attach_vec_report(hit, vec_report, dim_sizes, dtype)
+        if plan_cache_dir is not None and backend != "jax":
+            # disk-restored artifacts carry no StoragePlan, so they live
+            # under a marked key: a later compile *without* plan_cache_dir
+            # must rebuild the full artifact, not inherit the degraded one
+            dkey = key + ("disk",)
             if use_cache:
-                _CACHE[dkey] = gen
-            return _attach_vec_report(gen, vec_report, dim_sizes, dtype)
-    idag, plan = _build_plan(program)
-    if backend == "jax":
-        gen: Union[Generated, PallasGenerated] = generate(plan, idag)
-    elif backend == "auto":
-        gen = _pallas_auto_probe(plan, idag, dtype=dtype, interpret=interpret,
+                hit = _CACHE.get(dkey)
+                if hit is not None:
+                    return _attach_vec_report(hit, vec_report, dim_sizes,
+                                              dtype)
+            kplan = _load_plan_from_disk(program, backend, plan_cache_dir)
+            if kplan is not None:
+                gen = _emit_plan(kplan, None,
+                                 interpreter="pallas" if backend == "auto"
+                                 else backend,
+                                 dtype=dtype, interpret=interpret,
                                  double_buffer=double_buffer,
                                  use_cache=use_cache, check=check,
                                  dim_sizes=dim_sizes, apply_mode=apply_mode)
-        if gen is None:
-            gen = generate(plan, idag)
-    else:
-        gen = _emit_pallas(plan, idag, interpreter=backend, dtype=dtype,
-                           interpret=interpret, double_buffer=double_buffer,
-                           use_cache=use_cache, check=check,
-                           dim_sizes=dim_sizes, apply_mode=apply_mode)
-    if plan_cache_dir is not None and isinstance(gen, PallasGenerated):
-        _store_plan_to_disk(
-            program, getattr(gen, "base_plan", None) or gen.kernel_plan,
-            plan_cache_dir)
-    if use_cache:
-        _CACHE[key] = gen
-        if key[4] and isinstance(gen, Generated):
-            # double_buffer had no effect (auto fell back to JAX): alias
-            # the normalized key so neither flag value recompiles
-            _CACHE[key[:4] + (False,) + key[5:]] = gen
-    return _attach_vec_report(gen, vec_report, dim_sizes, dtype)
+                if use_cache:
+                    _CACHE[dkey] = gen
+                return _attach_vec_report(gen, vec_report, dim_sizes, dtype)
+        idag, plan = _build_plan(program)
+        if backend == "jax":
+            with span("hfav.emit"):
+                gen: Union[Generated, PallasGenerated] = generate(plan, idag)
+        elif backend == "auto":
+            gen = _pallas_auto_probe(plan, idag, dtype=dtype, interpret=interpret,
+                                     double_buffer=double_buffer,
+                                     use_cache=use_cache, check=check,
+                                     dim_sizes=dim_sizes, apply_mode=apply_mode)
+            if gen is None:
+                with span("hfav.emit"):
+                    gen = generate(plan, idag)
+        else:
+            gen = _emit_pallas(plan, idag, interpreter=backend, dtype=dtype,
+                               interpret=interpret, double_buffer=double_buffer,
+                               use_cache=use_cache, check=check,
+                               dim_sizes=dim_sizes, apply_mode=apply_mode)
+        if plan_cache_dir is not None and isinstance(gen, PallasGenerated):
+            _store_plan_to_disk(
+                program, getattr(gen, "base_plan", None) or gen.kernel_plan,
+                plan_cache_dir)
+        if use_cache:
+            _CACHE[key] = gen
+            if key[4] and isinstance(gen, Generated):
+                # double_buffer had no effect (auto fell back to JAX): alias
+                # the normalized key so neither flag value recompiles
+                _CACHE[key[:4] + (False,) + key[5:]] = gen
+        return _attach_vec_report(gen, vec_report, dim_sizes, dtype)
 
 
 class BatchedGenerated:

@@ -48,6 +48,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from ..trace import count, span
 from .plan import (PLAN_FEATURES, CallPlan, KernelPlan, OutputPlan,
                    PallasUnsupported)
 from .runtime import lane_reduce
@@ -112,8 +113,9 @@ def _ensure_builtins() -> None:
     if _builtins_loaded:
         return
     _builtins_loaded = True
-    for mod in _BUILTIN_MODULES:
-        importlib.import_module(mod)
+    with span("hfav.load_interpreters"):
+        for mod in _BUILTIN_MODULES:
+            importlib.import_module(mod)
 
 
 def register_interpreter(spec: InterpreterSpec) -> None:
@@ -377,6 +379,15 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
                            slice(out.i_lo, out.i_lo + w))]
 
 
+def _grid_steps(call: CallPlan, n_outs: tuple[int, ...], nj: int) -> int:
+    """Grid steps of one stencil call: ``steps_j`` rows times every
+    outer grid dim's extent, as ``build_call`` lays out its grid."""
+    steps = nj + call.x_hi_off - call.x_lo
+    for n, lo, hi in zip(n_outs, call.outer_lo, call.outer_hi_off):
+        steps *= n + hi - lo
+    return steps
+
+
 def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
                  dtype=jnp.float32, interpret: Optional[bool] = None,
                  double_buffer: bool = False):
@@ -393,7 +404,12 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     feature set raises :class:`PlanUnsupported` before anything builds.
     ``interpret`` (resolved by :func:`resolve_interpret`) and
     ``double_buffer`` are forwarded to ``build_call``; interpreters that
-    don't honor a flag accept and ignore it."""
+    don't honor a flag accept and ignore it.
+
+    The span ``hfav.build_call`` (attributes ``call`` and
+    ``grid_steps``) and the counter ``hfav.grid_steps``
+    (:mod:`repro.trace`) mark each stencil call as ``fn``'s Python
+    runs: under ``jax.jit`` once per trace, never per compiled call."""
     spec = get_interpreter(interpreter)
     interpret = resolve_interpret(interpret)
     check_capabilities(spec, kplan)
@@ -425,16 +441,19 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
             for hs in cp.host_pre:
                 _run_host(cp, hs, env)
             if cp.has_grid:
-                pcall, _ = spec.build_call(cp, (*n_outs, nj, ni), dtype,
-                                           interpret=interpret,
-                                           double_buffer=double_buffer)
-                args = []
-                for ispec in cp.inputs:
-                    v = jnp.asarray(env[ispec.name], dtype)
-                    if ispec.scalar:
-                        v = v.reshape((1, 1))
-                    args.append(v)
-                padded = pcall(*args)
+                steps = _grid_steps(cp, n_outs, nj)
+                count("hfav.grid_steps", steps)
+                with span("hfav.build_call", call=cp.name, grid_steps=steps):
+                    pcall, _ = spec.build_call(cp, (*n_outs, nj, ni), dtype,
+                                               interpret=interpret,
+                                               double_buffer=double_buffer)
+                    args = []
+                    for ispec in cp.inputs:
+                        v = jnp.asarray(env[ispec.name], dtype)
+                        if ispec.scalar:
+                            v = v.reshape((1, 1))
+                        args.append(v)
+                    padded = pcall(*args)
                 if not isinstance(padded, (list, tuple)):
                     padded = [padded]
                 for out, pout in zip(cp.outputs, padded):

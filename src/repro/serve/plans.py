@@ -41,10 +41,13 @@ Three layers:
 
 Per-request metrics (queue wait, batch size, compile-vs-cache-hit,
 p50/p99 latency, requests/s) accumulate in :class:`ServeMetrics`; the
-schema is documented in docs/ARCHITECTURE.md ("Plan serving").
+schema is documented in docs/ARCHITECTURE.md ("Plan serving").  Each
+batch and its steps also run under the ``hfav.serve.*`` spans of
+:mod:`repro.trace` (docs/ARCHITECTURE.md, "Tracing").
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -58,6 +61,7 @@ import numpy as np
 from ..core.engine import (PLAN_CACHE_DIR_ENV, BatchedGenerated,
                            compile_batched)
 from ..core.rules import Program
+from ..trace import span
 
 #: Backends PlanServe accepts: every one is pinned vmap-safe by the
 #: cross-backend conformance tests (tests/test_serve.py pins
@@ -210,7 +214,8 @@ class ServeTicket:
         self._outputs: Optional[dict] = None
         self._error: Optional[BaseException] = None
         #: Per-request metrics (filled when done): ``latency_ms``,
-        #: ``queue_wait_ms``, ``batch_size``, ``bucket``, ``compiled``.
+        #: ``queue_wait_ms``, ``batch_size``, ``bucket``, ``batch_id``
+        #: (the ``batch_id`` of its batch's ``hfav.serve.*`` spans).
         self.stats: dict = {}
 
     def done(self) -> bool:
@@ -375,6 +380,7 @@ class PlanServe:
         self._queues: dict = {}     # (name, bucket) -> deque[_Pending]
         self._cond = threading.Condition()
         self._closed = False
+        self._batch_ids = itertools.count()
         self._batcher = threading.Thread(
             target=self._batch_loop, name="planserve-batcher", daemon=True)
         self._batcher.start()
@@ -417,11 +423,11 @@ class PlanServe:
                     program_plan_key(prog))
             except OSError:
                 disk_hit = False
-        t0 = time.perf_counter()
-        gen = compile_batched(
-            prog, self.backend, dim_sizes=dict(bucket),
-            plan_cache_dir=self.plan_cache_dir, **self.compile_kwargs)
-        self.metrics.record_compile((time.perf_counter() - t0) * 1e3,
+        with span("hfav.serve.compile", program=name, bucket=bucket) as sp:
+            gen = compile_batched(
+                prog, self.backend, dim_sizes=dict(bucket),
+                plan_cache_dir=self.plan_cache_dir, **self.compile_kwargs)
+        self.metrics.record_compile((sp.end_ns - sp.start_ns) / 1e6,
                                     disk_hit)
         self._compiled[key] = gen
         return gen
@@ -502,45 +508,63 @@ class PlanServe:
                 # collect: up to max_batch requests, or whatever arrived
                 # by the oldest request's deadline
                 deadline = q[0].t_submit + self.max_wait_s
-                while (len(q) < self.max_batch
-                       and not self._closed
-                       and (left := deadline - time.perf_counter()) > 0):
-                    self._cond.wait(timeout=left)
+                with span("hfav.serve.collect"):
+                    while (len(q) < self.max_batch
+                           and not self._closed
+                           and (left := deadline - time.perf_counter()) > 0):
+                        self._cond.wait(timeout=left)
                 batch = [q.popleft()
                          for _ in range(min(len(q), self.max_batch))]
             self._execute(key, batch)
 
     def _execute(self, key, batch) -> None:
+        """Run one batch under the span ``hfav.serve.batch`` (attributes
+        ``batch_id``, ``bucket``, ``n``, ``slots``), its steps under the
+        child spans ``hfav.serve.pad``, ``.stack``, ``.call`` (transfer
+        in, kernel, block), ``.fetch`` (device to host) and ``.unpad``.
+        A request's ``queue_wait_ms`` ends where the batch span starts
+        and its ``latency_ms`` where the fetch ends."""
         name, bucket = key
         prog = self.programs[name]
-        t_start = time.perf_counter()
-        self.metrics.record_batch(bucket, len(batch))
-        try:
-            gen = self._get_compiled(name, bucket)
-            padded = [pad_to_bucket(prog, p.arrays, bucket) for p in batch]
-            # slot-bucket the batch axis (duplicate the last request) so
-            # jit traces O(log max_batch) batch widths
-            slots = _slot_count(len(batch), self.max_batch)
-            while len(padded) < slots:
-                padded.append(padded[-1])
-            stacked = {k: np.stack([p[k] for p in padded])
-                       for k in padded[0]}
-            outputs = jax.block_until_ready(gen.fn(stacked))
-            outputs = {k: np.asarray(v) for k, v in outputs.items()}
-        except Exception as err:
-            for p in batch:
-                p.ticket._fail(err)
-            return
-        t_done = time.perf_counter()
-        for i, p in enumerate(batch):
-            example = {k: v[i] for k, v in outputs.items()}
-            out = unpad_outputs(prog, example, p.sizes)
-            p.ticket.stats = {
-                "latency_ms": (t_done - p.t_submit) * 1e3,
-                "queue_wait_ms": (t_start - p.t_submit) * 1e3,
-                "batch_size": len(batch),
-                "bucket": bucket,
-            }
-            self.metrics.record_request(p.ticket.stats["latency_ms"],
-                                        p.ticket.stats["queue_wait_ms"])
-            p.ticket._resolve(out)
+        batch_id = next(self._batch_ids)
+        # slot-bucket the batch axis (duplicate the last request) so
+        # jit traces O(log max_batch) batch widths
+        slots = _slot_count(len(batch), self.max_batch)
+        with span("hfav.serve.batch", batch_id=batch_id, bucket=bucket,
+                  n=len(batch), slots=slots) as sp_batch:
+            self.metrics.record_batch(bucket, len(batch))
+            try:
+                gen = self._get_compiled(name, bucket)
+                with span("hfav.serve.pad", batch_id=batch_id):
+                    padded = [pad_to_bucket(prog, p.arrays, bucket)
+                              for p in batch]
+                    while len(padded) < slots:
+                        padded.append(padded[-1])
+                with span("hfav.serve.stack", batch_id=batch_id):
+                    stacked = {k: np.stack([p[k] for p in padded])
+                               for k in padded[0]}
+                with span("hfav.serve.call", batch_id=batch_id):
+                    outputs = jax.block_until_ready(gen.fn(stacked))
+                with span("hfav.serve.fetch", batch_id=batch_id) as sp_fetch:
+                    outputs = {k: np.asarray(v) for k, v in outputs.items()}
+            except Exception as err:
+                for p in batch:
+                    p.ticket._fail(err)
+                return
+            t_start = sp_batch.start_ns / 1e9
+            t_done = sp_fetch.end_ns / 1e9
+            with span("hfav.serve.unpad", batch_id=batch_id):
+                for i, p in enumerate(batch):
+                    example = {k: v[i] for k, v in outputs.items()}
+                    out = unpad_outputs(prog, example, p.sizes)
+                    p.ticket.stats = {
+                        "latency_ms": (t_done - p.t_submit) * 1e3,
+                        "queue_wait_ms": (t_start - p.t_submit) * 1e3,
+                        "batch_size": len(batch),
+                        "bucket": bucket,
+                        "batch_id": batch_id,
+                    }
+                    self.metrics.record_request(
+                        p.ticket.stats["latency_ms"],
+                        p.ticket.stats["queue_wait_ms"])
+                    p.ticket._resolve(out)

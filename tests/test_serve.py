@@ -283,3 +283,42 @@ def test_close_drains_queued_requests():
     assert time.perf_counter() - t0 < 60
     for u, t in zip(inputs, tickets):
         np.testing.assert_array_equal(t.result(1)["lap"], _laplace_ref(u))
+
+
+def test_batch_spans_carry_the_ticket_batch_id():
+    """One batch's spans carry its tickets' ``batch_id``; its steps (pad,
+    stack, call, fetch, unpad) cover nearly all of it, and a ticket's
+    service time is the batch span's start to the fetch's end."""
+    from repro import trace
+
+    rng = _rng()
+    inputs = [rng.standard_normal((200, 300)).astype(np.float32)
+              for _ in range(4)]
+    prog = laplace5_program()
+    with trace.recording() as rec:
+        with PlanServe({"laplace5": prog}, max_batch=4,
+                       max_wait_ms=500.0) as srv:
+            srv.prefill("laplace5", request_sizes(prog, {"cell": inputs[0]}),
+                        batch=4)
+            tickets = [srv.submit("laplace5", {"cell": u}) for u in inputs]
+            for t in tickets:
+                t.result(60)
+    assert [s.attrs["program"] for s in rec.named("hfav.serve.compile")] \
+        == ["laplace5"]
+    (batch,) = rec.named("hfav.serve.batch")
+    assert {t.stats["batch_id"] for t in tickets} == {batch.attrs["batch_id"]}
+    assert batch.attrs["n"] == batch.attrs["slots"] == 4
+    steps = rec.children(batch)
+    assert [s.name for s in steps] == [
+        "hfav.serve.pad", "hfav.serve.stack", "hfav.serve.call",
+        "hfav.serve.fetch", "hfav.serve.unpad"]
+    assert all(s.attrs == {"batch_id": batch.attrs["batch_id"]} for s in steps)
+    assert sum(s.seconds for s in steps) >= 0.95 * batch.seconds
+    fetch = steps[3]
+    for t in tickets:
+        service_ms = t.stats["latency_ms"] - t.stats["queue_wait_ms"]
+        assert service_ms == pytest.approx((fetch.end_ns - batch.start_ns) / 1e6,
+                                           abs=1e-3)
+    # the batcher thread waited for the batch to fill, outside the batch
+    assert rec.named("hfav.serve.collect")
+    assert all(s.parent is None for s in rec.named("hfav.serve.collect"))
