@@ -67,20 +67,6 @@ def _shapes(prog, sizes: dict) -> dict:
     return shapes
 
 
-def _grid_steps(kplan, sizes: dict) -> int:
-    """Grid steps over all stencil calls of the plan."""
-    sym = dict(kplan.dim_sizes)
-    total = 0
-    for c in kplan.calls:
-        if not c.has_grid:
-            continue
-        steps = sizes[sym[c.row_dim]] + c.x_hi_off - c.x_lo
-        for g, lo, hi in zip(c.grid[:-1], c.outer_lo, c.outer_hi_off):
-            steps *= sizes[sym[g.dim]] + hi - lo
-        total += steps
-    return total
-
-
 def _errors(got: dict, want: dict) -> tuple[float, float]:
     """(max|got - ref|, max |got - ref| / (|ref| + mean|ref|)) over
     every goal store; raises when the second exceeds :data:`RTOL`."""
@@ -102,6 +88,7 @@ def _errors(got: dict, want: dict) -> tuple[float, float]:
 def library_phase(name: str, sizes: dict, double_buffer: bool) -> str:
     import jax
 
+    from repro import trace
     from repro.core import compile_program
     from repro.core.programs import ALL_PROGRAMS
     from repro.core.unfused import build_unfused
@@ -114,7 +101,8 @@ def library_phase(name: str, sizes: dict, double_buffer: bool) -> str:
     arrays = {n: jax.random.normal(k, s, jax.numpy.float32)
               for k, (n, s) in zip(keys, sorted(shapes.items()))}
     t0 = time.perf_counter()
-    compiled = jax.jit(lambda a: gen.fn(**a)).lower(arrays).compile()
+    with trace.recording() as rec:
+        compiled = jax.jit(lambda a: gen.fn(**a)).lower(arrays).compile()
     compile_s = time.perf_counter() - t0
     if "tpu_custom_call" not in compiled.as_text():
         raise AssertionError("no tpu_custom_call in the compiled program")
@@ -125,7 +113,8 @@ def library_phase(name: str, sizes: dict, double_buffer: bool) -> str:
     want = jax.block_until_ready(
         jax.jit(lambda a: build_unfused(prog).fn(**a))(arrays))
     err, rel = _errors(got, want)
-    return (f"shape={shapes} grid_steps={_grid_steps(gen.kernel_plan, sizes)}"
+    return (f"shape={shapes} grid_steps={rec.counters['hfav.grid_steps']}"
+            f" row_tiles={[b.attrs['row_tile'] for b in rec.named('hfav.build_call')]}"
             f" compile_s={compile_s!r} smoke_warm_call_s={warm_s!r}"
             f" max_err={err!r} rel_err={rel!r} tol={RTOL}"
             f" tpu_custom_call=True")

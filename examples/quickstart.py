@@ -35,9 +35,9 @@ in_cell[j+1], in_cell[j+0], in_cell[j+0]] -> out:0
       goals: lap<-laplace_cell
     --- vmem estimate ---
       laplace5_n0:
-        in_cell: sub(3) x pad(Ni+0) x 4B
-        stream cell: 2 x 8 x pad(Ni+0) x 4B
-        out laplace_cell: 2 x 8 x pad(Ni+0) x 4B
+        in_cell: sub(R+2) x pad(Ni+0) x 4B
+        stream cell: 2 x R x pad(Ni+0) x 4B
+        out laplace_cell: 2 x R x pad(Ni+0) x 4B
     --- vectorization ---
       access classes: aligned=2 shifted=4
       redundant-load ratio: 1.67
@@ -87,7 +87,7 @@ def main():
     assert err < 1e-5
 
     # backend="pallas": the same schedule on the TPU stencil executor —
-    # rolling buffers in VMEM, one streamed row per grid step.  Off-TPU
+    # rolling buffers in VMEM, a tile of rows per grid step.  Off-TPU
     # it runs in interpret mode, so we validate on a small grid (the
     # grid unrolls at trace time); on a TPU it compiles with Mosaic.
     # double_buffer=True selects the explicit two-slot input-DMA pipeline.

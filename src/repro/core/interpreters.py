@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from ..trace import count, span
 from .plan import (PLAN_FEATURES, CallPlan, KernelPlan, OutputPlan,
                    PallasUnsupported)
+from .plancheck import row_tile
 from .runtime import lane_reduce
 
 
@@ -360,7 +361,7 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
     nrows = nj + out.j_hi - out.j_lo
     otrim = _outer_trim(out, call, n_outs, n_out)
     if out.kind == "acc_rows":
-        # one identity-padded partial-accumulator row per grid step:
+        # one identity-padded partial-accumulator row per row position:
         # trim, fold the lanes, seat at the goal origin
         part = padded[otrim + (slice(t0, t0 + nrows), slice(None))]
         vals = lane_reduce(reduce_fn, jnp.moveaxis(part, -1, 0),
@@ -379,13 +380,17 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
                            slice(out.i_lo, out.i_lo + w))]
 
 
-def _grid_steps(call: CallPlan, n_outs: tuple[int, ...], nj: int) -> int:
-    """Grid steps of one stencil call: ``steps_j`` rows times every
-    outer grid dim's extent, as ``build_call`` lays out its grid."""
-    steps = nj + call.x_hi_off - call.x_lo
+def _grid_steps(call: CallPlan, n_outs: tuple[int, ...], nj: int, ni: int,
+                dtype, double_buffer: bool) -> tuple[int, int]:
+    """Grid steps of one stencil call and the rows R each computes
+    (:func:`repro.core.plancheck.row_tile`): ``cdiv(steps_j, R)`` row
+    steps times every outer grid dim's extent, as the Pallas
+    ``build_call`` lays out its grid."""
+    rows = row_tile(call, nj, ni, jnp.dtype(dtype).itemsize, double_buffer)
+    steps = -(-(nj + call.x_hi_off - call.x_lo) // rows)
     for n, lo, hi in zip(n_outs, call.outer_lo, call.outer_hi_off):
         steps *= n + hi - lo
-    return steps
+    return steps, rows
 
 
 def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
@@ -406,10 +411,12 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     ``double_buffer`` are forwarded to ``build_call``; interpreters that
     don't honor a flag accept and ignore it.
 
-    The span ``hfav.build_call`` (attributes ``call`` and
-    ``grid_steps``) and the counter ``hfav.grid_steps``
-    (:mod:`repro.trace`) mark each stencil call as ``fn``'s Python
-    runs: under ``jax.jit`` once per trace, never per compiled call."""
+    The span ``hfav.build_call`` (attributes ``call``, ``grid_steps``
+    and ``row_tile``) and the counters ``hfav.grid_steps`` and
+    ``hfav.row_tile`` (the rows each grid step computes, added once per
+    built call; :mod:`repro.trace`) mark each stencil call as ``fn``'s
+    Python runs: under ``jax.jit`` once per trace, never per compiled
+    call."""
     spec = get_interpreter(interpreter)
     interpret = resolve_interpret(interpret)
     check_capabilities(spec, kplan)
@@ -441,9 +448,12 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
             for hs in cp.host_pre:
                 _run_host(cp, hs, env)
             if cp.has_grid:
-                steps = _grid_steps(cp, n_outs, nj)
+                steps, rows = _grid_steps(cp, n_outs, nj, ni, dtype,
+                                          double_buffer)
                 count("hfav.grid_steps", steps)
-                with span("hfav.build_call", call=cp.name, grid_steps=steps):
+                count("hfav.row_tile", rows)
+                with span("hfav.build_call", call=cp.name, grid_steps=steps,
+                          row_tile=rows):
                     pcall, _ = spec.build_call(cp, (*n_outs, nj, ni), dtype,
                                                interpret=interpret,
                                                double_buffer=double_buffer)

@@ -300,8 +300,8 @@ class InputPlan:
 
     Array inputs cover positions ``[j_lo, Nj + j_hi) x [i_lo, Ni + i_hi)``
     of the iteration space (array index = position - origin) and stream
-    one row per grid step into a ``stages``-row VMEM window at ``lead``
-    rows ahead of the canonical point.  ``n_outer`` is the number of
+    one row per row position into a ``stages``-row VMEM window at
+    ``lead`` rows ahead of the canonical point.  ``n_outer`` is the number of
     *outer* grid dimensions the array itself carries (fewer than the
     grid's broadcasts over the leading outer dims);
     ``outer_los``/``outer_his`` are its per-outer-dim origins.  Scalar

@@ -199,11 +199,19 @@ def pad_to_lane(w: int) -> int:
 _pad_to_lane = pad_to_lane
 
 
+def row_tile_unit(dtype_bytes: int) -> int:
+    """Rows of one sublane tile: 8 of 32-bit values, 16 of 2-byte ones."""
+    return SUBLANE * max(1, 4 // int(dtype_bytes))
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def _pad_rows(rows: int, dtype_bytes: int) -> int:
     """Rows a VMEM buffer occupies: a multiple of the sublane tile
     (8 rows of 32-bit values, more for narrower ones)."""
-    tile = SUBLANE * max(1, 4 // dtype_bytes)
-    return -(-rows // tile) * tile
+    return _round_up(rows, row_tile_unit(dtype_bytes))
 
 
 # ---------------------------------------------------------------------------
@@ -774,49 +782,205 @@ def _call_sizes(kplan: KernelPlan, call: CallPlan, sizes: dict):
     return tuple(vals)
 
 
-def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
-              double_buffer: bool) -> dict:
-    """Per-buffer VMEM bytes of one call, plus their ``"total"``,
-    mirroring the interpreter's allocation (``build_call``): rolling
-    windows ``stages x pad(width)``, plane windows
-    ``p_stages x rows x pad(width)``, accumulators one padded row, and
-    the two buffers of every 8-row stream block (array inputs, either
-    the pipeline's or the explicit DMA slots; row and accumulator
-    outputs).  Rows are padded to the sublane tile."""
-    ib = int(dtype_bytes)
+#: Most rows one grid step computes: the cap of the row tile R.  On a
+#: TPU v5e, kernel time fell with R up to 128, the largest R tried, in
+#: both chip benchmark cells (PERF.md, "Findings").
+ROW_TILE_CAP = 128
 
-    def rows(n):
+
+@dataclass(frozen=True)
+class RowGeometry:
+    """Where one call's rows sit when each grid step computes ``rows``
+    (R) of them: what the Pallas interpreter's ``build_call`` allocates
+    and indexes, and what :func:`call_vmem` counts.
+
+    Grid step ``jid`` computes canonical positions ``jid * R + x_lo``
+    onward.  An array input's step brings the block whose first source
+    row is ``jid * R + first_row``, clamped to ``[0, last_row]``; that is
+    ``lookahead`` rows beyond ``x_lo + lead - j_lo``, so that every block
+    starts on a multiple of R.  A rolling window keeps ``halo[name] =
+    (keep, at)``: the newest R rows land at row ``at`` and the ``keep``
+    rows above them carry over from the step before; ``lead[name]`` is
+    the position lead of those newest rows (a window's writer at a
+    lower lead stores that many rows higher up).  A plane buffer keeps
+    absolute rows below a top ``margin``; for R > 1 each step loads its
+    reads in one aligned ``span[name] = (first, rows)`` window at row
+    ``jid * R + first``.  ``height`` is every buffer's row count."""
+
+    rows: int
+    tile: int
+    steps: int
+    out_block: int
+    block: dict
+    first_row: dict
+    last_row: dict
+    lead: dict
+    halo: dict
+    margin: dict
+    span: dict
+    height: dict
+
+
+def row_geometry(call: CallPlan, nj: int, rows: int,
+                 dtype_bytes: int) -> RowGeometry:
+    """The buffers of ``call`` at row tile ``rows`` and row count ``nj``
+    (see :class:`RowGeometry`)."""
+    R = int(rows)
+    t = row_tile_unit(dtype_bytes)
+    steps = -(-(nj + call.x_hi_off - call.x_lo) // R)
+    writers = _writers(call)
+    block, first, last, lead, halo, margin, span, height = \
+        {}, {}, {}, {}, {}, {}, {}, {}
+    for i in call.inputs:
+        if i.scalar:
+            continue
+        in_h = nj + i.j_hi - i.j_lo
+        s = call.x_lo + i.lead - i.j_lo
+        block[i.name] = min(SUBLANE if R == 1 else R, in_h)
+        first[i.name] = s + (-s) % R
+        last[i.name] = in_h - 1 if R == 1 else \
+            (-(-in_h // block[i.name]) - 1) * block[i.name]
+        if not i.plane:
+            lead[f"in_{i.name}"] = i.lead + (-s) % R
+            halo[f"in_{i.name}"] = i.stages + (-s) % R - 1
+    for w in call.windows:
+        if w.plane:
+            continue
+        lead[w.name] = max((call.steps[si].lead
+                            for si in writers.get(w.name, ())), default=0)
+        halo[w.name] = w.stages - 1
+    for name, keep in halo.items():
+        at = keep if R == 1 else _round_up(keep, t)
+        halo[name] = (keep, at)
+        height[name] = at + R
+    planes = [(f"in_{i.name}", i.j_lo, i) for i in call.inputs if i.plane]
+    planes += [(w.name, w.j_lo, None) for w in call.windows if w.plane]
+    for name, j_lo, ispec in planes:
+        reads = [call.x_lo + rd.j_off - j_lo for s_ in call.steps
+                 for rd in s_.reads if rd.src == name]
+        writes = [call.x_lo + call.steps[si].lead - j_lo
+                  for si in writers.get(name, ())]
+        m = _round_up(max(0, -min(reads + writes, default=0)), t)
+        if R > 1 and writes:
+            m += -(m + writes[0]) % t
+        ends = [m]
+        if ispec is not None:
+            in_h = nj + ispec.j_hi - ispec.j_lo
+            ends.append(m + (in_h if R == 1 else
+                             -(-in_h // block[ispec.name]) * block[ispec.name]))
+        if writes:
+            ends.append((steps - 1) * R + m + max(writes) + R)
+        if reads and R == 1:
+            ends.append(steps + m + max(reads))
+        elif reads:
+            lo = (m + min(reads)) // t * t
+            span[name] = (lo, _round_up(m + max(reads) + R - lo, t))
+            ends.append((steps - 1) * R + sum(span[name]))
+        margin[name] = m
+        height[name] = _round_up(max(ends), t)
+    return RowGeometry(R, t, steps, SUBLANE if R == 1 else R, block, first,
+                       last, lead, halo, margin, span, height)
+
+
+def _row_tileable(call: CallPlan, double_buffer: bool, tile: int) -> bool:
+    """Whether a grid step of ``call`` may compute several rows at once.
+
+    Not with accumulators: folding R rows into a carried row per step
+    would reorder its floating-point combines.  Not with the explicit
+    one-row DMA pipeline (``double_buffer``).  Not where a step reads a
+    window row that a later step of the same grid step writes (a row
+    recurrence), nor where a plane window's writers store rows at
+    offsets that no one row tile aligns."""
+    if double_buffer or call.accs:
+        return False
+    writers = _writers(call)
+    windows = {w.name: w for w in call.windows}
+    for si, step in enumerate(call.steps):
+        for rd in step.reads:
+            w = windows.get(rd.src)
+            if w is None or (w.plane and rd.p_off != w.p_lead):
+                continue
+            if not writers.get(rd.src) or writers[rd.src][-1] >= si:
+                return False
+    return all(len({(call.steps[si].lead - w.j_lo) % tile
+                    for si in writers.get(w.name, ())}) <= 1
+               for w in windows.values() if w.plane)
+
+
+def row_tile(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
+             double_buffer: bool) -> int:
+    """Rows R that each grid step of ``call`` computes at row count
+    ``nj`` and lane count ``ni``.
+
+    1 where :func:`_row_tileable` says rows must go one at a time.
+    Otherwise the largest multiple of the sublane tile that is at most
+    :data:`ROW_TILE_CAP` and the grid's rows rounded up to 8, that
+    starts every array input's block on a multiple of R (an input whose
+    first row ``x_lo + lead - j_lo`` is positive must be such a
+    multiple; one at or below 0 is brought in ahead), and whose VMEM
+    need (:func:`call_vmem`) leaves the compiler's default scoped limit
+    in place; the smallest such R where none does."""
+    t = row_tile_unit(dtype_bytes)
+    if not _row_tileable(call, double_buffer, t):
+        return 1
+    top = min(ROW_TILE_CAP, _round_up(nj + call.x_hi_off - call.x_lo,
+                                      SUBLANE))
+    starts = [call.x_lo + i.lead - i.j_lo for i in call.inputs
+              if not i.scalar]
+    fits = [R for R in range(t, top + 1, t)
+            if all(s <= 0 or s % R == 0 for s in starts)]
+    for R in reversed(fits):
+        need = call_vmem(call, nj, ni, dtype_bytes, False, rows=R)
+        if scoped_vmem_limit(need["total"]) is None:
+            return R
+    return fits[0] if fits else 1
+
+
+def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
+              double_buffer: bool, rows: Optional[int] = None) -> dict:
+    """Per-buffer VMEM bytes of one call, plus their ``"total"``,
+    mirroring the interpreter's allocation (``build_call``) at row tile
+    ``rows`` (default :func:`row_tile`'s): rolling windows of
+    ``R + stages - 1`` rows, plane windows ``p_stages`` planes with
+    their row margins (:func:`row_geometry`), accumulators one padded
+    row, and the two buffers of every stream block (array inputs,
+    either the pipeline's or the explicit DMA slots, and row outputs:
+    R rows, 8 when R is 1; accumulator outputs: 8 rows).  Rows are
+    padded to the sublane tile, lanes to 128."""
+    ib = int(dtype_bytes)
+    if rows is None:
+        rows = row_tile(call, nj, ni, ib, double_buffer)
+    geo = row_geometry(call, nj, rows, ib)
+
+    def sub(n):
         return _pad_rows(n, ib)
 
     report: dict = {}
     arr_ins = [i for i in call.inputs if not i.scalar]
     for i in arr_ins:
-        in_w = ni + i.i_hi - i.i_lo + i.align_pad
-        if i.plane:
-            in_h = nj + i.j_hi - i.j_lo
-            report[f"in_{i.name}"] = \
-                i.p_stages * rows(in_h) * _pad_to_lane(in_w) * ib
-        else:
-            report[f"in_{i.name}"] = \
-                rows(i.stages) * _pad_to_lane(in_w) * ib
+        in_w = _pad_to_lane(ni + i.i_hi - i.i_lo + i.align_pad)
+        planes = i.p_stages if i.plane else 1
+        report[f"in_{i.name}"] = \
+            planes * sub(geo.height[f"in_{i.name}"]) * in_w * ib
         blk = "dma" if double_buffer else "blk"
-        report[f"{blk}_{i.name}"] = 2 * rows(SUBLANE) * _pad_to_lane(in_w) * ib
+        report[f"{blk}_{i.name}"] = 2 * sub(geo.block[i.name]) * in_w * ib
     for w in call.windows:
         width = _pad_to_lane(ni + w.i_hi - w.i_lo + w.align_pad)
-        if w.plane:
-            report[w.name] = w.p_stages * rows(nj + w.j_hi - w.j_lo) \
-                * width * ib
-        else:
-            report[w.name] = rows(w.stages) * width * ib
+        planes = w.p_stages if w.plane else 1
+        report[w.name] = planes * sub(geo.height[w.name]) * width * ib
     for a in call.accs:
-        report[a.name] = rows(1) * _pad_to_lane(ni + a.w_off) * ib
+        report[a.name] = sub(1) * _pad_to_lane(ni + a.w_off) * ib
     for v in call.vloads:
         report[f"vec:{v.name}"] = \
             (v.carry + 1) * _pad_to_lane(ni + v.w_off) * ib
     acc_w = {a.name: ni + a.w_off for a in call.accs}
     for o in call.outputs:
-        width = acc_w[o.acc] if o.acc is not None else ni
-        report[f"out_{o.name}"] = 2 * rows(SUBLANE) * _pad_to_lane(width) * ib
+        if o.acc is not None:
+            report[f"out_{o.name}"] = \
+                2 * sub(SUBLANE) * _pad_to_lane(acc_w[o.acc]) * ib
+        else:
+            report[f"out_{o.name}"] = \
+                2 * sub(geo.out_block) * _pad_to_lane(ni) * ib
     report["total"] = sum(report.values())
     return report
 
@@ -864,7 +1028,9 @@ def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
     """Symbolic per-nest VMEM formulas for ``explain(verbose=True)``:
     one line per resident buffer with the padded shape algebra
     (``sub`` rounds rows up to the sublane tile, ``pad`` lanes up to
-    128), usable without concrete sizes."""
+    128), usable without concrete sizes.  ``R`` is the rows a grid step
+    computes (:func:`row_tile`, 1 where R-row steps do not apply) and
+    ``m`` a plane window's row margins (:func:`row_geometry`)."""
     lines: list[str] = []
     ib = int(dtype_bytes)
     for call in kplan.calls:
@@ -878,28 +1044,30 @@ def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
             if i.plane:
                 lines.append(
                     f"    in_{i.name}: {i.p_stages} x "
-                    f"sub(Nj{i.j_hi - i.j_lo:+d}) x {w} x {ib}B")
+                    f"sub(Nj{i.j_hi - i.j_lo:+d}+m) x {w} x {ib}B")
             else:
                 lines.append(
-                    f"    in_{i.name}: sub({i.stages}) x {w} x {ib}B")
-            lines.append(f"    stream {i.name}: 2 x {SUBLANE} x {w} x {ib}B")
+                    f"    in_{i.name}: sub(R+{i.stages - 1}) x {w} x {ib}B")
+            lines.append(f"    stream {i.name}: 2 x R x {w} x {ib}B")
         for wp in call.windows:
             w = f"pad(Ni{wp.i_hi - wp.i_lo:+d})"
             if wp.plane:
                 lines.append(
                     f"    {wp.name}: {wp.p_stages} x "
-                    f"sub(Nj{wp.j_hi - wp.j_lo:+d}) x {w} x {ib}B")
+                    f"sub(Nj{wp.j_hi - wp.j_lo:+d}+m) x {w} x {ib}B")
             else:
                 lines.append(
-                    f"    {wp.name}: sub({wp.stages}) x {w} x {ib}B")
+                    f"    {wp.name}: sub(R+{wp.stages - 1}) x {w} x {ib}B")
         for a in call.accs:
             lines.append(
                 f"    {a.name}: sub(1) x pad(Ni{a.w_off:+d}) x {ib}B")
         w_off = {a.name: a.w_off for a in call.accs}
         for o in call.outputs:
-            off = w_off[o.acc] if o.acc is not None else 0
-            lines.append(f"    out {o.name}: 2 x {SUBLANE} x "
-                         f"pad(Ni{off:+d}) x {ib}B")
+            if o.acc is not None:
+                lines.append(f"    out {o.name}: 2 x {SUBLANE} x "
+                             f"pad(Ni{w_off[o.acc]:+d}) x {ib}B")
+            else:
+                lines.append(f"    out {o.name}: 2 x R x pad(Ni+0) x {ib}B")
     return lines
 
 

@@ -10,53 +10,69 @@ state becomes the Pallas grid, and *all* rolling buffers — including the
 optional input-row window the paper mentions for COSMO — live in VMEM
 scratch that persists across sequential grid steps.
 
-The grid is ``(*outer, steps_j)``: the plan's outer :class:`GridDim`
-entries map one-to-one onto leading grid dimensions and the row dim onto
-the last, each covering its canonical range ``[lo, N_d + hi_off)`` —
-narrowed by halo'd goals and extended downward by plane-window warm-up
-tiles.  TPU grids execute sequentially with the last dimension fastest,
-which is exactly the fused nest's traversal order — VMEM scratch
-therefore carries state both across rows *and* across outer-tile
-boundaries.  Each grid step:
+The grid is ``(*outer, cdiv(steps_j, R))``: the plan's outer
+:class:`GridDim` entries map one-to-one onto leading grid dimensions and
+the row dim onto the last, each covering its canonical range
+``[lo, N_d + hi_off)`` — narrowed by halo'd goals and extended downward
+by plane-window warm-up tiles.  Each grid step computes a tile of R
+consecutive rows (:func:`repro.core.plancheck.row_tile`: a multiple of
+the sublane tile chosen from the plan, the dtype and the shape, or 1 for
+calls with accumulators and for ``double_buffer=True``).  TPU grids
+execute sequentially with the last dimension fastest, which is exactly
+the fused nest's traversal order — VMEM scratch therefore carries state
+both across row tiles *and* across outer-tile boundaries.  Grid step
+``jid`` covers canonical positions ``jid * R + x_lo`` onward and:
 
-1. takes exactly one new row per array input into that input's VMEM
-   window, ``lead`` rows ahead of the canonical point.  Rows arrive from
-   HBM in 8-row (sublane-tile) groups that consecutive grid steps
-   revisit — Mosaic refuses one-row blocks — either through the
-   BlockSpec index map, or, with ``double_buffer=True``, through an
-   explicitly double-buffered ``make_async_copy`` pair that prefetches
-   the next group while the current rows are being consumed.  Inputs
-   read at non-zero offsets in the *plane dim* (the outer identifier
-   adjacent to the row dim — ``u[k-1][j][i]`` stencils) use a
-   *multi-plane window* instead of a rolling row window:
-   ``(p_stages, rows, width)`` VMEM where whole planes stay resident across outer tiles and the streamed row lands in
-   the newest plane, ``p_lead`` tiles ahead (Fig. 9a/9b applied one loop
-   level further out);
-2. executes every fused step at its software-pipeline lead, reading
-   neighbor rows from VMEM windows via mod-``stages`` index arithmetic
-   (the functional form of the paper's pointer rotation, Fig. 9a/9b) —
-   and neighbor *planes* via mod-``p_stages`` plane slots.  Variables
-   *produced in the nest* and read at plane offsets write a **producer
-   plane window** (:class:`~repro.core.plan.WindowPlan` in plane mode):
-   the producing step runs ``p_lead`` tiles ahead in the plane dim and
-   seats each row at its absolute plane-row index (store predicated to
-   the plane's row extent), so ``v[k-1][j][i]``-style consumers read
-   older resident planes without a round-trip through HBM.  Reduction
-   steps combine into VMEM accumulator rows carried across grid steps
-   (the vector partial accumulators of Section 3.5), predicated on the
-   canonical point being inside the reduced extent (rows *and* outer
-   tiles) — carried across the whole grid or re-initialized per
-   kept-prefix tile (:attr:`~repro.core.plan.AccPlan.n_kept`); row-kept
-   reductions carry nothing and emit one identity-padded partial row per
-   step instead;
-3. writes one row per terminal output into its revisited 8-row output
-   block (the fill, then the value at its static lane offset), which
+1. takes R new rows per array input into that input's VMEM window,
+   ``lead`` rows ahead of the canonical point.  With R > 1 each step
+   brings one R-row block through the BlockSpec index map, the last
+   block of a ragged array partial; an input whose first row
+   ``x_lo + lead - j_lo`` is not on the tile is brought in that many
+   rows ahead.  One-row steps take their row from an 8-row
+   (sublane-tile) group that consecutive grid steps revisit — Mosaic
+   refuses one-row blocks — either through the BlockSpec index map, or,
+   with ``double_buffer=True``, through an explicitly double-buffered
+   ``make_async_copy`` pair that prefetches the next group while the
+   current row is being consumed.  Inputs read at non-zero offsets in
+   the *plane dim* (the outer identifier adjacent to the row dim —
+   ``u[k-1][j][i]`` stencils) use a *multi-plane window* instead of a
+   rolling row window: ``(p_stages, rows, width)`` VMEM where whole
+   planes stay resident across outer tiles and the streamed rows land
+   in the newest plane at their absolute row index, ``p_lead`` tiles
+   ahead (Fig. 9a/9b applied one loop level further out);
+2. executes every fused step at its software-pipeline lead over its R
+   rows, each rule applied row by row (``jax.vmap``), so every element
+   is made by the same arithmetic as with one row a step.  A rolling
+   window is a linear buffer of ``R + stages - 1`` rows: at the start of
+   a step the ``stages - 1`` rows that the step still reads move up
+   above the new rows (the paper's pointer rotation, Fig. 9a/9b, done as
+   one copy a tile), and every read is an ``(R, w)`` slice at a static
+   row offset.  Neighbor *planes* sit in mod-``p_stages`` plane slots,
+   rows at their absolute index below a top margin; an R-row step loads
+   each slot's rows once from an aligned row offset and slices its reads
+   out of them.  Variables *produced in the nest* and read at plane
+   offsets write a **producer plane window**
+   (:class:`~repro.core.plan.WindowPlan` in plane mode): the producing
+   step runs ``p_lead`` tiles ahead in the plane dim and seats its rows
+   at their absolute plane-row index, so ``v[k-1][j][i]``-style
+   consumers read older resident planes without a round-trip through
+   HBM.  Reduction steps (one-row steps only) combine into VMEM
+   accumulator rows carried across grid steps (the vector partial
+   accumulators of Section 3.5), predicated on the canonical point being
+   inside the reduced extent (rows *and* outer tiles) — carried across
+   the whole grid or re-initialized per kept-prefix tile
+   (:attr:`~repro.core.plan.AccPlan.n_kept`); row-kept reductions carry
+   nothing and emit one identity-padded partial row per position
+   instead;
+3. writes its rows of each terminal output as one ``(R, ni)`` store (the
+   fill, then the values at their static lane offset) into its output
+   block, R rows, or the revisited 8-row block of one-row steps, which
    goes back to HBM when the grid moves to the next block; accumulator
-   outputs are dumped into a revisited block whose final grid step
-   (per kept tile) holds the fully-combined partial-accumulator row.
+   outputs are dumped into a revisited block whose final grid step (per
+   kept tile) holds the fully-combined partial-accumulator row.
 
 Rolling windows are padded to the 128-wide TPU lane tile (the
-vector-length expansion of Fig. 9c).  Warm-up/drain grid steps compute
+vector-length expansion of Fig. 9c).  Warm-up/drain rows compute
 garbage rows into padded outputs that :func:`execute_plan`'s host layer
 slices away — the masked steady-state ('HFAV + Tuning') form.
 
@@ -87,13 +103,14 @@ from ...core.interpreters import (InterpreterSpec, register_interpreter,
                                   require_hazard_free, require_linked_fns)
 from ...core.plan import (PLAN_FEATURES, CallPlan, KernelPlan,
                           PallasUnsupported, WindowPlan)
-from ...core.plancheck import VMEM_CAPACITY, call_vmem, scoped_vmem_limit
+from ...core.plancheck import (VMEM_CAPACITY, call_vmem, row_geometry,
+                               row_tile, scoped_vmem_limit)
 
 LANE = 128
-#: Rows per streamed block: one f32 sublane tile.  Mosaic accepts a
-#: block only when its last two dims are multiples of (8, 128) or equal
-#: to the array's, so rows move between HBM and VMEM in 8-row groups
-#: that consecutive grid steps revisit.
+#: Rows per streamed block of one-row grid steps: one f32 sublane tile.
+#: Mosaic accepts a block only when its last two dims are multiples of
+#: (8, 128) or equal to the array's, so those rows move between HBM and
+#: VMEM in 8-row groups that consecutive grid steps revisit.
 SUBLANE = 8
 
 
@@ -106,6 +123,13 @@ def _mod(pos, stages: int):
     return jax.lax.rem(jax.lax.rem(pos, stages) + stages, stages)
 
 
+def _per_row(fn, ins):
+    """Apply a rule written for one row to R rows: ``jax.vmap`` over the
+    row axis of every 2-D operand, scalars shared."""
+    axes = tuple(0 if jnp.ndim(v) == 2 else None for v in ins)
+    return jax.vmap(fn, in_axes=axes)(*ins)
+
+
 def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                interpret: bool = False, double_buffer: bool = False):
     """Concretize one :class:`CallPlan` for a problem size and build the
@@ -115,20 +139,25 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     outer extents (``(Nj, Ni)`` for a plain 2-D nest).  Returns
     ``(fn, steps_j)``; the call maps the input arrays to one padded
     output per ``call.outputs`` entry (a list when there are several).
-    Row outputs are ``(*grid, rows, ni)`` with ``rows`` the grid's
-    ``steps_j`` rounded up to a whole sublane tile; row ``t`` holds
+    Row outputs are ``(*grid, rows, ni)`` with ``rows`` the ``steps_j``
+    canonical rows rounded up to a whole sublane tile; row ``t`` holds
     iteration position ``t + x_lo + out.lead``.  Carried-accumulator
     outputs are ``(1, width)`` and kept-prefix accumulator outputs
     ``(*kept grid, 1, width)``.
 
-    Each grid step computes one row.  Array inputs and row outputs move
-    in 8-row blocks that consecutive grid steps revisit: the step reads
-    (or writes) its row at ``row % 8`` inside the block.
-    ``double_buffer=True`` replaces the BlockSpec input streaming with an
-    explicit two-slot async-DMA pipeline: array inputs stay in HBM
+    Each grid step computes a tile of R rows
+    (:func:`repro.core.plancheck.row_tile`, from the plan, the dtype and
+    the shape), so the row grid dim has ``cdiv(steps_j, R)`` steps and
+    the last row block may be partial.  Array inputs and row outputs
+    move in R-row blocks, one per step.  Where R is 1 (calls with
+    accumulators, or ``double_buffer=True``) they move in 8-row blocks
+    that consecutive grid steps revisit, the step reading (or writing)
+    its row at ``row % 8`` inside the block.  ``double_buffer=True``
+    replaces the BlockSpec input streaming with an explicit two-slot
+    async-DMA pipeline: array inputs stay in HBM
     (``memory_space=ANY``); whenever the next grid step needs a new
     8-row group, the current step starts its copy into the other slot,
-    so the input DMA overlaps the compute of the current rows.
+    so the input DMA overlaps the compute of the current row.
 
     The VMEM the call needs (:func:`repro.core.plancheck.call_vmem`) is
     passed on as the compiler's scoped VMEM limit when it exceeds the
@@ -147,7 +176,10 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     gsz = [outer_sizes[d] + o_hi[d] - o_lo[d] for d in range(n_out)]
     steps_j = (nj + call.x_hi_off) - call.x_lo
     out_rows = pl.cdiv(steps_j, SUBLANE) * SUBLANE
-    total_steps = steps_j
+    itemsize = jnp.dtype(dtype).itemsize
+    R = row_tile(call, nj, ni, itemsize, double_buffer)
+    geo = row_geometry(call, nj, R, itemsize)
+    total_steps = geo.steps
     for s in gsz:
         total_steps *= s
 
@@ -157,15 +189,18 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     roll_wins = [WindowPlan(f"in_{i.name}", i.stages, i.i_lo, i.i_hi)
                  for i in row_ins] + [w for w in call.windows if not w.plane]
     plane_wins = [w for w in call.windows if w.plane]
-    bwidth = {w.name: ni + (w.i_hi - w.i_lo) for w in roll_wins + plane_wins}
-    win_h = {w.name: nj + (w.j_hi - w.j_lo) for w in plane_wins}
+    roll_lo = {w.name: w.i_lo for w in roll_wins}
+    pwin_of = {w.name: w for w in plane_wins}
     acc_w = {a.name: ni + a.w_off for a in call.accs}
     ref_idx = {ispec.name: k for k, ispec in enumerate(call.inputs)}
-    ispec_of = {i.name: i for i in arr_ins}
     in_h = {i.name: nj + (i.j_hi - i.j_lo) for i in arr_ins}
     in_w = {i.name: ni + (i.i_hi - i.i_lo) for i in arr_ins}
-    # rows per streamed input block (the whole array when it is shorter)
-    rb = {i.name: min(SUBLANE, in_h[i.name]) for i in arr_ins}
+    # plane buffers by read/write name: (j_lo, i_lo, p_stages)
+    plane_src = {f"in_{i.name}": (i.j_lo, i.i_lo, i.p_stages)
+                 for i in plane_ins}
+    plane_src.update({w.name: (w.j_lo, w.i_lo, w.p_stages)
+                      for w in plane_wins})
+    rb = geo.block
     # lanes per explicit DMA: Mosaic copies whole lane tiles, reaching
     # into the lane padding of the TPU's tiled HBM layout, which the
     # interpreter's arrays do not have
@@ -173,10 +208,12 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     n_scratch = len(roll_wins) + len(plane_ins) + len(plane_wins) \
         + len(call.accs)
 
-    def _row_pos(ispec, x):
-        """Source row index of ``ispec`` for canonical position ``x``
-        (clamped: edge rows repeat during warm-up/drain)."""
-        return jnp.clip(x + ispec.lead - ispec.j_lo, 0, in_h[ispec.name] - 1)
+    def _src_row(ispec, j_id):
+        """First source row of ``ispec``'s block for row-grid step
+        ``j_id`` (clamped: warm-up and drain steps fetch edge blocks,
+        whose rows feed only positions the host trims away)."""
+        return jnp.clip(j_id * R + geo.first_row[ispec.name], 0,
+                        geo.last_row[ispec.name])
 
     def _outer_src(ispec, pos):
         """Source indices for the input's own outer dims at canonical
@@ -197,14 +234,15 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         return idxs
 
     def _group(ispec, pos_outer, j_id):
-        """Where ``ispec``'s row for one grid step sits in HBM: its outer
-        source indices, the first row of its ``rb``-row group and the
-        row's offset inside the group.  On the TPU groups start on a
-        sublane tile, so the last one of an array whose rows are not a
-        multiple of 8 reaches into the tile padding, as the BlockSpec
-        pipeline's own ragged last block does; the interpreter, whose
-        arrays have no padding, shifts that group up instead."""
-        r = _row_pos(ispec, j_id + call.x_lo)
+        """Where ``ispec``'s row for one grid step sits in HBM (one-row
+        steps): its outer source indices, the first row of its
+        ``rb``-row group and the row's offset inside the group.  On the
+        TPU groups start on a sublane tile, so the last one of an array
+        whose rows are not a multiple of 8 reaches into the tile
+        padding, as the BlockSpec pipeline's own ragged last block does;
+        the interpreter, whose arrays have no padding, shifts that group
+        up instead."""
+        r = _src_row(ispec, j_id)
         n = rb[ispec.name]
         start = r - jax.lax.rem(r, n)
         if interpret:
@@ -216,8 +254,8 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     def _decode(lin):
         """Canonical outer positions and row-grid index of linear grid
         step ``lin`` (TPU grids run with the last dimension fastest)."""
-        j_id = jax.lax.rem(lin, steps_j)
-        rest = jax.lax.div(lin, steps_j)
+        j_id = jax.lax.rem(lin, geo.steps)
+        rest = jax.lax.div(lin, geo.steps)
         pos = [None] * n_out
         for d in reversed(range(n_out)):
             pos[d] = jax.lax.rem(rest, gsz[d]) + o_lo[d]
@@ -229,11 +267,10 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         in_refs = refs[:nin]
         o_refs = refs[nin:nin + len(call.outputs)]
         scratch = refs[nin + len(call.outputs):]
-        ref_of = {w.name: (r, w) for r, w in zip(scratch, roll_wins)}
-        plane_of = {i.name: r for i, r in
-                    zip(plane_ins, scratch[len(roll_wins):])}
-        pwin_of = {w.name: (r, w) for r, w in zip(
-            scratch[len(roll_wins) + len(plane_ins):], plane_wins)}
+        roll_of = dict(zip((w.name for w in roll_wins), scratch))
+        plane_of = dict(zip(
+            [f"in_{i.name}" for i in plane_ins] + [w.name for w in plane_wins],
+            scratch[len(roll_wins):]))
         acc_of = {a.name: (r, a) for r, a in zip(
             scratch[len(roll_wins) + len(plane_ins) + len(plane_wins):],
             call.accs)}
@@ -241,23 +278,30 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         outer_ids = [pl.program_id(d) for d in range(n_out)]
         opos = [outer_ids[d] + o_lo[d] for d in range(n_out)]
         jid = pl.program_id(n_out)
-        x = jid + call.x_lo
+        # this step's first canonical position, and the row-grid offset
+        # of its R rows (a multiple of R, for aligned plane accesses)
+        x0 = jid * R + call.x_lo
+        base = jid if R == 1 else pl.multiple_of(jid * R, R)
 
-        def _store_window(ispec, row, pos_outer, xx):
-            """Seat one freshly-streamed row: rolling row windows rotate
-            by mod-``stages`` position arithmetic; plane windows place
-            the row at its absolute array index inside the newest plane
-            (``p_lead`` tiles ahead, mod-``p_stages`` plane slot)."""
+        def _plane_slot(name, p_off):
+            return _mod(opos[n_out - 1] + p_off, plane_src[name][2])
+
+        def _store_rows(ispec, rows, src_row):
+            """Seat a step's freshly-streamed rows: below the kept halo
+            of a rolling row window, or at their absolute row index in
+            the newest plane of a plane window (``p_lead`` tiles
+            ahead)."""
+            name = f"in_{ispec.name}"
+            cols = pl.ds(0, in_w[ispec.name])
             if ispec.plane:
-                slot = _mod(pos_outer[n_out - 1] + ispec.p_lead,
-                            ispec.p_stages)
-                plane_of[ispec.name][
-                    slot, _row_pos(ispec, xx),
-                    pl.ds(0, in_w[ispec.name])] = row
+                at = geo.margin[name] + src_row
+                if R > 1:
+                    at = pl.multiple_of(at, geo.tile)
+                plane_of[name][_plane_slot(name, ispec.p_lead),
+                               pl.ds(at, rows.shape[0]), cols] = rows
             else:
-                ref, w = ref_of[f"in_{ispec.name}"]
-                ref[_mod(xx + ispec.lead, w.stages),
-                    pl.ds(0, bwidth[w.name])] = row
+                roll_of[name][pl.ds(geo.halo[name][1], rows.shape[0]),
+                              cols] = rows
 
         # 0. identity-initialize accumulators: carried accumulators
         # (n_kept == 0) once on the very first grid step, kept-prefix
@@ -270,9 +314,17 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             @pl.when(first)
             def _init_acc(_a=a):
                 r, _ = acc_of[_a.name]
-                r[0, :] = jnp.full((r.shape[1],), _a.init, dtype)
+                r[pl.ds(0, 1), :] = jnp.full((1, r.shape[1]), _a.init, dtype)
 
-        # 1. stream one new row per array input into its VMEM window
+        # 1a. rolling windows: the rows the previous step computed last
+        # that this step still reads move up above the new rows
+        for name, ref in roll_of.items():
+            keep, at = geo.halo[name]
+            if keep:
+                ref[pl.ds(at - keep, keep), :] = \
+                    ref[pl.ds(at + R - keep, keep), :]
+
+        # 1b. stream each array input's new rows into its VMEM window
         if double_buffer and arr_ins:
             dma_stage = dict(zip(
                 (i.name for i in arr_ins),
@@ -283,7 +335,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             # execution order; the neighbours decide whether the row
             # group changes at this step (wait) or at the next (prefetch).
             lin = jid
-            mult = steps_j
+            mult = geo.steps
             for d in reversed(range(n_out)):
                 lin = lin + outer_ids[d] * mult
                 mult *= gsz[d]
@@ -334,69 +386,81 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                 def _prefetch(_ai=ai, _sp=ispec, _g=ahead, _s=slot):
                     _copy(_ai, _sp, _g, 1 - _s).start()
 
-                row = dma_stage[ispec.name][
+                rows = dma_stage[ispec.name][
                     (slot,) + (0,) * ispec.n_outer
-                    + (here[2], pl.ds(0, in_w[ispec.name]))]
-                _store_window(ispec, row, opos, x)
+                    + (pl.ds(here[2], 1), pl.ds(0, in_w[ispec.name]))]
+                _store_rows(ispec, rows, here[1] + here[2])
         else:
             for ispec in arr_ins:
                 src = in_refs[ref_idx[ispec.name]]
-                r = jax.lax.rem(_row_pos(ispec, x), rb[ispec.name])
-                row = src[(0,) * ispec.n_outer + (r, slice(None))]
-                _store_window(ispec, row, opos, x)
+                r = _src_row(ispec, jid)
+                if R == 1:
+                    rows = src[(0,) * ispec.n_outer
+                               + (pl.ds(jax.lax.rem(r, rb[ispec.name]), 1),
+                                  slice(None))]
+                else:
+                    rows = src[(0,) * ispec.n_outer
+                               + (slice(None), slice(None))]
+                _store_rows(ispec, rows, r)
 
-        # 2. fused steps, in dataflow order, at their leads
+        # 2. fused steps, in dataflow order, at their leads, each over
+        # the step's R rows
         local: dict[str, jnp.ndarray] = {}
+        loaded: dict = {}  # (plane buffer, p_off) -> its rows of this step
+
+        def _plane_read(name, rd, w):
+            """``(R, w)`` rows of a plane buffer for one read: absolute
+            rows of the plane slot at ``rd.p_off``.  One-row steps load
+            the row directly; R-row steps load each slot's aligned read
+            span once and slice every read out of it."""
+            j_lo, i_lo, _ = plane_src[name]
+            slot = _plane_slot(name, rd.p_off)
+            row = geo.margin[name] + call.x_lo + rd.j_off - j_lo
+            col = rd.col0 - i_lo
+            if R == 1:
+                return plane_of[name][slot, pl.ds(base + row, 1),
+                                      pl.ds(col, w)]
+            first, n = geo.span[name]
+            key = (name, rd.p_off)
+            if key not in loaded:
+                loaded[key] = plane_of[name][slot, pl.ds(
+                    pl.multiple_of(base + first, geo.tile), n), :]
+            return loaded[key][row - first:row - first + R, col:col + w]
+
         for step in call.steps:
             ins = []
             cur = None
             if step.acc is not None:
                 aref, _ = acc_of[step.acc]
-                cur = aref[0, pl.ds(0, acc_w[step.acc])]
+                cur = aref[pl.ds(0, 1), pl.ds(0, acc_w[step.acc])]
                 ins.append(cur)
             for rd in step.reads:
                 w = ni + rd.w_off
                 if rd.src.startswith("local:"):
-                    lrow = local[rd.src[6:]]
-                    ins.append(jax.lax.slice(lrow, (rd.col0,), (rd.col0 + w,)))
+                    ins.append(local[rd.src[6:]][:, rd.col0:rd.col0 + w])
                 elif rd.src.startswith("scalar:"):
                     sref = in_refs[ref_idx[rd.src[7:]]]
                     ins.append(sref[0, 0])
-                elif rd.src.startswith("in_") and \
-                        ispec_of.get(rd.src[3:]) is not None and \
-                        ispec_of[rd.src[3:]].plane:
-                    # streamed plane-window read: plane slot by mod-stage
-                    # rotation in the plane dim, absolute row inside it
-                    ispec = ispec_of[rd.src[3:]]
-                    slot = _mod(opos[n_out - 1] + rd.p_off, ispec.p_stages)
-                    r_idx = jnp.clip(x + rd.j_off - ispec.j_lo, 0,
-                                     in_h[ispec.name] - 1)
-                    ins.append(plane_of[ispec.name][
-                        slot, r_idx, pl.ds(rd.col0 - ispec.i_lo, w)])
-                elif rd.src in pwin_of:
-                    # producer plane-window read: older planes resident,
-                    # rows addressed absolutely (clamped on warm-up)
-                    pref, pw = pwin_of[rd.src]
-                    slot = _mod(opos[n_out - 1] + rd.p_off, pw.p_stages)
-                    r_idx = jnp.clip(x + rd.j_off - pw.j_lo, 0,
-                                     win_h[pw.name] - 1)
-                    ins.append(pref[slot, r_idx,
-                                    pl.ds(rd.col0 - pw.i_lo, w)])
+                elif rd.src in plane_src:
+                    ins.append(_plane_read(rd.src, rd, w))
                 else:
-                    ref, b = ref_of[rd.src]
-                    stage = _mod(x + rd.j_off, b.stages)
-                    ins.append(ref[stage, pl.ds(rd.col0 - b.i_lo, w)])
-            vals = call.fns[step.fn_idx](*ins)
+                    # rolling window: static rows relative to the newest
+                    row = geo.halo[rd.src][1] + rd.j_off - geo.lead[rd.src]
+                    ins.append(roll_of[rd.src][
+                        pl.ds(row, R), pl.ds(rd.col0 - roll_lo[rd.src], w)])
+            vals = _per_row(call.fns[step.fn_idx], ins)
             if step.acc is not None:
-                # predicated combine: warm-up/drain rows *and* tiles
-                # must not pollute
+                # predicated combine, one mask row per position:
+                # warm-up/drain rows *and* tiles must not pollute
                 lo, hi = step.valid
-                pos = x + step.lead
+                pos = x0 + step.lead + jax.lax.broadcasted_iota(
+                    jnp.int32, (R, 1), 0)
                 ok = (pos >= lo) & (pos < nj + hi)
                 for d, (vlo, vhi) in enumerate(step.valid_outer):
                     ok &= (opos[d] >= vlo) & (opos[d] < outer_sizes[d] + vhi)
                 aref, _ = acc_of[step.acc]
-                aref[0, pl.ds(0, acc_w[step.acc])] = jnp.where(ok, vals, cur)
+                aref[pl.ds(0, 1), pl.ds(0, acc_w[step.acc])] = \
+                    jnp.where(ok, vals, cur)
                 continue
             if len(step.writes) == 1:
                 vals = (vals,)
@@ -404,31 +468,36 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                 for wkind, wtgt in targets:
                     if wkind == "local":
                         local[str(wtgt)] = val
-                    elif wkind == "buf" and str(wtgt) in pwin_of:
+                    elif wkind == "buf" and str(wtgt) in plane_src:
                         # producer plane window: the newest plane slot
-                        # (p_lead tiles ahead), absolute row seating,
-                        # predicated to the plane's row extent
-                        pref, pw = pwin_of[str(wtgt)]
-                        slot = _mod(opos[n_out - 1] + pw.p_lead,
-                                    pw.p_stages)
-                        r_idx = x + step.lead - pw.j_lo
-
-                        @pl.when((r_idx >= 0) & (r_idx < win_h[pw.name]))
-                        def _seat(_p=pref, _s=slot, _r=r_idx, _v=val,
-                                  _c=step.out_col0 - pw.i_lo):
-                            _p[_s, _r, pl.ds(_c, _v.shape[0])] = _v
+                        # (p_lead tiles ahead), rows at their absolute
+                        # index below the window's top margin
+                        name = str(wtgt)
+                        pw = pwin_of[name]
+                        at = geo.margin[name] + call.x_lo + step.lead \
+                            - pw.j_lo + base
+                        if R > 1:
+                            at = pl.multiple_of(at, geo.tile)
+                        plane_of[name][
+                            _plane_slot(name, pw.p_lead), pl.ds(at, R),
+                            pl.ds(step.out_col0 - pw.i_lo, val.shape[1])] \
+                            = val
+                        for key in [k for k in loaded if k[0] == name]:
+                            del loaded[key]
                     elif wkind == "buf":
-                        ref, b = ref_of[str(wtgt)]
-                        stage = _mod(x + step.lead, b.stages)
-                        ref[stage, pl.ds(step.out_col0 - b.i_lo,
-                                         val.shape[0])] = val
-                    else:  # 3. one output row for this grid step: the
-                        # fill, then the value at its static lane offset
+                        name = str(wtgt)
+                        row = geo.halo[name][1] + step.lead - geo.lead[name]
+                        roll_of[name][pl.ds(row, R),
+                                      pl.ds(step.out_col0 - roll_lo[name],
+                                            val.shape[1])] = val
+                    else:  # 3. the step's output rows: the fill, then
+                        # the values at their static lane offset
                         oref = o_refs[int(wtgt)]
-                        orow = (0,) * n_out + (jax.lax.rem(jid, SUBLANE),)
-                        oref[orow + (slice(None),)] = jnp.full(
-                            (ni,), call.outputs[int(wtgt)].fill, val.dtype)
-                        oref[orow + (pl.ds(step.out_col0, val.shape[0]),)] \
+                        at = (0,) * n_out + (pl.ds(
+                            0 if R > 1 else jax.lax.rem(jid, SUBLANE), R),)
+                        oref[at + (slice(None),)] = jnp.full(
+                            (R, ni), call.outputs[int(wtgt)].fill, val.dtype)
+                        oref[at + (pl.ds(step.out_col0, val.shape[1]),)] \
                             = val
 
         # 3b. dump accumulators into their revisited output blocks: the
@@ -437,10 +506,10 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         for oi, out in enumerate(call.outputs):
             if out.acc is not None:
                 aref, a = acc_of[out.acc]
-                row = aref[0, pl.ds(0, acc_w[out.acc])]
-                o_refs[oi][(0,) * (a.n_kept + 1) + (slice(None),)] = row
+                o_refs[oi][(0,) * a.n_kept + (pl.ds(0, 1), slice(None))] = \
+                    aref[pl.ds(0, 1), pl.ds(0, acc_w[out.acc])]
 
-    grid = (*gsz, steps_j)
+    grid = (*gsz, geo.steps)
     in_specs = []
     out_specs = []
     out_shape = []
@@ -455,7 +524,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             (1,) * ispec.n_outer + (rb[ispec.name], in_w[ispec.name]),
             (lambda *ids, _sp=ispec:
              tuple(_outer_src(_sp, [ids[d] + o_lo[d] for d in range(n_out)]))
-             + (_row_pos(_sp, ids[n_out] + call.x_lo) // rb[_sp.name], 0)),
+             + (_src_row(_sp, ids[n_out]) // rb[_sp.name], 0)),
         ))
     for out in call.outputs:
         if out.acc is not None:
@@ -469,21 +538,22 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                 jax.ShapeDtypeStruct((*gsz[:k], 1, wa), dtype))
         else:
             out_specs.append(pl.BlockSpec(
-                (1,) * n_out + (SUBLANE, ni),
+                (1,) * n_out + (geo.out_block, ni),
                 lambda *ids: tuple(ids[:n_out])
-                + (ids[n_out] // SUBLANE, 0)))
+                + (ids[n_out] * R // geo.out_block, 0)))
             out_shape.append(
                 jax.ShapeDtypeStruct((*gsz, out_rows, ni), dtype))
 
     scratch_shapes = [
-        pltpu.VMEM((w.stages, _pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
+        pltpu.VMEM((geo.height[w.name],
+                    _pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
         for w in roll_wins
     ] + [
-        pltpu.VMEM((i.p_stages, in_h[i.name], _pad_to_lane(in_w[i.name])),
-                   dtype)
+        pltpu.VMEM((i.p_stages, geo.height[f"in_{i.name}"],
+                    _pad_to_lane(in_w[i.name])), dtype)
         for i in plane_ins
     ] + [
-        pltpu.VMEM((w.p_stages, win_h[w.name],
+        pltpu.VMEM((w.p_stages, geo.height[w.name],
                     _pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
         for w in plane_wins
     ] + [
@@ -498,8 +568,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         ]
         scratch_shapes.append(pltpu.SemaphoreType.DMA((len(arr_ins), 2)))
         scratch_shapes.append(pltpu.SMEM((len(arr_ins),), jnp.int32))
-    need = call_vmem(call, nj, ni, jnp.dtype(dtype).itemsize,
-                     double_buffer)["total"]
+    need = call_vmem(call, nj, ni, itemsize, double_buffer, rows=R)["total"]
     if need > VMEM_CAPACITY:
         raise PallasUnsupported(
             f"call {call.name} needs {need} B of VMEM at sizes {sizes}; "

@@ -14,18 +14,20 @@ import jax.numpy as jnp
 DIM = {"i": 20, "j": 7, "k": 4, "l": 3}
 
 
-def sizes_for(kplan) -> dict:
-    """``{size symbol: int}`` for a plan under the standard test dims."""
-    return {sym: DIM.get(d, 3) for d, sym in kplan.dim_sizes}
+def sizes_for(kplan, dims: dict = DIM) -> dict:
+    """``{size symbol: int}`` for a plan under the loop dims ``dims``
+    (default the standard test dims)."""
+    return {sym: dims.get(d, 3) for d, sym in kplan.dim_sizes}
 
 
-def arrays_for(kplan, rng) -> dict:
-    """Synthesize one input array per axiom of ``kplan``.
+def arrays_for(kplan, rng, dims: dict = DIM) -> dict:
+    """Synthesize one input array per axiom of ``kplan`` at the loop
+    dims ``dims``.
 
     Shapes come from the plan's axiom extents (outermost dim first,
     ``size + hi - lo`` per dim); values are standard-normal float32 so
     cancellation bugs don't hide behind all-ones inputs."""
-    sizes = sizes_for(kplan)
+    sizes = sizes_for(kplan, dims)
     arrs = {}
     for ax in kplan.axioms:
         ext = {d: (sym, lo, hi) for d, sym, lo, hi in ax.extents}

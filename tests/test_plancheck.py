@@ -21,7 +21,7 @@ from repro.core import (KernelPlan, PlanCache, PlanCheckError,
 from repro.core.codegen_jax import Generated
 from repro.core.engine import _emit_plan
 from repro.core.plancheck import (DEFAULT_VMEM_BUDGET, Diagnostic,
-                                  resolve_check_mode, vmem_budget)
+                                  call_vmem, resolve_check_mode, vmem_budget)
 from repro.core.programs import ALL_PROGRAMS, heat3d_program
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -169,30 +169,37 @@ def test_sizes_from_arrays_matches_runtime_resolution():
 
 
 def test_vmem_bytes_mirrors_scratch_shapes():
-    """heat3d's only scratch is the 3-plane input window:
-    3 planes x 10 rows (padded to 16) x pad(200->256) lanes x 4 B; the
-    input and output stream two 8-row blocks each."""
+    """heat3d's only scratch is the 3-plane input window.  At Nj=10 each
+    grid step computes a 16-row tile (10 rows rounded up to the tile),
+    so a plane holds 24 rows: an 8-row top margin for the first step's
+    j-1 read and the step's aligned 24-row read span; x pad(200->256)
+    lanes x 4 B.  The input streams two 10-row blocks (padded to 16)
+    and the output two 16-row blocks."""
     kp = load_golden("heat3d")
     sizes = {"Nk": 8, "Nj": 10, "Ni": 200}
-    block = 2 * 8 * 256 * 4
-    assert vmem_bytes(kp, sizes) == 3 * 16 * 256 * 4 + 2 * block
+    block = 2 * 16 * 256 * 4
+    assert vmem_bytes(kp, sizes) == 3 * 24 * 256 * 4 + 2 * block
     rep = vmem_report(kp, sizes)
-    assert rep["heat3d_n0"]["in_u"] == 49152
+    assert rep["heat3d_n0"]["in_u"] == 73728
     assert rep["heat3d_n0"]["blk_u"] == block
     assert rep["heat3d_n0"]["out_heat_u"] == block
-    assert rep["heat3d_n0"]["total"] == 81920
+    assert rep["heat3d_n0"]["total"] == 139264
 
 
 def test_vmem_bytes_double_buffer_adds_staging():
     """The explicit two-slot DMA staging takes the place of the
-    pipeline's two input blocks, at the same size."""
+    pipeline's two input blocks, at the same size, for the one-row grid
+    steps that ``double_buffer`` keeps (R-row steps stream R-row
+    blocks instead)."""
     kp = load_golden("cosmo")
     sizes = sizes_from_arrays(kp, {"u": (4, 12, 100)})
     plain = vmem_report(kp, sizes)["cosmo_n0"]
     dbuf = vmem_report(kp, sizes, double_buffer=True)["cosmo_n0"]
-    assert dbuf["dma_u"] == plain["blk_u"] == 2 * 8 * 128 * 4
+    one_row = call_vmem(kp.calls[0], 12, 100, 4, False, rows=1)
+    assert dbuf["dma_u"] == one_row["blk_u"] == 2 * 8 * 128 * 4
+    assert plain["blk_u"] == 2 * 16 * 128 * 4
     assert "blk_u" not in dbuf and "dma_u" not in plain
-    assert dbuf["total"] == plain["total"]
+    assert dbuf["total"] == one_row["total"]
 
 
 def test_vmem_budget_resolution(monkeypatch):
@@ -283,8 +290,8 @@ def test_explain_verbose_renders_vmem():
     out = explain(heat3d_program(), verbose=True,
                   dim_sizes={"Nk": 8, "Nj": 10, "Ni": 200})
     assert "--- vmem estimate ---" in out
-    assert "in_u: 3 x sub(Nj+0) x pad(Ni+0) x 4B" in out
-    assert "81920 B resident" in out
+    assert "in_u: 3 x sub(Nj+0+m) x pad(Ni+0) x 4B" in out
+    assert "139264 B resident" in out
 
 
 # ---------------------------------------------------------------------------

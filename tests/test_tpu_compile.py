@@ -23,17 +23,34 @@ from repro.core.programs import ALL_PROGRAMS
 #: (program, {size symbol: int}, double_buffer): the main path's
 #: programs at Ni=1024, each streaming mode on a 2-D and a 3-D grid
 CASES = [
-    ("laplace5", {"Nj": 1024, "Ni": 1024}, False),
-    ("cosmo", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
-    ("heat3d", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
-    ("normalization", {"Nj": 1024, "Ni": 1024}, False),
-    ("hydro1d", {"Nj": 1024, "Ni": 1024}, False),
-    ("plane_sum", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
-    # rows off the tile: the last DMA group reaches into its padding
-    ("laplace5", {"Nj": 1020, "Ni": 1024}, True),
-    ("heat3d", {"Nk": 8, "Nj": 254, "Ni": 1024}, True),
-    # the second nest streams a 1023-wide intermediate: whole lane tiles
-    ("normalization", {"Nj": 1020, "Ni": 1024}, True),
+    pytest.param(name, sizes, db, id=f"{name}-db{int(db)}")
+    for name, sizes, db in [
+        ("laplace5", {"Nj": 1024, "Ni": 1024}, False),
+        ("cosmo", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
+        ("heat3d", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
+        ("normalization", {"Nj": 1024, "Ni": 1024}, False),
+        ("hydro1d", {"Nj": 1024, "Ni": 1024}, False),
+        ("plane_sum", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
+        # rows off the tile: the last DMA group reaches into its padding
+        ("laplace5", {"Nj": 1020, "Ni": 1024}, True),
+        ("heat3d", {"Nk": 8, "Nj": 254, "Ni": 1024}, True),
+        # the second nest streams a 1023-wide intermediate: whole lane tiles
+        ("normalization", {"Nj": 1020, "Ni": 1024}, True),
+    ]
+] + [
+    pytest.param(name, sizes, False, id=tag)
+    for tag, name, sizes in [
+        # the chip benchmark's two cells at their real shapes, as
+        # R-row tiles (cosmo's 774 rows end in a partial row block)
+        ("cosmo1-80x774x1158", "cosmo", {"Nk": 80, "Nj": 774, "Ni": 1158}),
+        ("heat3d-512cubed", "heat3d", {"Nk": 512, "Nj": 512, "Ni": 512}),
+        # rows not a multiple of the row tile, and rows fewer than it
+        ("laplace5-rows-ragged", "laplace5", {"Nj": 1020, "Ni": 1024}),
+        ("heat3d-rows-below-tile", "heat3d", {"Nk": 8, "Nj": 12, "Ni": 1024}),
+        # a producer plane window stored R rows at a time
+        ("heat3d_stage-db0", "heat3d_stage",
+         {"Nk": 8, "Nj": 256, "Ni": 1024}),
+    ]
 ]
 
 
@@ -65,8 +82,7 @@ def _inputs(prog, sizes, sharding, batch=()):
     return out
 
 
-@pytest.mark.parametrize("name,sizes,double_buffer", CASES,
-                         ids=[f"{n}-db{int(db)}" for n, _, db in CASES])
+@pytest.mark.parametrize("name,sizes,double_buffer", CASES)
 def test_kernel_compiles_for_v5e(one_chip, name, sizes, double_buffer):
     prog = ALL_PROGRAMS[name]()
     gen = compile_program(prog, backend="pallas", interpret=False,

@@ -149,10 +149,13 @@ def test_compile_program_spans_and_cache_counters(fresh_cache):
         jax.block_until_ready(step(u))
         jax.block_until_ready(step(u))
     # the kernel is built while JAX traces, once; the compiled calls run
-    # no span.  One grid step per (k, j) row of the 5x11x132 field.
+    # no span.  One grid step per k plane of the 5x11x132 field: its 11
+    # rows fit one 16-row tile.
     (build,) = rec.named("hfav.build_call")
-    assert build.attrs == {"call": "heat3d_n0", "grid_steps": 5 * 11}
-    assert rec.counters["hfav.grid_steps"] == 5 * 11
+    assert build.attrs == {"call": "heat3d_n0", "grid_steps": 5 * 1,
+                           "row_tile": 16}
+    assert rec.counters["hfav.grid_steps"] == 5 * 1
+    assert rec.counters["hfav.row_tile"] == 16
 
 
 def test_plan_disk_spans_and_hits(fresh_cache, tmp_path):
