@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from ..trace import count, span
 from .plan import (PLAN_FEATURES, CallPlan, KernelPlan, OutputPlan,
                    PallasUnsupported)
-from .plancheck import row_tile
+from .plancheck import call_vmem, row_tile, scoped_vmem_limit
 from .runtime import lane_reduce
 
 
@@ -381,16 +381,21 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
 
 
 def _grid_steps(call: CallPlan, n_outs: tuple[int, ...], nj: int, ni: int,
-                dtype, double_buffer: bool) -> tuple[int, int]:
-    """Grid steps of one stencil call and the rows R each computes
-    (:func:`repro.core.plancheck.row_tile`): ``cdiv(steps_j, R)`` row
-    steps times every outer grid dim's extent, as the Pallas
-    ``build_call`` lays out its grid."""
-    rows = row_tile(call, nj, ni, jnp.dtype(dtype).itemsize, double_buffer)
+                dtype, double_buffer: bool) -> tuple[int, int, int, int]:
+    """Grid steps of one stencil call, the rows R each computes
+    (:func:`repro.core.plancheck.row_tile`), the VMEM the call needs at
+    that R (:func:`repro.core.plancheck.call_vmem`) and the scoped limit
+    passed for it, 0 for the compiler's default
+    (:func:`repro.core.plancheck.scoped_vmem_limit`): ``cdiv(steps_j,
+    R)`` row steps times every outer grid dim's extent, as the Pallas
+    ``build_call`` lays out its grid and sizes its VMEM."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = row_tile(call, nj, ni, itemsize, double_buffer)
     steps = -(-(nj + call.x_hi_off - call.x_lo) // rows)
     for n, lo, hi in zip(n_outs, call.outer_lo, call.outer_hi_off):
         steps *= n + hi - lo
-    return steps, rows
+    need = call_vmem(call, nj, ni, itemsize, double_buffer, rows=rows)["total"]
+    return steps, rows, need, scoped_vmem_limit(need) or 0
 
 
 def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
@@ -411,12 +416,14 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     ``double_buffer`` are forwarded to ``build_call``; interpreters that
     don't honor a flag accept and ignore it.
 
-    The span ``hfav.build_call`` (attributes ``call``, ``grid_steps``
-    and ``row_tile``) and the counters ``hfav.grid_steps`` and
-    ``hfav.row_tile`` (the rows each grid step computes, added once per
-    built call; :mod:`repro.trace`) mark each stencil call as ``fn``'s
-    Python runs: under ``jax.jit`` once per trace, never per compiled
-    call."""
+    The span ``hfav.build_call`` (attributes ``call``, ``grid_steps``,
+    ``row_tile``, ``vmem_need_bytes`` and ``vmem_limit_bytes``) and the
+    counters ``hfav.grid_steps``, ``hfav.row_tile`` (the rows each grid
+    step computes), ``hfav.vmem_need_bytes`` (the VMEM model's need at
+    that row tile) and ``hfav.vmem_limit_bytes`` (the scoped VMEM limit
+    passed, 0 for the compiler's default), each added once per built
+    call (:mod:`repro.trace`), mark each stencil call as ``fn``'s Python
+    runs: under ``jax.jit`` once per trace, never per compiled call."""
     spec = get_interpreter(interpreter)
     interpret = resolve_interpret(interpret)
     check_capabilities(spec, kplan)
@@ -448,12 +455,15 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
             for hs in cp.host_pre:
                 _run_host(cp, hs, env)
             if cp.has_grid:
-                steps, rows = _grid_steps(cp, n_outs, nj, ni, dtype,
-                                          double_buffer)
+                steps, rows, need, limit = _grid_steps(
+                    cp, n_outs, nj, ni, dtype, double_buffer)
                 count("hfav.grid_steps", steps)
                 count("hfav.row_tile", rows)
+                count("hfav.vmem_need_bytes", need)
+                count("hfav.vmem_limit_bytes", limit)
                 with span("hfav.build_call", call=cp.name, grid_steps=steps,
-                          row_tile=rows):
+                          row_tile=rows, vmem_need_bytes=need,
+                          vmem_limit_bytes=limit):
                     pcall, _ = spec.build_call(cp, (*n_outs, nj, ni), dtype,
                                                interpret=interpret,
                                                double_buffer=double_buffer)

@@ -31,7 +31,8 @@ Four analyses over a validated :class:`~repro.core.plan.KernelPlan`:
    a kept output are constrained.
 3. **VMEM footprint estimate** — :func:`vmem_bytes` mirrors the
    interpreter's VMEM allocation (``build_call``'s scratch shapes and
-   the pipeline's 8-row stream blocks, padded to (8, 128) tiles) and
+   the pipeline's stream blocks, padded to (8, 128) tiles), plus the
+   values the step bodies hold at once (:func:`body_values`), and
    warns above a configurable budget (:data:`DEFAULT_VMEM_BUDGET`, the
    compiler's default scoped limit).  ``build_call`` passes a larger
    scoped limit (:func:`scoped_vmem_limit`) to a kernel that needs it.
@@ -70,6 +71,7 @@ contract).  CLI: ``scripts/plan_lint.py``.
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass, replace
 from typing import Optional, Sequence
@@ -936,6 +938,70 @@ def row_tile(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
     return fits[0] if fits else 1
 
 
+def body_values(call: CallPlan) -> int:
+    """The most values of one row each that the fused steps of ``call``
+    hold at once: a liveness count over the steps' bodies, traced in
+    step order.  A step holds its inputs (the rows it reads), its
+    temporaries until their last use and its outputs until they are
+    stored; a ``local`` row lives from the step that makes it to the
+    last step that reads it.  Scalars are not counted.  Each value is
+    ``(R, lanes)`` in the kernel, where the compiler keeps what the
+    vector registers cannot hold in VMEM of its own.  A step whose
+    function is not linked (a deserialized plan not yet re-linked)
+    counts its reads and outputs."""
+    return _body_values(call.steps, tuple(call.fns))
+
+
+@functools.lru_cache(maxsize=256)
+def _body_values(steps: tuple, fns: tuple) -> int:
+    last_read: dict = {}
+    for si, step in enumerate(steps):
+        for rd in step.reads:
+            if rd.src.startswith("local:"):
+                last_read[rd.src[6:]] = si
+    carried: set = set()
+    peak = 0
+    for si, step in enumerate(steps):
+        fn = fns[step.fn_idx] if step.fn_idx < len(fns) else None
+        peak = max(peak, len(carried) + _step_values(step, fn))
+        for targets in step.writes:
+            carried |= {str(t) for kind, t in targets if kind == "local"}
+        carried = {n for n in carried if last_read.get(n, -1) > si}
+    return peak
+
+
+def _step_values(step: StepPlan, fn) -> int:
+    """Most row values live at once inside one step's body."""
+    if fn is None:
+        return len(step.reads) + (step.acc is not None) + len(step.writes)
+    import jax
+    import jax.extend
+    import jax.numpy as jnp
+
+    row = jax.ShapeDtypeStruct((LANE,), jnp.float32)
+    args = [row] * (step.acc is not None) + [
+        jax.ShapeDtypeStruct((), jnp.float32) if rd.src.startswith("scalar:")
+        else row for rd in step.reads]
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+
+    def rows(vs):
+        return {v for v in vs if not isinstance(v, jax.extend.core.Literal)
+                and v.aval.ndim >= 1}
+
+    end = len(jaxpr.eqns)
+    last = {v: end for v in rows(jaxpr.outvars)}
+    for ei, e in enumerate(jaxpr.eqns):
+        for v in rows(e.invars):
+            last[v] = max(last.get(v, -1), ei)
+    live = rows(jaxpr.invars)
+    peak = len(live)
+    for ei, e in enumerate(jaxpr.eqns):
+        live |= rows(e.outvars)
+        peak = max(peak, len(live))
+        live = {v for v in live if last.get(v, -1) > ei}
+    return peak
+
+
 def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
               double_buffer: bool, rows: Optional[int] = None) -> dict:
     """Per-buffer VMEM bytes of one call, plus their ``"total"``,
@@ -946,7 +1012,9 @@ def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
     row, and the two buffers of every stream block (array inputs,
     either the pipeline's or the explicit DMA slots, and row outputs:
     R rows, 8 when R is 1; accumulator outputs: 8 rows).  Rows are
-    padded to the sublane tile, lanes to 128."""
+    padded to the sublane tile, lanes to 128.  ``"body"`` is what the
+    step bodies hold beside them: :func:`body_values` values of R rows
+    by ``Ni`` lanes."""
     ib = int(dtype_bytes)
     if rows is None:
         rows = row_tile(call, nj, ni, ib, double_buffer)
@@ -981,6 +1049,7 @@ def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
         else:
             report[f"out_{o.name}"] = \
                 2 * sub(geo.out_block) * _pad_to_lane(ni) * ib
+    report["body"] = body_values(call) * sub(rows) * _pad_to_lane(ni) * ib
     report["total"] = sum(report.values())
     return report
 
@@ -1068,6 +1137,8 @@ def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
                              f"pad(Ni{w_off[o.acc]:+d}) x {ib}B")
             else:
                 lines.append(f"    out {o.name}: 2 x R x pad(Ni+0) x {ib}B")
+        lines.append(f"    body: {body_values(call)} x sub(R) x pad(Ni+0) "
+                     f"x {ib}B")
     return lines
 
 

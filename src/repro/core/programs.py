@@ -10,10 +10,14 @@
   Section 5.3: ulapstage -> flux_x / flux_y -> ustage over (k, j, i) with
   no k dependencies.  HFAV contracts the Laplacian to a 3-row and the
   fluxes to 2-row rolling buffers.
-* :func:`hydro1d_program` — a dimensionally-split Godunov-style pass in the
-  spirit of Hydro2D's nine kernels (Section 5.4), simplified to a single
-  conserved system sweep: primitive conversion, EOS, slope limiting, trace,
-  Riemann solve at interfaces, flux, conservative update.
+* :func:`hydro2d_program` — Section 5.4's Hydro2D (the CEA/PRACE Hydro
+  mini-app, HydroC): one x-then-y Godunov step of the 2-D Euler equations
+  on four conserved fields, with minmod slopes, a Hancock half step and
+  HydroC's iterative Riemann solver, both passes fused into one nest.
+* :func:`hydro1d_program` — a coverage program shaped like one Hydro pass:
+  a seven-kernel chain (primitive conversion, EOS, slope, trace, interface
+  state, flux, update) over one conserved variable, with a toy interface
+  state in place of a Riemann solver.  It is not Hydro2D.
 
 Executor coverage programs (one per lifted Pallas restriction — see
 docs/BACKENDS.md):
@@ -657,7 +661,8 @@ def cosmo_program(name: str = "cosmo") -> Program:
 
 
 # ---------------------------------------------------------------------------
-# Hydro-style dimensionally-split pass (Section 5.4, simplified)
+# A Hydro-shaped coverage chain: one conserved variable, a toy interface
+# state (Section 5.4's Hydro2D is hydro2d_program below)
 # ---------------------------------------------------------------------------
 
 def _constoprim(rho, mom):
@@ -769,6 +774,187 @@ def hydro1d_program(name: str = "hydro1d") -> Program:
 
 
 # ---------------------------------------------------------------------------
+# Hydro2D: one x-then-y Godunov step of the 2-D Euler equations (Section 5.4)
+# ---------------------------------------------------------------------------
+
+#: Hydro2D's constants: the ratio of specific heats, the density and sound
+#: speed floors of HydroC (``smallr``, ``smallc``), its pressure floor
+#: ``smallp = smallc**2 / gamma``, the fixed ``dt / dx`` of one step and
+#: the Riemann solver's fixed iteration count (``niter_riemann``).
+GAMMA = 1.4
+SMALLR = 1e-10
+SMALLC = 1e-10
+SMALLP = SMALLC * SMALLC / GAMMA
+DTDX = 0.1
+NITER_RIEMANN = 10
+
+
+def _hy_floor(r, p):
+    r = jnp.maximum(r, SMALLR)
+    return r, jnp.maximum(p, r * SMALLP)
+
+
+def _hy_prim(rho, mn, mt, en):
+    """Conserved to primitive along one pass: density, normal and
+    transverse velocity, pressure from the ideal-gas equation of state."""
+    r = jnp.maximum(rho, SMALLR)
+    un = mn / r
+    ut = mt / r
+    p = jnp.maximum((GAMMA - 1.0) * (en - 0.5 * r * (un * un + ut * ut)),
+                    r * SMALLP)
+    return r, un, ut, p
+
+
+def _minmod(qm, q0, qp):
+    dl = q0 - qm
+    dr = qp - q0
+    m = jnp.minimum(jnp.abs(dl), jnp.abs(dr))
+    return jnp.where(dl * dr > 0.0, jnp.where(dl > 0.0, m, -m), 0.0)
+
+
+def _hy_trace(rm, unm, utm, pm, r, un, ut, p, rp, unp, utp, pp):
+    """Minmod slopes, the Hancock half step in primitive form, and the
+    cell's two face states: left of face i+1/2 (``+``), right of face
+    i-1/2 (``-``)."""
+    dr = _minmod(rm, r, rp)
+    dun = _minmod(unm, un, unp)
+    dut = _minmod(utm, ut, utp)
+    dp = _minmod(pm, p, pp)
+    h = 0.5 * DTDX
+    rb = r - h * (un * dr + r * dun)
+    unb = un - h * (un * dun + dp / r)
+    utb = ut - h * (un * dut)
+    pb = p - h * (GAMMA * p * dun + un * dp)
+    r_p, p_p = _hy_floor(rb + 0.5 * dr, pb + 0.5 * dp)
+    r_m, p_m = _hy_floor(rb - 0.5 * dr, pb - 0.5 * dp)
+    return (r_p, unb + 0.5 * dun, utb + 0.5 * dut, p_p,
+            r_m, unb - 0.5 * dun, utb - 0.5 * dut, p_m)
+
+
+def _hy_riemann(rl, ul, vl, pl, rr, ur, vr, pr):
+    """HydroC's iterative Riemann solver at one face, left state ``l``
+    and right state ``r``, then the Godunov flux of the face state."""
+    g6 = (GAMMA + 1.0) / (2.0 * GAMMA)
+    cl = GAMMA * pl * rl
+    cr = GAMMA * pr * rr
+    wl = jnp.sqrt(cl)
+    wr = jnp.sqrt(cr)
+    ps = jnp.maximum((wr * pl + wl * pr + wl * wr * (ul - ur)) / (wl + wr), 0.0)
+    for _ in range(NITER_RIEMANN):
+        wwl = jnp.sqrt(cl * (1.0 + g6 * (ps - pl) / pl))
+        wwr = jnp.sqrt(cr * (1.0 + g6 * (ps - pr) / pr))
+        ql = 2.0 * wwl * wwl * wwl / (wwl * wwl + cl)
+        qr = 2.0 * wwr * wwr * wwr / (wwr * wwr + cr)
+        usl = ul - (ps - pl) / wwl
+        usr = ur + (ps - pr) / wwr
+        ps = ps + jnp.maximum(qr * ql / (qr + ql) * (usl - usr), -ps)
+    wl = jnp.sqrt(cl * (1.0 + g6 * (ps - pl) / pl))
+    wr = jnp.sqrt(cr * (1.0 + g6 * (ps - pr) / pr))
+    us = 0.5 * (ul + (pl - ps) / wl + ur - (pr - ps) / wr)
+    left = us > 0.0
+    s = jnp.where(left, 1.0, -1.0)
+    ro = jnp.where(left, rl, rr)
+    uo = jnp.where(left, ul, ur)
+    po = jnp.where(left, pl, pr)
+    wo = jnp.where(left, wl, wr)
+    vo = jnp.where(left, vl, vr)
+    rs = jnp.maximum(ro / (1.0 + ro * (po - ps) / (wo * wo)), SMALLR)
+    co = jnp.maximum(jnp.sqrt(GAMMA * po / ro), SMALLC)
+    cs = jnp.maximum(jnp.sqrt(GAMMA * ps / rs), SMALLC)
+    spout = co - s * uo
+    spin = cs - s * us
+    ushock = wo / ro - s * uo
+    shock = ps >= po
+    spout = jnp.where(shock, ushock, spout)
+    spin = jnp.where(shock, ushock, spin)
+    scr = jnp.maximum(spout - spin, SMALLC + jnp.abs(spout + spin))
+    frac = jnp.clip(0.5 * (1.0 + (spout + spin) / scr), 0.0, 1.0)
+
+    def face(star, o):
+        q = frac * star + (1.0 - frac) * o
+        return jnp.where(spin > 0.0, star, jnp.where(spout < 0.0, o, q))
+
+    rg = face(rs, ro)
+    ug = face(us, uo)
+    pg = face(ps, po)
+    ek = 0.5 * rg * (ug * ug + vo * vo)
+    return (rg * ug, rg * ug * ug + pg, rg * ug * vo,
+            ug * (pg / (GAMMA - 1.0) + ek + pg))
+
+
+def _hy_update(rho, mn, mt, en, fm0, fm1, fm2, fm3, f0, f1, f2, f3):
+    """The conservative update of one pass: ``U - dtdx (F+ - F-)``."""
+    return (rho - DTDX * (f0 - fm0), mn - DTDX * (f1 - fm1),
+            mt - DTDX * (f2 - fm2), en - DTDX * (f3 - fm3))
+
+
+def _hy_pass(d: str, src: tuple, dst: tuple, tag: str) -> list:
+    """The four rules of one direction pass along dim ``d`` (``"i"`` or
+    ``"j"``): ``src`` names the conserved terms read as (density, normal
+    momentum, transverse momentum, energy) patterns of ``{}``, the index
+    expression; ``dst`` the updated terms, in the same order."""
+    def at(pat, off):
+        jj = f"j?{off:+d}" if d == "j" and off else "j?"
+        ii = f"i?{off:+d}" if d == "i" and off else "i?"
+        return pat.format(f"[{jj}][{ii}]")
+
+    prim = [f"{tag}{q}(rho{{}})" for q in ("r", "un", "ut", "p")]
+    face_p = [f"{tag}{q}p(rho{{}})" for q in ("r", "un", "ut", "p")]
+    face_m = [f"{tag}{q}m(rho{{}})" for q in ("r", "un", "ut", "p")]
+    flux = [f"{tag}f{n}(rho{{}})" for n in range(4)]
+    q = ("rho", "mn", "mt", "en")
+    return [
+        kernel(f"prim{tag}", [(n, at(s, 0)) for n, s in zip(q, src)],
+               [(n, at(t, 0)) for n, t in zip(("r", "un", "ut", "p"), prim)],
+               fn=_hy_prim),
+        kernel(f"trace{tag}",
+               [(f"{n}{k}", at(t, off)) for k, off in (("m", -1), ("", 0), ("p", 1))
+                for n, t in zip(("r", "un", "ut", "p"), prim)],
+               [(f"{n}_{k}", at(t, 0)) for k, ts in (("p", face_p), ("m", face_m))
+                for n, t in zip(("r", "un", "ut", "p"), ts)],
+               fn=_hy_trace),
+        kernel(f"riemann{tag}",
+               [(f"{n}l", at(t, 0)) for n, t in zip("ruvp", face_p)]
+               + [(f"{n}r", at(t, 1)) for n, t in zip("ruvp", face_m)],
+               [(f"f{n}", at(t, 0)) for n, t in enumerate(flux)],
+               fn=_hy_riemann),
+        kernel(f"upd{tag}",
+               [(n, at(s, 0)) for n, s in zip(q, src)]
+               + [(f"fm{n}", at(t, -1)) for n, t in enumerate(flux)]
+               + [(f"f{n}", at(t, 0)) for n, t in enumerate(flux)],
+               [(n, at(t, 0)) for n, t in zip(q, dst)],
+               fn=_hy_update),
+    ]
+
+
+def hydro2d_program(name: str = "hydro2d") -> Program:
+    """One step of Hydro2D (the CEA/PRACE Hydro mini-app): the 2-D
+    compressible Euler equations on ``(rho, mu, mv, en)``, advanced by a
+    dimensionally split Godunov scheme, an x pass then a y pass, each
+    with primitive variables, minmod slopes, a Hancock half step,
+    HydroC's iterative Riemann solver and a conservative update.  Both
+    passes fuse into one nest: the y pass reads the x-updated fields at
+    rows ``j-2 .. j+2`` from rolling row windows, and the x pass also
+    runs on the halo rows, so one two-cell halo serves the whole step."""
+    x_src = ("rho{}", "mu{}", "mv{}", "en{}")
+    x_dst = ("xrho(rho{})", "xmu(rho{})", "xmv(rho{})", "xen(rho{})")
+    # the y pass's normal momentum is mv, its transverse one mu
+    y_src = (x_dst[0], x_dst[2], x_dst[1], x_dst[3])
+    y_dst = ("rho_new(rho{})", "mv_new(rho{})", "mu_new(rho{})",
+             "en_new(rho{})")
+    return Program(
+        rules=_hy_pass("i", x_src, x_dst, "x") + _hy_pass("j", y_src, y_dst, "y"),
+        axioms=[axiom(f"{a}[j?][i?]", j="Nj", i="Ni")
+                for a in ("rho", "mu", "mv", "en")],
+        goals=[goal(f"{a}_new(rho[j][i])", store_as=f"{a}_new",
+                    j=("Nj", 2, -2), i=("Ni", 2, -2))
+               for a in ("rho", "mu", "mv", "en")],
+        loop_order=("j", "i"),
+        name=name,
+    )
+
+
+# ---------------------------------------------------------------------------
 # Program registry
 # ---------------------------------------------------------------------------
 
@@ -792,4 +978,5 @@ ALL_PROGRAMS = {
     "normalization": normalization_program,
     "cosmo": cosmo_program,
     "hydro1d": hydro1d_program,
+    "hydro2d": hydro2d_program,
 }

@@ -174,16 +174,19 @@ def test_vmem_bytes_mirrors_scratch_shapes():
     so a plane holds 24 rows: an 8-row top margin for the first step's
     j-1 read and the step's aligned 24-row read span; x pad(200->256)
     lanes x 4 B.  The input streams two 10-row blocks (padded to 16)
-    and the output two 16-row blocks."""
+    and the output two 16-row blocks.  The step body holds at most 8
+    values of 16 x 256 (its seven reads and one sum)."""
     kp = load_golden("heat3d")
     sizes = {"Nk": 8, "Nj": 10, "Ni": 200}
     block = 2 * 16 * 256 * 4
-    assert vmem_bytes(kp, sizes) == 3 * 24 * 256 * 4 + 2 * block
+    body = 8 * 16 * 256 * 4
+    assert vmem_bytes(kp, sizes) == 3 * 24 * 256 * 4 + 2 * block + body
     rep = vmem_report(kp, sizes)
     assert rep["heat3d_n0"]["in_u"] == 73728
     assert rep["heat3d_n0"]["blk_u"] == block
     assert rep["heat3d_n0"]["out_heat_u"] == block
-    assert rep["heat3d_n0"]["total"] == 139264
+    assert rep["heat3d_n0"]["body"] == body
+    assert rep["heat3d_n0"]["total"] == 270336
 
 
 def test_vmem_bytes_double_buffer_adds_staging():
@@ -291,7 +294,7 @@ def test_explain_verbose_renders_vmem():
                   dim_sizes={"Nk": 8, "Nj": 10, "Ni": 200})
     assert "--- vmem estimate ---" in out
     assert "in_u: 3 x sub(Nj+0+m) x pad(Ni+0) x 4B" in out
-    assert "139264 B resident" in out
+    assert "270336 B resident" in out
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +355,7 @@ def test_pc008_interpreter_capability_mismatch():
 def test_cli_goldens_exit_zero():
     res = _run_lint(str(GOLDEN_DIR), "-q")
     assert res.returncode == 0, res.stdout + res.stderr
-    assert "15 target(s), 0 error(s), 0 warning(s)" in res.stdout
+    assert "16 target(s), 0 error(s), 0 warning(s)" in res.stdout
 
 
 @pytest.mark.slow

@@ -24,14 +24,21 @@ from repro.core import compile_program
 from repro.core import plancheck
 from repro.core.interpreters import execute_plan
 from repro.core.plan import GridDim
-from repro.core.plancheck import (LANE, call_vmem, row_geometry, row_tile,
-                                  scoped_vmem_limit)
+from repro.core.plancheck import (LANE, body_values, call_vmem, row_geometry,
+                                  row_tile, scoped_vmem_limit)
 from repro.core.programs import ALL_PROGRAMS
 from repro.kernels.stencil2d import build_call
 
 #: (rows Nj, cap of R): rows below one tile; one ragged 40-row tile;
 #: three 16-row steps, the last ragged.
 ROWS = [(7, None), (37, None), (37, 16)]
+
+#: The programs compared bit for bit below.  hydro2d's step bodies are
+#: long enough that XLA's CPU compiler fuses a multiply and an add into
+#: one rounding in one schedule and not in the other; tests/test_hydro2d.py
+#: compares its R-row steps bit for bit with the compiler's optimizations
+#: off.
+BIT_EXACT = sorted(set(ALL_PROGRAMS) - {"hydro2d"})
 
 
 def _bits(got: dict, want: dict, tag: str) -> None:
@@ -43,7 +50,7 @@ def _bits(got: dict, want: dict, tag: str) -> None:
 
 @pytest.mark.parametrize("double_buffer", [False, True])
 @pytest.mark.parametrize("nj,cap", ROWS, ids=[f"nj{n}-cap{c}" for n, c in ROWS])
-@pytest.mark.parametrize("name", sorted(ALL_PROGRAMS))
+@pytest.mark.parametrize("name", BIT_EXACT)
 def test_row_tiled_pallas_matches_jax_bit_for_bit(name, nj, cap, double_buffer,
                                                   monkeypatch):
     """R-row steps give the ``jax`` backend's outputs bit for bit;
@@ -145,7 +152,9 @@ def test_call_vmem_mirrors_built_blocks_and_scratch(name, sizes, double_buffer):
     """``call_vmem`` counts what ``build_call`` allocates: two buffers
     of every stream block (R rows, or the 8-row groups of one-row
     steps) and every VMEM scratch buffer (windows of ``R + stages - 1``
-    rows, plane windows with their margins, accumulators, DMA slots)."""
+    rows, plane windows with their margins, accumulators, DMA slots),
+    plus the step bodies' values of R rows (8 for one-row steps) by
+    ``Ni`` lanes."""
     (call,) = [c for c in compile_program(ALL_PROGRAMS[name](), backend="pallas")
                .kernel_plan.calls if c.has_grid]
     *_, nj, ni = sizes
@@ -158,7 +167,9 @@ def test_call_vmem_mirrors_built_blocks_and_scratch(name, sizes, double_buffer):
             keep = w.stages - 1
             assert geo.height[w.name] == R + (keep if R == 1 else -(-keep // 8) * 8)
     want = sum(2 * _bytes(b) for b in blocks) + sum(_bytes(s) for s in vmem)
-    assert call_vmem(call, nj, ni, 4, double_buffer)["total"] == want
+    report = call_vmem(call, nj, ni, 4, double_buffer)
+    assert report["body"] == _bytes((body_values(call) * max(R, 8), ni))
+    assert report["total"] == want + report["body"]
 
 
 def test_vmem_limit_forces_the_row_tile_down(monkeypatch):
@@ -175,7 +186,8 @@ def test_vmem_limit_forces_the_row_tile_down(monkeypatch):
     assert grid == (4, 1024 // R)
     assert all(b[-2] == R for b in blocks)
     want = sum(2 * _bytes(b) for b in blocks) + sum(_bytes(s) for s in vmem)
-    assert call_vmem(call, 1024, 1024, 4, False)["total"] == want
+    report = call_vmem(call, 1024, 1024, 4, False)
+    assert report["total"] == want + report["body"]
 
 
 @pytest.mark.parametrize("name", ["cosmo", "heat3d"])

@@ -50,6 +50,10 @@ CASES = [
         # a producer plane window stored R rows at a time
         ("heat3d_stage-db0", "heat3d_stage",
          {"Nk": 8, "Nj": 256, "Ni": 1024}),
+        # a body whose own values outgrow the buffers: without counting
+        # them the VMEM model chose R = 72 here, and Mosaic refused the
+        # kernel (an 18.67 MiB scoped allocation over the 16 MiB limit)
+        ("hydro2d-1028sq", "hydro2d", {"Nj": 1028, "Ni": 1028}),
     ]
 ]
 
