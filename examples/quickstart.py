@@ -38,6 +38,7 @@ in_cell[j+1], in_cell[j+0], in_cell[j+0]] -> out:0
         in_cell: sub(R+2) x pad(Ni+0) x 4B
         stream cell: 2 x R x pad(Ni+0) x 4B
         out laplace_cell: 2 x R x pad(Ni+0) x 4B
+        seat laplace_cell: sub(R+p) x pad(Ni+0) x 4B where R > 1
         body: 6 x sub(R) x pad(Ni+0) x 4B
     --- vectorization ---
       access classes: aligned=2 shifted=4

@@ -14,16 +14,18 @@ double-buffer DMA pipeline uses (last dimension fastest — the fused
 nest's traversal order), and all VMEM scratch becomes loop-carried
 state: rolling row windows ``(stages, width)``, streamed and producer
 plane windows ``(p_stages, rows, width)``, accumulator rows, and the
-padded outputs themselves.  Every mechanism keeps the reference
-semantics exactly — clamped row/plane streaming (edge rows repeat
-during warm-up/drain), floor-mod slot rotation, predicated accumulator
+outputs themselves.  Every mechanism keeps the reference semantics
+exactly — clamped row/plane streaming (edge rows repeat during
+warm-up/drain), floor-mod slot rotation, predicated accumulator
 combines over rows *and* outer tiles, predicated absolute-row seating
 of producer planes, identity-filled output rows — so the output
-contract matches the Pallas ``build_call`` (which pads its rows to a
-sublane tile): row outputs ``(*grid, steps_j, ni)``, carried accumulators
-``(1, width)``, kept-prefix accumulators ``(*grid[:n_kept], 1, width)``,
-and the shared host half
-(:func:`repro.core.interpreters.execute_plan`) assembles them with the
+contract matches the Pallas ``build_call``: ``external`` outputs
+goal-shaped ``(*N_outer, Nj, Ni)``, each row written at its goal seat
+and every row and plane outside the goal left at the ``fill``; other
+row outputs ``(*grid, steps_j, ni)`` (the Pallas ones pad their rows to
+a sublane tile), carried accumulators ``(1, width)``, kept-prefix
+accumulators ``(*grid[:n_kept], 1, width)``, and the shared host half
+(:func:`repro.core.interpreters.execute_plan`) assembles those with the
 identical trim/seat rules.
 
 This is also the repo's first **layout-aware** interpreter
@@ -67,7 +69,8 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     Mirrors the Pallas ``build_call`` contract: ``sizes`` is
     ``(*outer_sizes, Nj, Ni)``, the result is ``(fn, steps_j)``, and
     ``fn`` maps the call's input arrays (scalars as ``(1, 1)``) to one
-    padded output per ``call.outputs`` entry (a list when several).
+    output per ``call.outputs`` entry (a list when several): goal-shaped
+    for ``external`` outputs, in the grid's frame for the others.
     ``interpret``/``double_buffer`` are ignored — see module docstring.
     """
     n_out = call.n_outer
@@ -158,6 +161,21 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             idxs.append(jnp.clip(p - ilos[li], 0, n_planes - 1))
         return idxs
 
+    def _seat(out, opos, x):
+        """Where an external output's row of canonical position ``x``
+        sits in the goal-shaped output, clamped inside it, and whether
+        it is a goal row of a goal plane (rows and planes outside keep
+        the ``fill``)."""
+        lead = out.outer_lead or (0,) * n_out
+        planes = [opos[d] + lead[d] for d in range(n_out)]
+        g = x + out.lead
+        inside = (g >= out.j_lo) & (g < nj + out.j_hi)
+        for d, p in enumerate(planes):
+            inside &= (p >= out.outer_lo[d]) \
+                & (p < outer_sizes[d] + out.outer_hi[d])
+        at = tuple(jnp.clip(p, 0, n - 1) for p, n in zip(planes, outer_sizes))
+        return at + (jnp.clip(g, 0, nj - 1), 0), inside
+
     def fn(*args):
         st0 = {}
         for w in roll_wins:
@@ -176,10 +194,13 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             if out.acc is not None:
                 a = acc_of[out.acc]
                 wa = acc_w[out.acc]
-                shape = (*gsz[:a.n_kept], 1, wa)
+                st0[("out", oi)] = jnp.zeros((*gsz[:a.n_kept], 1, wa), dtype)
+            elif out.kind == "external":
+                st0[("out", oi)] = jnp.full((*outer_sizes, nj, ni),
+                                            out.fill, dtype)
             else:
-                shape = (*gsz, steps_j, out.lane_block or ni)
-            st0[("out", oi)] = jnp.zeros(shape, dtype)
+                st0[("out", oi)] = jnp.zeros(
+                    (*gsz, steps_j, out.lane_block or ni), dtype)
 
         def body(lin, st):
             st = dict(st)
@@ -389,11 +410,19 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                                     padrow.reshape(chunks, lb),
                                     ospec.reduce_init)
                             wrow = out_row.shape[0]
+                            at = tuple(outer_ids) + (jid, 0)
+                            if ospec.kind == "external":
+                                at, inside = _seat(ospec, opos, x)
+                                old = lax.dynamic_slice(
+                                    st[("out", oi)], at,
+                                    (1,) * (n_out + 1) + (wrow,))
+                                out_row = jnp.where(
+                                    inside, out_row, old.reshape(wrow))
                             st[("out", oi)] = lax.dynamic_update_slice(
                                 st[("out", oi)],
                                 out_row.reshape(
                                     (1,) * (n_out + 1) + (wrow,)),
-                                tuple(outer_ids) + (jid, 0))
+                                at)
 
             # 3b. dump accumulators into their revisited output blocks
             for oi, out in enumerate(call.outputs):
@@ -408,8 +437,8 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
             return st
 
         st = lax.fori_loop(0, total_steps, body, st0)
-        padded = [st[("out", oi)] for oi in range(len(call.outputs))]
-        return padded if len(padded) > 1 else padded[0]
+        outs = [st[("out", oi)] for oi in range(len(call.outputs))]
+        return outs if len(outs) > 1 else outs[0]
 
     return fn, steps_j
 

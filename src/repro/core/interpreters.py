@@ -26,13 +26,16 @@ Two interpreters self-register on first use:
   legacy hand-written ``codegen_jax`` emitter on the plan-covered path.
 
 Every ``build_call`` must honor the **output contract** of the Pallas
-reference implementation — row outputs ``(*grid, rows, ni)`` with
-``rows >= steps_j`` (rows past ``steps_j`` are padding), carried
-accumulators ``(1, width)``, kept-prefix accumulators
-``(*grid[:n_kept], 1, width)`` — because the host half here
+reference implementation — ``external`` outputs goal-shaped
+``(*N_outer, Nj, Ni)``, every element outside the goal's rows, planes
+and lanes equal to the output's ``fill``; other row outputs
+``(*grid, rows, ni)`` with ``rows >= steps_j`` (rows past ``steps_j``
+are padding); carried accumulators ``(1, width)``, kept-prefix
+accumulators ``(*grid[:n_kept], 1, width)`` — because the host half here
 (:func:`execute_plan`: size resolution through axiom shape contracts,
 environment threading, and the :func:`_assemble` trim/seat/lane-reduce
-rules) is shared by every interpreter verbatim.
+rules for the outputs that are not ``external``) is shared by every
+interpreter verbatim.
 
 Capability mismatches raise the typed :class:`PlanUnsupported` (a
 :class:`~repro.core.plan.PallasUnsupported` subclass, so existing
@@ -51,7 +54,8 @@ import jax.numpy as jnp
 from ..trace import count, span
 from .plan import (PLAN_FEATURES, CallPlan, KernelPlan, OutputPlan,
                    PallasUnsupported)
-from .plancheck import call_vmem, row_tile, scoped_vmem_limit
+from .plancheck import (call_vmem, row_tile, scoped_vmem_limit,
+                        seat_geometry)
 from .runtime import lane_reduce
 
 
@@ -77,8 +81,8 @@ class InterpreterSpec:
 
     ``build_call(call, sizes, dtype, interpret=..., double_buffer=...)``
     concretizes a :class:`~repro.core.plan.CallPlan` to
-    ``(fn, steps_j)`` under the shared padded-output contract (see the
-    module docstring).  ``capabilities`` is the subset of
+    ``(fn, steps_j)`` under the shared output contract (see the module
+    docstring).  ``capabilities`` is the subset of
     :data:`~repro.core.plan.PLAN_FEATURES` the interpreter executes;
     ``flags`` names the execution flags it actually honors (subset of
     ``{"interpret", "double_buffer"}``) so the engine can normalize
@@ -269,8 +273,10 @@ def require_hazard_free(call: CallPlan) -> None:
 
 # ---------------------------------------------------------------------------
 # The shared host half: size resolution, environment threading, output
-# assembly (the plan's trim/seat rules) — identical for every
-# interpreter because every build_call honors the same output contract.
+# assembly (the plan's trim/seat rules for every output kind but
+# ``external``, which the interpreter seats itself) — identical for
+# every interpreter because every build_call honors the same output
+# contract.
 # ---------------------------------------------------------------------------
 
 def _lane_permute(arr, p, inverse: bool = False):
@@ -327,18 +333,26 @@ def _outer_seat(out: OutputPlan, n_outs: tuple[int, ...],
     )
 
 
-def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
+def _assemble(call: CallPlan, out: OutputPlan, dev, nj: int, ni: int,
               n_outs: tuple[int, ...], dtype):
-    """Map one padded device output back to its environment array: trim
-    warm-up/drain rows and tiles, re-seat goal origins, lane-reduce
-    accumulators whose vector dim was folded."""
+    """Map one device output back to its environment array.  An
+    ``external`` output comes back goal-shaped from the interpreter and
+    is handed on as it is; the other kinds come back in the grid's own
+    frame: trim warm-up/drain rows and tiles, re-seat goal origins,
+    lane-reduce accumulators whose vector dim was folded."""
+    if out.kind == "external":
+        if dev.shape != (*n_outs, nj, ni):
+            raise ValueError(
+                f"call {call.name}: external output {out.name} came back "
+                f"{dev.shape}, not goal-shaped {(*n_outs, nj, ni)}")
+        return dev
     n_out = call.n_outer
     reduce_fn = call.fns[out.reduce_idx] if out.reduce_idx is not None \
         else None
     if out.kind == "acc":
         if out.n_kept:
             # (*kept grid tiles, 1, width): one combined row per kept tile
-            part = padded[_outer_trim(out, call, n_outs, out.n_kept)
+            part = dev[_outer_trim(out, call, n_outs, out.n_kept)
                           + (0,)]
             if reduce_fn is not None:
                 part = lane_reduce(reduce_fn,
@@ -353,7 +367,7 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
             seat = _outer_seat(out, n_outs, out.n_kept) \
                 + (slice(None),) * (part.ndim - out.n_kept)
             return jnp.zeros(shape, dtype).at[seat].set(part)
-        row = padded[0]
+        row = dev[0]
         if reduce_fn is not None:
             return lane_reduce(reduce_fn, row, out.reduce_init)
         return row
@@ -363,20 +377,14 @@ def _assemble(call: CallPlan, out: OutputPlan, padded, nj: int, ni: int,
     if out.kind == "acc_rows":
         # one identity-padded partial-accumulator row per row position:
         # trim, fold the lanes, seat at the goal origin
-        part = padded[otrim + (slice(t0, t0 + nrows), slice(None))]
+        part = dev[otrim + (slice(t0, t0 + nrows), slice(None))]
         vals = lane_reduce(reduce_fn, jnp.moveaxis(part, -1, 0),
                            out.reduce_init)
         res = jnp.zeros((*n_outs, nj), dtype)
         return res.at[_outer_seat(out, n_outs, n_out)
                       + (slice(out.j_lo, nj + out.j_hi),)].set(vals)
-    if out.kind == "external":
-        jlo, jhi = out.j_lo, nj + out.j_hi
-        res = jnp.zeros((*n_outs, nj, ni), dtype)
-        return res.at[_outer_seat(out, n_outs, n_out)
-                      + (slice(jlo, jhi), slice(None))].set(
-            padded[otrim + (slice(t0, t0 + nrows), slice(None))])
     w = ni + out.i_hi - out.i_lo
-    return padded[otrim + (slice(t0, t0 + nrows),
+    return dev[otrim + (slice(t0, t0 + nrows),
                            slice(out.i_lo, out.i_lo + w))]
 
 
@@ -386,14 +394,12 @@ def _grid_steps(call: CallPlan, n_outs: tuple[int, ...], nj: int, ni: int,
     (:func:`repro.core.plancheck.row_tile`), the VMEM the call needs at
     that R (:func:`repro.core.plancheck.call_vmem`) and the scoped limit
     passed for it, 0 for the compiler's default
-    (:func:`repro.core.plancheck.scoped_vmem_limit`): ``cdiv(steps_j,
-    R)`` row steps times every outer grid dim's extent, as the Pallas
-    ``build_call`` lays out its grid and sizes its VMEM."""
+    (:func:`repro.core.plancheck.scoped_vmem_limit`), as the Pallas
+    ``build_call`` lays out its grid
+    (:func:`repro.core.plancheck.seat_geometry`) and sizes its VMEM."""
     itemsize = jnp.dtype(dtype).itemsize
     rows = row_tile(call, nj, ni, itemsize, double_buffer)
-    steps = -(-(nj + call.x_hi_off - call.x_lo) // rows)
-    for n, lo, hi in zip(n_outs, call.outer_lo, call.outer_hi_off):
-        steps *= n + hi - lo
+    steps = seat_geometry(call, n_outs, nj, rows, itemsize).steps
     need = call_vmem(call, nj, ni, itemsize, double_buffer, rows=rows)["total"]
     return steps, rows, need, scoped_vmem_limit(need) or 0
 
@@ -421,8 +427,11 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     counters ``hfav.grid_steps``, ``hfav.row_tile`` (the rows each grid
     step computes), ``hfav.vmem_need_bytes`` (the VMEM model's need at
     that row tile) and ``hfav.vmem_limit_bytes`` (the scoped VMEM limit
-    passed, 0 for the compiler's default), each added once per built
-    call (:mod:`repro.trace`), mark each stencil call as ``fn``'s Python
+    passed, 0 for the compiler's default), ``hfav.outputs_seated`` (the
+    call's ``external`` outputs, which the interpreter writes at their
+    goal seat) and ``hfav.outputs_trimmed`` (its other outputs, which
+    :func:`_assemble` trims), each added once per built call
+    (:mod:`repro.trace`), mark each stencil call as ``fn``'s Python
     runs: under ``jax.jit`` once per trace, never per compiled call."""
     spec = get_interpreter(interpreter)
     interpret = resolve_interpret(interpret)
@@ -461,6 +470,9 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
                 count("hfav.row_tile", rows)
                 count("hfav.vmem_need_bytes", need)
                 count("hfav.vmem_limit_bytes", limit)
+                seated = sum(o.kind == "external" for o in cp.outputs)
+                count("hfav.outputs_seated", seated)
+                count("hfav.outputs_trimmed", len(cp.outputs) - seated)
                 with span("hfav.build_call", call=cp.name, grid_steps=steps,
                           row_tile=rows, vmem_need_bytes=need,
                           vmem_limit_bytes=limit):
@@ -473,10 +485,10 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
                         if ispec.scalar:
                             v = v.reshape((1, 1))
                         args.append(v)
-                    padded = pcall(*args)
-                if not isinstance(padded, (list, tuple)):
-                    padded = [padded]
-                for out, pout in zip(cp.outputs, padded):
+                    outs = pcall(*args)
+                if not isinstance(outs, (list, tuple)):
+                    outs = [outs]
+                for out, pout in zip(cp.outputs, outs):
                     env[out.name] = _assemble(cp, out, pout, nj, ni,
                                               n_outs, dtype)
             for hs in cp.host_post:

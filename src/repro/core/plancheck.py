@@ -76,7 +76,8 @@ import os
 from dataclasses import dataclass, replace
 from typing import Optional, Sequence
 
-from .plan import CallPlan, KernelPlan, StepPlan, WindowPlan
+from .plan import (CallPlan, KernelPlan, PallasUnsupported, StepPlan,
+                   WindowPlan)
 
 #: Default VMEM budget for PC003 and ``backend="auto"``: the Mosaic
 #: compiler's default scoped VMEM limit on TPU v5e (16 MiB).
@@ -643,9 +644,10 @@ def _check_plane_read(call, si, pi, rd, w: WindowPlan, row_req,
 
 def _check_outputs(call: CallPlan, writers: dict,
                    nest: str) -> list[Diagnostic]:
-    """The host-side assembly slices device rows
-    ``[j_lo - (x_lo + lead), ...)`` and outer blocks
-    ``[outer_lo - outer_lead - o_lo, ...)``; both must stay inside
+    """An output's rows are those the grid computed from row
+    ``j_lo - (x_lo + lead)`` on, and its planes those from outer block
+    ``outer_lo - outer_lead - o_lo`` on (the host's trim takes them, or
+    the kernel's seat for an ``external`` output); both must stay inside
     what the grid produced, and the declared output lead must match
     the producing step's actual lead."""
     diags: list[Diagnostic] = []
@@ -884,6 +886,109 @@ def row_geometry(call: CallPlan, nj: int, rows: int,
                        last, lead, halo, margin, span, height)
 
 
+@dataclass(frozen=True)
+class Seat:
+    """Where the Pallas interpreter writes one ``external`` output, which
+    it returns goal-shaped, ``(*N_outer, Nj, Ni)``, every element outside
+    the goal's rows, planes and lanes equal to the output's ``fill``.
+
+    Goal row ``g`` is computed at row position ``g + skew`` of the row
+    grid (``skew = -(x_lo + lead)``).  The output moves in blocks of
+    ``block`` rows, ``blocks`` of them per plane.  With R > 1 the block
+    of R goal rows straddles two steps' tiles: it is written one grid
+    step after the step that computed most of it (``skew // R + 1``
+    steps after the row step of the same index), from the rows that
+    step left in a carry buffer and the first ``skew % R`` rows of the
+    step's own tile.  The carry buffer keeps ``pad`` rows above the
+    step's rows, so that the carried rows start on a sublane tile.  One
+    row a step, the row goes to row ``g % block`` of the block that
+    consecutive steps revisit.
+
+    Along outer dim ``d`` the grid's ``t``-th tile computes plane
+    ``t + first[d]``.  The ``N_d`` tiles from plane ``window[d]`` on
+    write planes ``c mod N_d``, so the warm-up and drain tiles that
+    compute no goal plane write ``fill`` into the halo planes that no
+    goal tile covers; tiles before the window write planes that a tile
+    of the window writes again later, and tiles after it write
+    nothing."""
+
+    skew: int
+    block: int
+    blocks: int
+    pad: int
+    first: tuple
+    window: tuple
+
+
+@dataclass(frozen=True)
+class SeatGeometry:
+    """The grid of one call whose ``external`` outputs are seated
+    (:func:`seat_geometry`): ``radix`` is the tiles of each outer dim
+    and the row steps of each tile, ``extra`` the steps after the last
+    tile, and ``seats`` the :class:`Seat` of each external output by its
+    index in ``call.outputs``.  The Pallas grid is one dim of
+    ``steps`` steps, numbered in the order the TPU runs them; the steps
+    past the ones that compute (a tile past the grid's, a row step past
+    its tile's) compute nothing."""
+
+    radix: tuple
+    extra: int
+    seats: dict
+
+    @property
+    def steps(self) -> int:
+        n = self.extra
+        prod = 1
+        for r in self.radix:
+            prod *= r
+        return n + prod
+
+
+def _row_seat(call: CallPlan, out, nj: int, R: int, tile: int):
+    """``(skew, block, blocks, pad)`` of one external output at row tile
+    ``R`` (see :class:`Seat`)."""
+    skew = -(call.x_lo + out.lead)
+    block = min(SUBLANE if R == 1 else R, nj)
+    if (R == 1 and skew <= -block) or (R > 1 and skew // R < -1):
+        raise PallasUnsupported(
+            f"call {call.name}: output {out.name} is computed "
+            f"{-skew} rows ahead of its goal rows, more than one block")
+    pad = (-(skew % R)) % tile if R > 1 else 0
+    return skew, block, -(-nj // block), pad
+
+
+def seat_geometry(call: CallPlan, outer_sizes: Sequence[int], nj: int,
+                  rows: int, dtype_bytes: int) -> SeatGeometry:
+    """The grid that seats every ``external`` output of ``call`` at row
+    tile ``rows`` (see :class:`SeatGeometry`).  Each outer dim has at
+    least the tiles its goal planes and halo planes need, each tile at
+    least the row steps that reach every block of the output (R > 1) or
+    every 8-row block (one row a step), and the grid enough steps after
+    the last tile for the last block written late."""
+    R = int(rows)
+    t = row_tile_unit(dtype_bytes)
+    n_out = call.n_outer
+    tiles = [outer_sizes[d] + call.outer_hi_off[d] - call.outer_lo[d]
+             for d in range(n_out)]
+    row_steps = -(-(nj + call.x_hi_off - call.x_lo) // R)
+    seats = {}
+    for oi, out in enumerate(call.outputs):
+        if out.kind != "external":
+            continue
+        skew, block, blocks, pad = _row_seat(call, out, nj, R, t)
+        lead = out.outer_lead or (0,) * n_out
+        first = tuple(call.outer_lo[d] + lead[d] for d in range(n_out))
+        window = tuple(max(first[d], out.outer_hi[d]) for d in range(n_out))
+        for d in range(n_out):
+            tiles[d] = max(tiles[d], window[d] - first[d] + outer_sizes[d])
+        row_steps = max(row_steps, blocks if R > 1
+                        else block * (blocks - 1) + skew + 1)
+        seats[oi] = Seat(skew, block, blocks, pad, first, window)
+    extra = max([0] + [s.blocks + s.skew // R + 1 - row_steps
+                       for s in seats.values() if R > 1])
+    return SeatGeometry((*tiles, row_steps), extra, seats)
+
+
 def _row_tileable(call: CallPlan, double_buffer: bool, tile: int) -> bool:
     """Whether a grid step of ``call`` may compute several rows at once.
 
@@ -1011,7 +1116,9 @@ def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
     their row margins (:func:`row_geometry`), accumulators one padded
     row, and the two buffers of every stream block (array inputs,
     either the pipeline's or the explicit DMA slots, and row outputs:
-    R rows, 8 when R is 1; accumulator outputs: 8 rows).  Rows are
+    R rows, 8 when R is 1, at most ``Nj`` for external outputs;
+    accumulator outputs: 8 rows), and for R > 1 the carry buffer of each
+    external output (:class:`Seat`).  Rows are
     padded to the sublane tile, lanes to 128.  ``"body"`` is what the
     step bodies hold beside them: :func:`body_values` values of R rows
     by ``Ni`` lanes."""
@@ -1046,6 +1153,12 @@ def call_vmem(call: CallPlan, nj: int, ni: int, dtype_bytes: int,
         if o.acc is not None:
             report[f"out_{o.name}"] = \
                 2 * sub(SUBLANE) * _pad_to_lane(acc_w[o.acc]) * ib
+        elif o.kind == "external":
+            _, block, _, pad = _row_seat(call, o, nj, rows, geo.tile)
+            report[f"out_{o.name}"] = 2 * sub(block) * _pad_to_lane(ni) * ib
+            if rows > 1:
+                report[f"seat_{o.name}"] = \
+                    sub(pad + rows) * _pad_to_lane(ni) * ib
         else:
             report[f"out_{o.name}"] = \
                 2 * sub(geo.out_block) * _pad_to_lane(ni) * ib
@@ -1098,8 +1211,9 @@ def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
     one line per resident buffer with the padded shape algebra
     (``sub`` rounds rows up to the sublane tile, ``pad`` lanes up to
     128), usable without concrete sizes.  ``R`` is the rows a grid step
-    computes (:func:`row_tile`, 1 where R-row steps do not apply) and
-    ``m`` a plane window's row margins (:func:`row_geometry`)."""
+    computes (:func:`row_tile`, 1 where R-row steps do not apply),
+    ``m`` a plane window's row margins (:func:`row_geometry`) and ``p``
+    the pad rows of an external output's carry buffer (:class:`Seat`)."""
     lines: list[str] = []
     ib = int(dtype_bytes)
     for call in kplan.calls:
@@ -1137,6 +1251,9 @@ def render_vmem(kplan: KernelPlan, *, dtype_bytes: int = 4) -> list[str]:
                              f"pad(Ni{w_off[o.acc]:+d}) x {ib}B")
             else:
                 lines.append(f"    out {o.name}: 2 x R x pad(Ni+0) x {ib}B")
+            if o.kind == "external":
+                lines.append(f"    seat {o.name}: sub(R+p) x pad(Ni+0) x "
+                             f"{ib}B where R > 1")
         lines.append(f"    body: {body_values(call)} x sub(R) x pad(Ni+0) "
                      f"x {ib}B")
     return lines

@@ -144,7 +144,7 @@ def test_body_values_count_the_riemann_step(call):
     # 1028^2: the step bodies push R down to 40 under the default limit
     (1028, 40, None),
     # 8196^2: the smallest tile, 8 rows, needs a raised limit
-    (8196, 8, 26772480),
+    (8196, 8, 29434880),
 ])
 def test_call_vmem_counts_the_body(call, n, rows, limit):
     """``call_vmem``'s body term is the body values at R rows by the
@@ -167,7 +167,7 @@ def test_vmem_counters_per_traced_call(fused):
     model's need at the chosen R and the scoped limit passed, added once
     per traced stencil call; at 8196^2 the limit is raised."""
     (c,) = fused.kernel_plan.calls
-    for n, limit in [(260, 0), (8196, 26772480)]:
+    for n, limit in [(260, 0), (8196, 29434880)]:
         shapes = {k: jax.ShapeDtypeStruct((n, n), jnp.float32)
                   for k in ("rho", "mu", "mv", "en")}
         with trace.recording() as rec:

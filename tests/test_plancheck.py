@@ -174,19 +174,25 @@ def test_vmem_bytes_mirrors_scratch_shapes():
     so a plane holds 24 rows: an 8-row top margin for the first step's
     j-1 read and the step's aligned 24-row read span; x pad(200->256)
     lanes x 4 B.  The input streams two 10-row blocks (padded to 16)
-    and the output two 16-row blocks.  The step body holds at most 8
-    values of 16 x 256 (its seven reads and one sum)."""
+    and the goal-shaped output two 10-row blocks (padded to 16).  The
+    output's carry buffer holds the step's 16 rows below 7 pad rows
+    (its goal rows start one row into the step's tile): 24 rows.  The
+    step body holds at most 8 values of 16 x 256 (its seven reads and
+    one sum)."""
     kp = load_golden("heat3d")
     sizes = {"Nk": 8, "Nj": 10, "Ni": 200}
     block = 2 * 16 * 256 * 4
     body = 8 * 16 * 256 * 4
-    assert vmem_bytes(kp, sizes) == 3 * 24 * 256 * 4 + 2 * block + body
+    carry = 24 * 256 * 4
+    assert vmem_bytes(kp, sizes) == \
+        3 * 24 * 256 * 4 + 2 * block + carry + body
     rep = vmem_report(kp, sizes)
     assert rep["heat3d_n0"]["in_u"] == 73728
     assert rep["heat3d_n0"]["blk_u"] == block
     assert rep["heat3d_n0"]["out_heat_u"] == block
+    assert rep["heat3d_n0"]["seat_heat_u"] == carry
     assert rep["heat3d_n0"]["body"] == body
-    assert rep["heat3d_n0"]["total"] == 270336
+    assert rep["heat3d_n0"]["total"] == 294912
 
 
 def test_vmem_bytes_double_buffer_adds_staging():
@@ -294,7 +300,8 @@ def test_explain_verbose_renders_vmem():
                   dim_sizes={"Nk": 8, "Nj": 10, "Ni": 200})
     assert "--- vmem estimate ---" in out
     assert "in_u: 3 x sub(Nj+0+m) x pad(Ni+0) x 4B" in out
-    assert "270336 B resident" in out
+    assert "seat heat_u: sub(R+p) x pad(Ni+0) x 4B where R > 1" in out
+    assert "294912 B resident" in out
 
 
 # ---------------------------------------------------------------------------

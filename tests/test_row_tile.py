@@ -25,7 +25,7 @@ from repro.core import plancheck
 from repro.core.interpreters import execute_plan
 from repro.core.plan import GridDim
 from repro.core.plancheck import (LANE, body_values, call_vmem, row_geometry,
-                                  row_tile, scoped_vmem_limit)
+                                  row_tile, scoped_vmem_limit, seat_geometry)
 from repro.core.programs import ALL_PROGRAMS
 from repro.kernels.stencil2d import build_call
 
@@ -157,10 +157,12 @@ def test_call_vmem_mirrors_built_blocks_and_scratch(name, sizes, double_buffer):
     ``Ni`` lanes."""
     (call,) = [c for c in compile_program(ALL_PROGRAMS[name](), backend="pallas")
                .kernel_plan.calls if c.has_grid]
-    *_, nj, ni = sizes
+    *outer, nj, ni = sizes
     R = row_tile(call, nj, ni, 4, double_buffer)
     grid, blocks, vmem = _built(call, sizes, double_buffer)
-    assert grid[-1] == -(-(nj + call.x_hi_off - call.x_lo) // R)
+    seats = seat_geometry(call, outer, nj, R, 4)
+    assert grid == (seats.steps,)
+    assert seats.radix[-1] == -(-(nj + call.x_hi_off - call.x_lo) // R)
     geo = row_geometry(call, nj, R, 4)
     for w in call.windows:
         if not w.plane:  # the kept rows, rounded up to the tile for R > 1
@@ -183,7 +185,9 @@ def test_vmem_limit_forces_the_row_tile_down(monkeypatch):
     assert scoped_vmem_limit(call_vmem(call, 1024, 1024, 4, False, rows=R)["total"]) is None
     assert scoped_vmem_limit(call_vmem(call, 1024, 1024, 4, False, rows=R + 8)["total"])
     grid, blocks, vmem = _built(call, (4, 1024, 1024))
-    assert grid == (4, 1024 // R)
+    # four planes of 1024 // R row steps, and one step after the last
+    # that writes the last goal block
+    assert grid == (4 * (1024 // R) + 1,)
     assert all(b[-2] == R for b in blocks)
     want = sum(2 * _bytes(b) for b in blocks) + sum(_bytes(s) for s in vmem)
     report = call_vmem(call, 1024, 1024, 4, False)
@@ -203,7 +207,9 @@ def test_grid_step_counters_match_the_built_grid(name):
     (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
     grid = eqn.params["grid_mapping"].grid
     (call,) = gen.kernel_plan.calls
+    R = rec.counters["hfav.row_tile"]
     assert rec.counters["hfav.grid_steps"] == math.prod(grid)
-    assert rec.counters["hfav.row_tile"] == row_tile(call, 150, DIM["i"], 4, False)
-    assert grid[-1] == -(-(150 + call.x_hi_off - call.x_lo)
-                         // rec.counters["hfav.row_tile"])
+    assert R == row_tile(call, 150, DIM["i"], 4, False)
+    seats = seat_geometry(call, (DIM["k"],) * call.n_outer, 150, R, 4)
+    assert grid == (seats.steps,)
+    assert seats.radix[-1] == -(-(150 + call.x_hi_off - call.x_lo) // R)

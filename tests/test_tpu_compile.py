@@ -10,7 +10,9 @@ program holds the Mosaic kernel (``tpu_custom_call``).
 The topology is described inside a module-scoped fixture: only the test
 worker that runs this file loads the TPU library.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,3 +107,58 @@ def test_batched_kernel_compiles_for_v5e(one_chip):
     compiled = jax.jit(bgen.fn).lower(
         _inputs(prog, sizes, one_chip, batch=(4,))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: The sweep cells' chain link at the cells' sizes: cosmo1's field, and a
+#: Hydro2D subdomain whose 8196 rows end in a partial 8-row block.
+CHAIN = [
+    pytest.param("cosmo", {"Nk": 80, "Nj": 774, "Ni": 1158}, id="cosmo1-80x774x1158"),
+    pytest.param("hydro2d", {"Nj": 8196, "Ni": 8196}, id="hydro2d-8196sq"),
+]
+
+_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\((.*)$")
+
+
+@pytest.mark.parametrize("name,sizes", CHAIN)
+def test_donated_chain_link_holds_no_glue_for_v5e(one_chip, name, sizes):
+    """One link of the benchmark's sweep chain, built as
+    ``bench/generators/sweep.py`` ``_chained`` builds it (the previous
+    output donated, one element returned as a marker), compiles to the
+    kernel alone: no ``pad`` or ``dynamic-update-slice``, no ``slice``
+    but the one-element marker, and no ``copy`` that keeps its layout.
+    cosmo1's input and output each take one ``copy`` that changes the
+    layout: the device keeps ``f32[80,774,1158]`` with the 774-row dim
+    major (no row padding), and the Mosaic kernel takes row-major
+    arrays."""
+    prog = ALL_PROGRAMS[name]()
+    gen = compile_program(prog, backend="pallas", interpret=False, use_cache=False)
+    inputs = _inputs(prog, sizes, one_chip)
+
+    def link(arrays, _previous):
+        out = gen.fn(**arrays)
+        leaf = jax.tree.leaves(out)[0]
+        return out, leaf[tuple(n // 2 for n in leaf.shape)]
+
+    shapes = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda a: gen.fn(**a), inputs))
+    text = jax.jit(link, donate_argnums=1, keep_unused=True).lower(
+        inputs, shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    types, copies = {}, []
+    for line in text.splitlines():
+        m = _OP.match(line)
+        if not m:
+            continue
+        op_name, rtype, opcode, rest = m.groups()
+        types[op_name] = rtype
+        assert opcode not in ("pad", "dynamic-update-slice"), line
+        if opcode == "slice":
+            dims = re.match(r"\w+\[([\d,]*)\]", rtype).group(1)
+            assert math.prod(int(d) for d in dims.split(",") if d) == 1, line
+        if opcode.startswith("copy"):
+            copies.append((rtype, rest.split(")")[0].lstrip("%")))
+    for rtype, operand in copies:
+        layout = re.search(r"\{[\d,]*", rtype).group(0)
+        assert layout != re.search(r"\{[\d,]*", types[operand]).group(0), (rtype, operand)
+    assert len(copies) == (2 if name == "cosmo" else 0)

@@ -150,19 +150,24 @@ def test_compile_program_spans_and_cache_counters(fresh_cache):
         jax.block_until_ready(step(u))
     # the kernel is built while JAX traces, once; the compiled calls run
     # no span.  One grid step per k plane of the 5x11x132 field: its 11
-    # rows fit one 16-row tile.  The VMEM model's need at that tile
-    # (plancheck.call_vmem: the 3-plane window, two stream blocks of each
-    # of input and output, 8 body values of 16 x 256) stays under the
-    # default scoped limit, so no limit is passed (0).
+    # rows fit one 16-row tile; one more step after the last plane
+    # writes that plane's goal rows, which each step writes one step
+    # late.  The VMEM model's need at that tile (plancheck.call_vmem:
+    # the 3-plane window, two stream blocks of each of input and output,
+    # the output's 24-row carry buffer, 8 body values of 16 x 256) stays
+    # under the default scoped limit, so no limit is passed (0).  The
+    # one output is a goal, seated by the kernel.
     (build,) = rec.named("hfav.build_call")
-    need = 73728 + 2 * 32768 + 8 * 16 * 256 * 4
-    assert build.attrs == {"call": "heat3d_n0", "grid_steps": 5 * 1,
+    need = 73728 + 2 * 32768 + 24 * 256 * 4 + 8 * 16 * 256 * 4
+    assert build.attrs == {"call": "heat3d_n0", "grid_steps": 5 * 1 + 1,
                            "row_tile": 16, "vmem_need_bytes": need,
                            "vmem_limit_bytes": 0}
-    assert rec.counters["hfav.grid_steps"] == 5 * 1
+    assert rec.counters["hfav.grid_steps"] == 5 * 1 + 1
     assert rec.counters["hfav.row_tile"] == 16
-    assert rec.counters["hfav.vmem_need_bytes"] == need == 270336
+    assert rec.counters["hfav.vmem_need_bytes"] == need == 294912
     assert rec.counters["hfav.vmem_limit_bytes"] == 0
+    assert rec.counters["hfav.outputs_seated"] == 1
+    assert rec.counters["hfav.outputs_trimmed"] == 0
 
 
 def test_plan_disk_spans_and_hits(fresh_cache, tmp_path):
