@@ -11,12 +11,8 @@ program as its in-row baseline:
 * ``plane_sum``  — per-outer-tile reduction (output keeps k);
 * ``smooth_norm`` — cross-row read of a same-nest materialized variable
   (served from a rolling VMEM window);
-* ``cosmo_dbuf`` — double-buffered input DMA (explicit two-slot
-  async-copy pipeline) vs the BlockSpec-streamed cosmo leg;
 * ``heat3d``     — outer-dim stencil halo (``u[k-1]``/``u[k+1]`` reads
   served from a 3-plane VMEM window carried across the k grid);
-* ``heat3d_dbuf`` — the same plane window fed by the double-buffered
-  DMA pipeline;
 * ``heat3d_stage`` — a *producer plane window*: the same-nest
   pre-smooth stage runs one tile ahead, its planes resident in VMEM,
   never materialized to HBM;
@@ -96,18 +92,16 @@ from .common import mk, time_fn, time_pair
 
 # interpret mode unrolls the grid at trace time: keep row counts bounded
 CASES = [
-    ("pyramid4d", pyramid4d_program, "edge", (2, 2, 24, 128), False),
-    ("energy3d", energy3d_program, "energy", (4, 32, 256), False),
-    ("plane_sum", plane_sum_program, "colsum", (4, 32, 256), False),
-    ("smooth_norm", smooth_norm_program, "nflux", (96, 256), False),
-    ("cosmo_dbuf", cosmo_program, "unew", (4, 48, 256), True),
-    ("heat3d", heat3d_program, "heat", (6, 32, 256), False),
-    ("heat3d_dbuf", heat3d_program, "heat", (6, 32, 256), True),
-    ("heat3d_stage", heat3d_stage_program, "heat", (6, 32, 256), False),
+    ("pyramid4d", pyramid4d_program, "edge", (2, 2, 24, 128)),
+    ("energy3d", energy3d_program, "energy", (4, 32, 256)),
+    ("plane_sum", plane_sum_program, "colsum", (4, 32, 256)),
+    ("smooth_norm", smooth_norm_program, "nflux", (96, 256)),
+    ("heat3d", heat3d_program, "heat", (6, 32, 256)),
+    ("heat3d_stage", heat3d_stage_program, "heat", (6, 32, 256)),
     ("heat3d_residual_norm", heat3d_residual_norm_program, "rnorm",
-     (6, 32, 256), False),
-    ("row_sum", row_sum_program, "rsum", (96, 256), False),
-    ("subset_sum", subset_sum_program, "lsum", (3, 4, 24, 256), False),
+     (6, 32, 256)),
+    ("row_sum", row_sum_program, "rsum", (96, 256)),
+    ("subset_sum", subset_sum_program, "lsum", (3, 4, 24, 256)),
 ]
 
 
@@ -115,12 +109,11 @@ def run(interpret: Optional[bool] = None):
     interpret = resolve_interpret(interpret)
     rng = np.random.default_rng(7)
     rows = []
-    for name, build, out, shape, dbuf in CASES:
+    for name, build, out, shape in CASES:
         prog = build()
         u = mk(rng, shape)
         ref = build_unfused(prog).fn(u=u)[out]
-        gen = compile_program(prog, backend="pallas", interpret=interpret,
-                              double_buffer=dbuf)
+        gen = compile_program(prog, backend="pallas", interpret=interpret)
         pallas_fn = jax.jit(lambda u, _g=gen: _g.fn(u=u)[out])
         t_p, got = time_fn(pallas_fn, u)
         assert np.allclose(np.asarray(got), np.asarray(ref),
@@ -141,7 +134,7 @@ def run(interpret: Optional[bool] = None):
         # concrete shape (peak across nests; mirrors build_call scratch)
         kplan = gen.kernel_plan
         sizes = sizes_from_arrays(kplan, {"u": shape})
-        vmem = vmem_bytes(kplan, sizes, dtype_bytes=4, double_buffer=dbuf)
+        vmem = vmem_bytes(kplan, sizes, dtype_bytes=4)
         # the vectorization analyzer's prediction for the same concrete
         # shape, recorded beside the measured wall time so the model
         # can be judged against reality PR over PR
@@ -150,15 +143,13 @@ def run(interpret: Optional[bool] = None):
             "name": f"lifted_{name}_{'x'.join(map(str, shape))}",
             "us_per_call": t_p * 1e6,
             "derived": (
-                f"backend=pallas;interpret={interpret};"
-                f"double_buffer={dbuf};{base}"
+                f"backend=pallas;interpret={interpret};{base}"
                 f"Mcells_s={cells / t_p / 1e6:.0f};vmem_B={vmem};"
                 f"vec_ratio={vsum['vec_redundant_load_ratio']:.2f}"
             ),
             # structured fields for the --json trajectory record
             "backend": "pallas",
             "interpret": interpret,
-            "double_buffer": dbuf,
             "jax_us_per_call": jax_us,
             "mcells_per_s": cells / t_p / 1e6,
             "vmem_bytes": vmem,
@@ -306,7 +297,7 @@ def main(argv=None) -> None:
     cache_legs = run_plan_cache()
     if args.json:
         legs = [{k: r[k] for k in ("name", "us_per_call", "backend",
-                                   "interpret", "double_buffer",
+                                   "interpret",
                                    "jax_us_per_call", "mcells_per_s",
                                    "vmem_bytes",
                                    "vec_redundant_load_ratio",

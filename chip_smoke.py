@@ -6,9 +6,9 @@ reference (one ``jax.jit`` of :func:`repro.core.unfused.build_unfused`):
 
 * library path: ``compile_program(prog, backend="pallas")`` with no
   ``interpret`` argument, on COSMO's 80-level 1024x1024 grid, a 512^3
-  heat3d, an 8192x8192 normalization and an 8192x8192 hydro1d, plus one
-  2-D and one 3-D program with ``double_buffer=True``.  Each jitted
-  program must hold a Mosaic kernel (``tpu_custom_call``);
+  heat3d, an 8192x8192 normalization, an 8192x8192 hydro1d and a
+  4100x4100 laplace5.  Each jitted program must hold a Mosaic kernel
+  (``tpu_custom_call``);
 * serving path: a ``PlanServe(..., backend="pallas")`` answers a few
   requests each for laplace5 at 2048x2048 and heat3d at 256^3.
 
@@ -37,16 +37,15 @@ SEED = 0
 #: misplaced row or lane errs by the data's own magnitude.
 RTOL = 1e-4
 
-#: (program, {size symbol: int}, double_buffer) for the library path
+#: (program, {size symbol: int}) for the library path
 LIBRARY = (
-    ("cosmo", {"Nk": 80, "Nj": 1024, "Ni": 1024}, False),
-    ("heat3d", {"Nk": 512, "Nj": 512, "Ni": 512}, False),
-    ("normalization", {"Nj": 8192, "Ni": 8192}, False),
-    ("hydro1d", {"Nj": 8192, "Ni": 8192}, False),
+    ("cosmo", {"Nk": 80, "Nj": 1024, "Ni": 1024}),
+    ("heat3d", {"Nk": 512, "Nj": 512, "Ni": 512}),
+    ("normalization", {"Nj": 8192, "Ni": 8192}),
+    ("hydro1d", {"Nj": 8192, "Ni": 8192}),
     # rows and lanes that are not multiples of the (8, 128) tile: the
-    # DMA groups reach into the tile padding
-    ("laplace5", {"Nj": 4100, "Ni": 4100}, True),
-    ("heat3d", {"Nk": 512, "Nj": 512, "Ni": 512}, True),
+    # last row block and lane tile are partial
+    ("laplace5", {"Nj": 4100, "Ni": 4100}),
 )
 
 #: (program, {size symbol: int}, requests) for the serving path
@@ -85,7 +84,7 @@ def _errors(got: dict, want: dict) -> tuple[float, float]:
     return err, rel
 
 
-def library_phase(name: str, sizes: dict, double_buffer: bool) -> str:
+def library_phase(name: str, sizes: dict) -> str:
     import jax
 
     from repro import trace
@@ -94,8 +93,7 @@ def library_phase(name: str, sizes: dict, double_buffer: bool) -> str:
     from repro.core.unfused import build_unfused
 
     prog = ALL_PROGRAMS[name]()
-    gen = compile_program(prog, backend="pallas",
-                          double_buffer=double_buffer)
+    gen = compile_program(prog, backend="pallas")
     shapes = _shapes(prog, sizes)
     keys = jax.random.split(jax.random.key(SEED), len(shapes))
     arrays = {n: jax.random.normal(k, s, jax.numpy.float32)
@@ -169,8 +167,7 @@ def main() -> int:
               "chip", file=sys.stderr)
         return 2
     print(f"compile cache {enable_compile_cache()}", flush=True)
-    phases = [(f"library {n} double_buffer={db}", library_phase, (n, s, db))
-              for n, s, db in LIBRARY]
+    phases = [(f"library {n}", library_phase, (n, s)) for n, s in LIBRARY]
     phases += [(f"serving {n}", serving_phase, (n, s, r))
                for n, s, r in SERVING]
     failed = []

@@ -92,7 +92,6 @@ def main():
     # rolling buffers in VMEM, a tile of rows per grid step.  Off-TPU
     # it runs in interpret mode, so we validate on a small grid (the
     # grid unrolls at trace time); on a TPU it compiles with Mosaic.
-    # double_buffer=True selects the explicit two-slot input-DMA pipeline.
     small = cell[:24, :]
     gen_p = compile_program(prog, backend="pallas")
     perr = float(jnp.abs(

@@ -6,7 +6,7 @@
 __version__ = "0.9.0"
 
 from .codegen_jax import Generated
-from .codegen_pallas import PallasGenerated, generate_pallas, plan_pallas
+from .codegen_pallas import PallasGenerated, plan_pallas
 from .engine import (BACKENDS, BatchedGenerated, clear_compile_cache,
                      compile_batched, compile_cache_size,
                      compile_program, explain, pallas_auto_viable,
@@ -26,8 +26,9 @@ from .plan import (PLAN_FEATURES, SCHEMA_VERSION, CallPlan, KernelPlan,
                    register_step_builder, unregister_step_builder)
 from .plancache import PlanCache, program_plan_key
 from .plancheck import (Diagnostic, PlanCheckError, PlanCheckWarning,
-                        check_plan, has_errors, pad_to_lane,
-                        sizes_from_arrays, vmem_bytes, vmem_report)
+                        check_plan, has_errors, sizes_from_arrays,
+                        vmem_bytes, vmem_report)
+from .tiling import pad_to_lane
 from .vecscan import (ACCESS_CLASSES, AccessSite, VecReport,
                       attach_layout_hints, auto_vec_reject, render_vec,
                       scan_plan)
@@ -53,7 +54,7 @@ __all__ = [
     "sizes_from_arrays",
     "unregister_interpreter", "vmem_bytes",
     "vmem_report",
-    "compile_program", "fn_key", "generate_pallas",
+    "compile_program", "fn_key",
     "pallas_auto_viable", "plan_cache_cap", "plan_cache_size", "plan_pallas",
     "program_plan_key", "program_signature", "register_pallas_split_win",
     "register_step_builder", "set_plan_cache_cap", "unregister_step_builder",

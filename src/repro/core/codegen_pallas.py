@@ -43,20 +43,17 @@ in :mod:`repro.core.plan`, which owns all ``PallasUnsupported`` raise
 sites (the live table is docs/BACKENDS.md); the finished plan is
 re-checked by :meth:`KernelPlan.validate` before it leaves this module.
 
-:func:`generate_pallas` composes the planner with the interpreter for
-the engine's dispatch layer; :func:`plan_pallas` is the pure
-program-to-plan entry point used by tests and ``explain(verbose=True)``.
+:func:`plan_pallas` is the pure program-to-plan entry point; the engine
+(:func:`repro.core.engine.compile_program`) hands its plan to a
+registered interpreter through the shared host half.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Callable, Optional
 
-import jax.numpy as jnp
-
-from .dataflow import Group, build_dataflow
-from .fusion import fuse_inest_dag
-from .infer import IDAG, infer
+from .dataflow import Group
+from .infer import IDAG
 from .inest import walk_bodies
 from .plan import (AccPlan, AxiomPlan, CallPlan, GridDim, HostStepPlan,
                    InputPlan, KernelPlan, OutputPlan, PallasUnsupported,
@@ -73,13 +70,10 @@ from .plan import (AccPlan, AxiomPlan, CallPlan, GridDim, HostStepPlan,
                    require_representable_write, require_row_contraction,
                    require_row_kept_vector_only, require_same_step_position,
                    require_scalar_acc_stream, require_streamed_suffix)
-from .reuse import (StoragePlan, VarPlan, analyze_storage, dim_window,
-                    produced_window)
-from .rules import Program
+from .reuse import StoragePlan, VarPlan, dim_window, produced_window
 from .terms import Term
 
-__all__ = ["PallasGenerated", "PallasUnsupported", "plan_pallas",
-           "generate_pallas", "compile_program_pallas"]
+__all__ = ["PallasGenerated", "PallasUnsupported", "plan_pallas"]
 
 
 def _env_name(vp: VarPlan) -> str:
@@ -618,38 +612,3 @@ class PallasGenerated:
                 "StoragePlan/schedule exists (recompile without "
                 "plan_cache_dir to inspect the schedule)")
         return self.plan.schedule
-
-
-def generate_pallas(plan: StoragePlan, idag: IDAG, *, dtype=jnp.float32,
-                    interpret: Optional[bool] = None,
-                    double_buffer: bool = False) -> PallasGenerated:
-    """Plan + interpret: emit the Pallas execution of a storage plan.
-
-    ``interpret=True`` runs the kernel bodies on CPU for validation;
-    the default ``None`` does so exactly where the default backend is
-    not a TPU.  ``double_buffer=True`` switches the
-    interpreter's input streaming from BlockSpec row fetches to the
-    explicit two-slot async-DMA pipeline (see
-    :func:`repro.kernels.stencil2d.kernel.build_call`)."""
-    kplan = plan_pallas(plan, idag)
-    # imported lazily: the interpreter imports the plan IR from
-    # repro.core, so a module-level import here would be circular
-    from ..kernels.stencil2d.kernel import execute_plan
-    fn = execute_plan(kplan, dtype=dtype, interpret=interpret,
-                      double_buffer=double_buffer)
-    return PallasGenerated(kplan, fn, plan)
-
-
-def compile_program_pallas(
-    program: Program, *, dtype=jnp.float32,
-    interpret: Optional[bool] = None, double_buffer: bool = False
-) -> PallasGenerated:
-    """Engine pipeline + Pallas emission (standalone entry point; prefer
-    :func:`repro.core.engine.compile_program` with ``backend='pallas'``,
-    which shares the pipeline and caches compilations)."""
-    idag = infer(program)
-    dag = build_dataflow(idag)
-    schedule = fuse_inest_dag(dag)
-    plan = analyze_storage(schedule)
-    return generate_pallas(plan, idag, dtype=dtype, interpret=interpret,
-                           double_buffer=double_buffer)

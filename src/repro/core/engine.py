@@ -36,8 +36,8 @@ The full routing rules, the cache keys, and the table of remaining
 ``PallasUnsupported`` shapes live in docs/BACKENDS.md.
 
 Compiled results are cached at two levels: a fast path keyed on
-(program signature, backend, dtype, interpret, double_buffer) — with
-flags an interpreter declares it does not honor normalized out — and,
+(program signature, backend, dtype, interpret) — with flags an
+interpreter declares it does not honor normalized out — and,
 for every registry backend, a **plan-level** cache keyed on
 (interpreter name, :meth:`KernelPlan.cache_key`), so two
 differently-built programs that lower to structurally equal plans
@@ -214,7 +214,7 @@ def pallas_auto_viable(plan: StoragePlan) -> bool:
     return plan.schedule.program.name in PALLAS_SPLIT_WINS
 
 
-def _run_plancheck(kplan: KernelPlan, mode: str, *, dtype, double_buffer,
+def _run_plancheck(kplan: KernelPlan, mode: str, *, dtype,
                    dim_sizes=None) -> None:
     """Gate a plan on the static analyzer (:mod:`repro.core.plancheck`)
     per the resolved ``check_plans`` mode: ``"error"`` raises
@@ -229,7 +229,7 @@ def _run_plancheck(kplan: KernelPlan, mode: str, *, dtype, double_buffer,
         diags = check_plan(kplan,
                            sizes=dict(dim_sizes) if dim_sizes else None,
                            dtype_bytes=jnp.dtype(dtype).itemsize,
-                           double_buffer=double_buffer, validate=False)
+                           validate=False)
     if not diags:
         return
     if mode == "error" and has_errors(diags):
@@ -241,8 +241,7 @@ def _run_plancheck(kplan: KernelPlan, mode: str, *, dtype, double_buffer,
 
 
 def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
-               interpreter, dtype, interpret, double_buffer,
-               use_cache=True, check="warn",
+               interpreter, dtype, interpret, use_cache=True, check="warn",
                dim_sizes=None, apply_mode="off") -> PallasGenerated:
     """Build (or fetch) the named registered interpreter for a finished
     kernel plan.
@@ -276,8 +275,7 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
             sizes=dict(dim_sizes) if dim_sizes else None)
         kplan = layout_result.plan
     pkey = (interpreter, kplan.cache_key(), jnp.dtype(dtype).name,
-            bool(interpret) and "interpret" in spec.flags,
-            bool(double_buffer) and "double_buffer" in spec.flags)
+            bool(interpret) and "interpret" in spec.flags)
     if use_cache:
         hit = _PLAN_CACHE.get(pkey)
         count("hfav.plan_cache.hit" if hit is not None
@@ -290,15 +288,14 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
                 # shared artifact so .schedule works everywhere
                 hit.plan = plan
             return hit
-    _run_plancheck(kplan, check, dtype=dtype, double_buffer=double_buffer,
-                   dim_sizes=dim_sizes)
+    _run_plancheck(kplan, check, dtype=dtype, dim_sizes=dim_sizes)
     # the shared host half resolves the interpreter's build_call through
     # the registry (and runs the capability check, raising the typed
     # PlanUnsupported for plans outside the declared feature set)
     from .interpreters import execute_plan
     with span("hfav.emit"):
         fn = execute_plan(kplan, interpreter=interpreter, dtype=dtype,
-                          interpret=interpret, double_buffer=double_buffer)
+                          interpret=interpret)
         gen = PallasGenerated(kplan, fn, plan, interpreter=interpreter)
     gen.base_plan = base_plan
     gen.layout_result = layout_result
@@ -310,7 +307,7 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
 
 
 def _emit_pallas(plan, idag, *, interpreter, dtype, interpret,
-                 double_buffer, use_cache=True, check="warn",
+                 use_cache=True, check="warn",
                  dim_sizes=None, apply_mode="off") -> PallasGenerated:
     """Plan, then interpret — through the plan-level cache.
 
@@ -320,9 +317,8 @@ def _emit_pallas(plan, idag, *, interpreter, dtype, interpret,
     with span("hfav.plan_pallas"):
         kplan = plan_pallas(plan, idag)
     return _emit_plan(kplan, plan, interpreter=interpreter, dtype=dtype,
-                      interpret=interpret, double_buffer=double_buffer,
-                      use_cache=use_cache, check=check, dim_sizes=dim_sizes,
-                      apply_mode=apply_mode)
+                      interpret=interpret, use_cache=use_cache, check=check,
+                      dim_sizes=dim_sizes, apply_mode=apply_mode)
 
 
 def _load_plan_from_disk(program: Program, backend: str,
@@ -364,7 +360,7 @@ def _store_plan_to_disk(program: Program, kplan: KernelPlan,
         pass
 
 
-def _pallas_auto_probe(plan, idag, *, dtype, interpret, double_buffer,
+def _pallas_auto_probe(plan, idag, *, dtype, interpret,
                        use_cache=True, check="warn", dim_sizes=None,
                        apply_mode="off"):
     """The single auto-routing probe shared by :func:`compile_program`
@@ -390,8 +386,7 @@ def _pallas_auto_probe(plan, idag, *, dtype, interpret, double_buffer,
     if dim_sizes:
         with span("hfav.autoprobe"):
             est = vmem_bytes(kplan, dict(dim_sizes),
-                             dtype_bytes=jnp.dtype(dtype).itemsize,
-                             double_buffer=double_buffer)
+                             dtype_bytes=jnp.dtype(dtype).itemsize)
             if est > vmem_budget(None):
                 return None
             if auto_vec_reject(kplan, dict(dim_sizes),
@@ -399,9 +394,9 @@ def _pallas_auto_probe(plan, idag, *, dtype, interpret, double_buffer,
                 return None
     try:
         return _emit_plan(kplan, plan, interpreter="pallas", dtype=dtype,
-                          interpret=interpret, double_buffer=double_buffer,
-                          use_cache=use_cache, check=check,
-                          dim_sizes=dim_sizes, apply_mode=apply_mode)
+                          interpret=interpret, use_cache=use_cache,
+                          check=check, dim_sizes=dim_sizes,
+                          apply_mode=apply_mode)
     except PlanCheckError:
         return None
 
@@ -426,7 +421,6 @@ def compile_program(
     *,
     dtype=jnp.float32,
     interpret: Optional[bool] = None,
-    double_buffer: bool = False,
     use_cache: bool = True,
     plan_cache_dir=None,
     check_plans: Optional[str] = None,
@@ -436,9 +430,8 @@ def compile_program(
 ) -> Union[Generated, PallasGenerated]:
     """Compile ``program`` through the HFAV pipeline onto a backend.
 
-    ``interpret`` and ``double_buffer`` only affect the Pallas backend
-    (CPU validation vs TPU execution, and BlockSpec streaming vs the
-    explicit two-slot DMA pipeline).  ``interpret=None`` resolves to
+    ``interpret`` only affects the Pallas backend (CPU validation vs
+    TPU execution).  ``interpret=None`` resolves to
     interpret mode exactly where the default backend is not a TPU
     (:func:`repro.core.interpreters.resolve_interpret`), and the cache
     keys hold the resolved value.  Results are memoized; pass
@@ -513,14 +506,11 @@ def compile_program(
             plan_cache_dir = os.environ.get(PLAN_CACHE_DIR_ENV) or None
         sizes_key = tuple(sorted(dim_sizes.items())) if dim_sizes else None
         # flags an interpreter does not honor are normalized out of the key
-        # (a pure-JAX interpreter compiles identically either way); for the
-        # legacy "jax" emitter only double_buffer is moot, matching the
-        # pre-registry key shape exactly — and apply_layout normalizes to
-        # "off" for layout-oblivious backends, where the pass never runs
+        # (a pure-JAX interpreter compiles identically either way), and
+        # apply_layout normalizes to "off" for layout-oblivious backends,
+        # where the pass never runs
         key = (program_signature(program), backend, jnp.dtype(dtype).name,
                bool(interpret) and (spec is None or "interpret" in spec.flags),
-               bool(double_buffer) and backend != "jax"
-               and (spec is None or "double_buffer" in spec.flags),
                sizes_key,
                apply_mode if spec is not None and spec.layout_aware
                else "off")
@@ -556,7 +546,6 @@ def compile_program(
                                  interpreter="pallas" if backend == "auto"
                                  else backend,
                                  dtype=dtype, interpret=interpret,
-                                 double_buffer=double_buffer,
                                  use_cache=use_cache, check=check,
                                  dim_sizes=dim_sizes, apply_mode=apply_mode)
                 if use_cache:
@@ -568,7 +557,6 @@ def compile_program(
                 gen: Union[Generated, PallasGenerated] = generate(plan, idag)
         elif backend == "auto":
             gen = _pallas_auto_probe(plan, idag, dtype=dtype, interpret=interpret,
-                                     double_buffer=double_buffer,
                                      use_cache=use_cache, check=check,
                                      dim_sizes=dim_sizes, apply_mode=apply_mode)
             if gen is None:
@@ -576,7 +564,7 @@ def compile_program(
                     gen = generate(plan, idag)
         else:
             gen = _emit_pallas(plan, idag, interpreter=backend, dtype=dtype,
-                               interpret=interpret, double_buffer=double_buffer,
+                               interpret=interpret,
                                use_cache=use_cache, check=check,
                                dim_sizes=dim_sizes, apply_mode=apply_mode)
         if plan_cache_dir is not None and isinstance(gen, PallasGenerated):
@@ -585,10 +573,6 @@ def compile_program(
                 plan_cache_dir)
         if use_cache:
             _CACHE[key] = gen
-            if key[4] and isinstance(gen, Generated):
-                # double_buffer had no effect (auto fell back to JAX): alias
-                # the normalized key so neither flag value recompiles
-                _CACHE[key[:4] + (False,) + key[5:]] = gen
         return _attach_vec_report(gen, vec_report, dim_sizes, dtype)
 
 
@@ -654,8 +638,7 @@ def compile_batched(
 
 
 def explain(program: Program, *, dtype=jnp.float32,
-            interpret: Optional[bool] = None,
-            double_buffer: bool = False, verbose: bool = False,
+            interpret: Optional[bool] = None, verbose: bool = False,
             dim_sizes=None, apply_layout: Optional[str] = None) -> str:
     """Human-readable transformation report (the paper's debugging output).
 
@@ -663,8 +646,8 @@ def explain(program: Program, *, dtype=jnp.float32,
     shared probe (:func:`_pallas_auto_probe`), so the reported
     ``auto backend`` is exactly what ``backend="auto"`` would pick for a
     compilation with those flags — including split-win routing,
-    non-default ``double_buffer``/``dtype``, and (when ``dim_sizes``
-    is given) the VMEM-budget consult.
+    a non-default ``dtype``, and (when ``dim_sizes`` is given) the
+    VMEM-budget consult.
 
     ``verbose=True`` appends the rendered
     :class:`~repro.core.plan.KernelPlan` (grid ranges, window and
@@ -687,7 +670,6 @@ def explain(program: Program, *, dtype=jnp.float32,
     dag = schedule.dag
     gen = _pallas_auto_probe(plan, idag, dtype=dtype,
                              interpret=resolve_interpret(interpret),
-                             double_buffer=double_buffer,
                              dim_sizes=dim_sizes)
     backend = "pallas" if gen is not None else "jax"
     lines = [
@@ -709,8 +691,7 @@ def explain(program: Program, *, dtype=jnp.float32,
             lines.extend(render_vmem(gen.kernel_plan, dtype_bytes=itemsize))
             if dim_sizes:
                 rep = vmem_report(gen.kernel_plan, dict(dim_sizes),
-                                  dtype_bytes=itemsize,
-                                  double_buffer=double_buffer)
+                                  dtype_bytes=itemsize)
                 for nest, r in rep.items():
                     lines.append(
                         f"  {nest}: {r['total']} B resident "

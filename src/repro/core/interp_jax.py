@@ -9,9 +9,9 @@ KernelPlan* the Pallas stencil interpreter runs — a transliteration of
 only as the ``backend="jax"`` fallback for shapes the planner rejects).
 
 The Pallas grid becomes one ``lax.fori_loop`` over the linearized step
-count; the linear index is decomposed by the same odometer the
-double-buffer DMA pipeline uses (last dimension fastest — the fused
-nest's traversal order), and all VMEM scratch becomes loop-carried
+count; the linear index is decomposed by an odometer, last dimension
+fastest (the fused nest's traversal order), and all VMEM scratch becomes
+loop-carried
 state: rolling row windows ``(stages, width)``, streamed and producer
 plane windows ``(p_stages, rows, width)``, accumulator rows, and the
 outputs themselves.  Every mechanism keeps the reference semantics
@@ -42,9 +42,9 @@ device-side lane pre-folds for row-kept reductions
 (``OutputPlan.lane_block``: each partial row folds to one lane-wide
 chunk before the host's cross-lane reduce).
 
-``interpret`` and ``double_buffer`` are accepted and ignored (there is
-no kernel to interpret and no DMA to stage); the registry spec declares
-an empty flag set so the engine normalizes both out of its cache keys.
+``interpret`` is accepted and ignored (there is no kernel to
+interpret); the registry spec declares an empty flag set so the engine
+normalizes it out of its cache keys.
 """
 from __future__ import annotations
 
@@ -63,7 +63,7 @@ def _mod(pos, stages: int):
 
 
 def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
-               interpret: bool = False, double_buffer: bool = False):
+               interpret: bool = False):
     """Concretize one :class:`CallPlan` as a jitted-JAX callable.
 
     Mirrors the Pallas ``build_call`` contract: ``sizes`` is
@@ -71,7 +71,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     ``fn`` maps the call's input arrays (scalars as ``(1, 1)``) to one
     output per ``call.outputs`` entry (a list when several): goal-shaped
     for ``external`` outputs, in the grid's frame for the others.
-    ``interpret``/``double_buffer`` are ignored — see module docstring.
+    ``interpret`` is ignored — see module docstring.
     """
     n_out = call.n_outer
     if len(sizes) != n_out + 2:

@@ -19,7 +19,7 @@ Two interpreters self-register on first use:
 
 * ``"pallas"`` — the Pallas TPU stencil interpreter
   (:mod:`repro.kernels.stencil2d.kernel`): VMEM scratch windows,
-  BlockSpec or double-buffered DMA row streaming;
+  BlockSpec row streaming;
 * ``"interp_jax"`` — the pure-JAX plan interpreter
   (:mod:`repro.core.interp_jax`): the same plan semantics transliterated
   onto a ``lax.fori_loop`` over the linearized grid, replacing the
@@ -54,9 +54,8 @@ import jax.numpy as jnp
 from ..trace import count, span
 from .plan import (PLAN_FEATURES, CallPlan, KernelPlan, OutputPlan,
                    PallasUnsupported)
-from .plancheck import (call_vmem, row_tile, scoped_vmem_limit,
-                        seat_geometry)
 from .runtime import lane_reduce
+from .tiling import call_tiling
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
@@ -79,14 +78,14 @@ class PlanUnsupported(PallasUnsupported):
 class InterpreterSpec:
     """One registered plan interpreter.
 
-    ``build_call(call, sizes, dtype, interpret=..., double_buffer=...)``
-    concretizes a :class:`~repro.core.plan.CallPlan` to
-    ``(fn, steps_j)`` under the shared output contract (see the module
-    docstring).  ``capabilities`` is the subset of
+    ``build_call(call, sizes, dtype, interpret)`` concretizes a
+    :class:`~repro.core.plan.CallPlan` to ``(fn, steps_j)`` under the
+    shared output contract (see the module docstring).
+    ``capabilities`` is the subset of
     :data:`~repro.core.plan.PLAN_FEATURES` the interpreter executes;
     ``flags`` names the execution flags it actually honors (subset of
-    ``{"interpret", "double_buffer"}``) so the engine can normalize
-    un-honored flags out of its cache keys.  ``layout_aware`` declares
+    ``{"interpret"}``) so the engine can normalize un-honored flags out
+    of its cache keys.  ``layout_aware`` declares
     that ``build_call`` executes the constructs the LayoutApply pass
     (:mod:`repro.core.layoutapply`) writes when it realizes the plan's
     advisory :attr:`~repro.core.plan.KernelPlan.layout_hints`
@@ -388,25 +387,18 @@ def _assemble(call: CallPlan, out: OutputPlan, dev, nj: int, ni: int,
                            slice(out.i_lo, out.i_lo + w))]
 
 
-def _grid_steps(call: CallPlan, n_outs: tuple[int, ...], nj: int, ni: int,
-                dtype, double_buffer: bool) -> tuple[int, int, int, int]:
-    """Grid steps of one stencil call, the rows R each computes
-    (:func:`repro.core.plancheck.row_tile`), the VMEM the call needs at
-    that R (:func:`repro.core.plancheck.call_vmem`) and the scoped limit
-    passed for it, 0 for the compiler's default
-    (:func:`repro.core.plancheck.scoped_vmem_limit`), as the Pallas
-    ``build_call`` lays out its grid
-    (:func:`repro.core.plancheck.seat_geometry`) and sizes its VMEM."""
-    itemsize = jnp.dtype(dtype).itemsize
-    rows = row_tile(call, nj, ni, itemsize, double_buffer)
-    steps = seat_geometry(call, n_outs, nj, rows, itemsize).steps
-    need = call_vmem(call, nj, ni, itemsize, double_buffer, rows=rows)["total"]
-    return steps, rows, need, scoped_vmem_limit(need) or 0
+def _grid_steps(call: CallPlan, sizes: tuple[int, ...],
+                dtype) -> tuple[int, int, int, int]:
+    """Grid steps of one stencil call, the rows R each computes, the VMEM
+    the call needs at that R and the scoped limit passed for it, 0 for
+    the compiler's default: the :func:`repro.core.tiling.call_tiling`
+    that the Pallas ``build_call`` builds its grid and VMEM from."""
+    t = call_tiling(call, sizes, jnp.dtype(dtype).itemsize)
+    return t.seats.steps, t.rows, t.vmem_need, t.vmem_limit or 0
 
 
 def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
-                 dtype=jnp.float32, interpret: Optional[bool] = None,
-                 double_buffer: bool = False):
+                 dtype=jnp.float32, interpret: Optional[bool] = None):
     """Build the host callable executing a full :class:`KernelPlan` on
     the named registered interpreter.
 
@@ -418,9 +410,9 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     threads intermediate arrays through the environment.  The capability
     check runs here, so a plan outside the interpreter's declared
     feature set raises :class:`PlanUnsupported` before anything builds.
-    ``interpret`` (resolved by :func:`resolve_interpret`) and
-    ``double_buffer`` are forwarded to ``build_call``; interpreters that
-    don't honor a flag accept and ignore it.
+    ``interpret`` (resolved by :func:`resolve_interpret`) is forwarded
+    to ``build_call``; an interpreter that does not honor it accepts and
+    ignores it.
 
     The span ``hfav.build_call`` (attributes ``call``, ``grid_steps``,
     ``row_tile``, ``vmem_need_bytes`` and ``vmem_limit_bytes``) and the
@@ -464,8 +456,8 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
             for hs in cp.host_pre:
                 _run_host(cp, hs, env)
             if cp.has_grid:
-                steps, rows, need, limit = _grid_steps(
-                    cp, n_outs, nj, ni, dtype, double_buffer)
+                call_sizes = (*n_outs, nj, ni)
+                steps, rows, need, limit = _grid_steps(cp, call_sizes, dtype)
                 count("hfav.grid_steps", steps)
                 count("hfav.row_tile", rows)
                 count("hfav.vmem_need_bytes", need)
@@ -476,9 +468,7 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
                 with span("hfav.build_call", call=cp.name, grid_steps=steps,
                           row_tile=rows, vmem_need_bytes=need,
                           vmem_limit_bytes=limit):
-                    pcall, _ = spec.build_call(cp, (*n_outs, nj, ni), dtype,
-                                               interpret=interpret,
-                                               double_buffer=double_buffer)
+                    pcall, _ = spec.build_call(cp, call_sizes, dtype, interpret)
                     args = []
                     for ispec in cp.inputs:
                         v = jnp.asarray(env[ispec.name], dtype)

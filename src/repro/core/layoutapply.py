@@ -71,7 +71,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 from .plan import (KernelPlan, LanePass, LayoutHint, VecLoadPlan)
-from .plancheck import LANE
+from .tiling import LANE
 
 #: Hint kinds this pass can realize, in application order.  The docs
 #: table in docs/ARCHITECTURE.md is guarded against this tuple both

@@ -740,6 +740,21 @@ class CallPlan:
         """Per-outer-dim canonical range end offsets."""
         return tuple(g.hi_off for g in self.grid[:-1])
 
+    def writers(self) -> dict:
+        """Map each produced name (``b_<w>``, ``local:<v>``) and output
+        index (``("out", i)``) to the list of step indices writing it."""
+        table: dict = {}
+        for si, step in enumerate(self.steps):
+            for targets in step.writes:
+                for kind, tgt in targets:
+                    if kind == "buf":
+                        table.setdefault(tgt, []).append(si)
+                    elif kind == "local":
+                        table.setdefault(f"local:{tgt}", []).append(si)
+                    else:
+                        table.setdefault(("out", int(tgt)), []).append(si)
+        return table
+
     def to_dict(self) -> dict:
         """JSON-native form (schema :data:`SCHEMA_VERSION`); the fn
         table serializes as function specs (:func:`fn_to_spec`)."""

@@ -86,7 +86,8 @@ from dataclasses import dataclass
 from typing import Optional
 
 from .plan import CallPlan, KernelPlan, LayoutHint
-from .plancheck import LANE, Diagnostic, pad_to_lane
+from .plancheck import Diagnostic
+from .tiling import LANE, pad_to_lane
 
 #: Access-pattern classes, in decreasing order of vector efficiency
 #: (``register`` costs nothing: it is LayoutApply's carried-vector

@@ -17,14 +17,14 @@ and the row dim onto row steps, each covering its canonical range
 by plane-window warm-up tiles.  The Pallas grid is one dimension that
 numbers those steps in the nest's traversal order, last dimension
 fastest, plus the steps that seating the external outputs needs
-(:func:`repro.core.plancheck.seat_geometry`: one at the end, and any
+(:func:`repro.core.tiling.seat_geometry`: one at the end, and any
 tile or row step that only writes halo blocks), which compute nothing.
 TPU grids execute sequentially, so VMEM scratch carries state both
 across row tiles *and* across outer-tile boundaries.  Each grid step
 computes a tile of R consecutive rows
-(:func:`repro.core.plancheck.row_tile`: a multiple of the sublane tile
+(:func:`repro.core.tiling.row_tile`: a multiple of the sublane tile
 chosen from the plan, the dtype and the shape, or 1 for calls with
-accumulators and for ``double_buffer=True``).  Row step ``jid`` covers
+accumulators or row recurrences).  Row step ``jid`` covers
 canonical positions ``jid * R + x_lo`` onward and:
 
 1. takes R new rows per array input into that input's VMEM window,
@@ -34,10 +34,8 @@ canonical positions ``jid * R + x_lo`` onward and:
    ``x_lo + lead - j_lo`` is not on the tile is brought in that many
    rows ahead.  One-row steps take their row from an 8-row
    (sublane-tile) group that consecutive grid steps revisit — Mosaic
-   refuses one-row blocks — either through the BlockSpec index map, or,
-   with ``double_buffer=True``, through an explicitly double-buffered
-   ``make_async_copy`` pair that prefetches the next group while the
-   current row is being consumed.  Inputs read at non-zero offsets in
+   refuses one-row blocks — through the same BlockSpec index map.
+   Inputs read at non-zero offsets in
    the *plane dim* (the outer identifier adjacent to the row dim —
    ``u[k-1][j][i]`` stencils) use a *multi-plane window* instead of a
    rolling row window: ``(p_stages, rows, width)`` VMEM where whole
@@ -71,7 +69,7 @@ canonical positions ``jid * R + x_lo`` onward and:
 3. writes its rows of each terminal output as one ``(R, ni)`` store (the
    fill, then the values at their static lane offset).  An ``external``
    output (a goal) is written at its seat in a goal-shaped array
-   (:class:`repro.core.plancheck.Seat`), rows and planes outside the
+   (:class:`repro.core.tiling.Seat`), rows and planes outside the
    goal masked to the fill: with R > 1 a goal block of R rows straddles
    two steps' tiles, so the step stores its rows in a VMEM carry buffer
    and the next step writes the block, from the carried rows and the
@@ -87,26 +85,19 @@ canonical positions ``jid * R + x_lo`` onward and:
 Rolling windows are padded to the 128-wide TPU lane tile (the
 vector-length expansion of Fig. 9c).  Warm-up/drain rows compute
 garbage rows that the seat masks to the fill, or, for outputs in the
-grid's frame, that :func:`execute_plan`'s host layer slices away — the
-masked steady-state ('HFAV + Tuning') form.
+grid's frame, that the host half
+(:func:`repro.core.interpreters.execute_plan`) slices away — the masked
+steady-state ('HFAV + Tuning') form.
 
 All row widths in the plan are stored as *deltas against Ni* (and row
 counts as deltas against Nj) so one plan serves every problem size; they
-are concretized in :func:`build_call`.
-
-:func:`execute_plan` is the host half of the interpreter: it resolves
-runtime sizes through the plan's :class:`~repro.core.plan.AxiomPlan`
-shape contracts, threads the environment between stencil calls and host
-steps, hands each seated output on as it is and assembles the others
-back to their canonical arrays (trim warm-up rows/tiles, re-seat origins,
-lane-reduce folded accumulators) — exactly as the plan's
-:class:`OutputPlan` trim/seat rules dictate.
+are concretized in :func:`build_call`, whose R, row geometry, seats and
+VMEM all come from one :func:`repro.core.tiling.call_tiling`.
 """
 from __future__ import annotations
 
 import functools
 import operator
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -115,21 +106,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.interpreters import (InterpreterSpec, register_interpreter,
                                   require_hazard_free, require_linked_fns)
-from ...core.plan import (PLAN_FEATURES, CallPlan, KernelPlan,
-                          PallasUnsupported, WindowPlan)
-from ...core.plancheck import (VMEM_CAPACITY, call_vmem, row_geometry,
-                               row_tile, scoped_vmem_limit, seat_geometry)
-
-LANE = 128
-#: Rows per streamed block of one-row grid steps: one f32 sublane tile.
-#: Mosaic accepts a block only when its last two dims are multiples of
-#: (8, 128) or equal to the array's, so those rows move between HBM and
-#: VMEM in 8-row groups that consecutive grid steps revisit.
-SUBLANE = 8
-
-
-def _pad_to_lane(w: int) -> int:
-    return max(LANE, ((w + LANE - 1) // LANE) * LANE)
+from ...core.plan import (PLAN_FEATURES, CallPlan, PallasUnsupported,
+                          WindowPlan)
+from ...core.tiling import SUBLANE, VMEM_CAPACITY, call_tiling, pad_to_lane
 
 
 def _mod(pos, stages: int):
@@ -145,7 +124,7 @@ def _per_row(fn, ins):
 
 
 def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
-               interpret: bool = False, double_buffer: bool = False):
+               interpret: bool = False):
     """Concretize one :class:`CallPlan` for a problem size and build the
     pallas_call.
 
@@ -161,26 +140,21 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     Carried-accumulator outputs are ``(1, width)`` and kept-prefix
     accumulator outputs ``(*kept grid, 1, width)``.
 
-    Each grid step computes a tile of R rows
-    (:func:`repro.core.plancheck.row_tile`, from the plan, the dtype and
+    The tiling comes from :func:`repro.core.tiling.call_tiling`.  Each
+    grid step computes a tile of R rows (from the plan, the dtype and
     the shape), so each tile has ``cdiv(steps_j, R)`` row steps and the
-    last row block may be partial; the grid is one dimension of
-    :func:`repro.core.plancheck.seat_geometry`'s steps.  Array inputs
-    and row outputs move in R-row blocks, one per step, an external
-    output's block written one step late from a VMEM carry buffer.
-    Where R is 1 (calls with accumulators, or ``double_buffer=True``)
-    they move in 8-row blocks that consecutive grid steps revisit, the
-    step reading (or writing) its row at ``row % 8`` inside the block,
-    ``goal row % 8`` for an external output.  ``double_buffer=True``
-    replaces the BlockSpec input streaming with an explicit two-slot
-    async-DMA pipeline: array inputs stay in HBM
-    (``memory_space=ANY``); whenever the next grid step needs a new
-    8-row group, the current step starts its copy into the other slot,
-    so the input DMA overlaps the compute of the current row.
+    last row block may be partial; the grid is one dimension of the
+    seat geometry's steps.  Array inputs and row outputs move in R-row
+    blocks, one per step, an external output's block written one step
+    late from a VMEM carry buffer.  Where R is 1 (calls with
+    accumulators or row recurrences) they move in 8-row blocks that
+    consecutive grid steps revisit, the step reading (or writing) its
+    row at ``row % 8`` inside the block, ``goal row % 8`` for an
+    external output.
 
-    The VMEM the call needs (:func:`repro.core.plancheck.call_vmem`) is
-    passed on as the compiler's scoped VMEM limit when it exceeds the
-    default; a call that cannot fit the core's VMEM is refused with
+    The VMEM the call needs is passed on as the compiler's scoped VMEM
+    limit when it exceeds the default; a call that cannot fit the
+    core's VMEM is refused with
     :class:`~repro.core.plan.PallasUnsupported`."""
     n_out = call.n_outer
     if len(sizes) != n_out + 2:
@@ -195,9 +169,9 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     gsz = [outer_sizes[d] + o_hi[d] - o_lo[d] for d in range(n_out)]
     steps_j = (nj + call.x_hi_off) - call.x_lo
     out_rows = pl.cdiv(steps_j, SUBLANE) * SUBLANE
-    itemsize = jnp.dtype(dtype).itemsize
-    R = row_tile(call, nj, ni, itemsize, double_buffer)
-    geo = row_geometry(call, nj, R, itemsize)
+    tiling = call_tiling(call, sizes, jnp.dtype(dtype).itemsize)
+    R = tiling.rows
+    geo = tiling.geometry
     total_steps = geo.steps
     for s in gsz:
         total_steps *= s
@@ -212,7 +186,6 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     pwin_of = {w.name: w for w in plane_wins}
     acc_w = {a.name: ni + a.w_off for a in call.accs}
     ref_idx = {ispec.name: k for k, ispec in enumerate(call.inputs)}
-    in_h = {i.name: nj + (i.j_hi - i.j_lo) for i in arr_ins}
     in_w = {i.name: ni + (i.i_hi - i.i_lo) for i in arr_ins}
     # plane buffers by read/write name: (j_lo, i_lo, p_stages)
     plane_src = {f"in_{i.name}": (i.j_lo, i.i_lo, i.p_stages)
@@ -220,17 +193,11 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
     plane_src.update({w.name: (w.j_lo, w.i_lo, w.p_stages)
                       for w in plane_wins})
     rb = geo.block
-    # lanes per explicit DMA: Mosaic copies whole lane tiles, reaching
-    # into the lane padding of the TPU's tiled HBM layout, which the
-    # interpreter's arrays do not have
-    dma_w = {n: w if interpret else _pad_to_lane(w) for n, w in in_w.items()}
-    seat_geo = seat_geometry(call, outer_sizes, nj, R, itemsize)
+    seat_geo = tiling.seats
     seats = seat_geo.seats
     radix = seat_geo.radix
     n_tiled = seat_geo.steps - seat_geo.extra
     carried = [oi for oi in seats if R > 1]
-    n_scratch = len(roll_wins) + len(plane_ins) + len(plane_wins) \
-        + len(call.accs) + len(carried)
 
     def _digits(lin):
         """Tile of each outer dim and row step of grid step ``lin``, last
@@ -307,36 +274,6 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                 p = p + ispec.p_lead
             idxs.append(jnp.clip(p - ilos[li], 0, n_planes - 1))
         return idxs
-
-    def _group(ispec, pos_outer, j_id):
-        """Where ``ispec``'s row for one grid step sits in HBM (one-row
-        steps): its outer source indices, the first row of its
-        ``rb``-row group and the row's offset inside the group.  On the
-        TPU groups start on a sublane tile, so the last one of an array
-        whose rows are not a multiple of 8 reaches into the tile
-        padding, as the BlockSpec pipeline's own ragged last block does;
-        the interpreter, whose arrays have no padding, shifts that group
-        up instead."""
-        r = _src_row(ispec, j_id)
-        n = rb[ispec.name]
-        start = r - jax.lax.rem(r, n)
-        if interpret:
-            start = jnp.minimum(start, in_h[ispec.name] - n)
-        else:
-            start = pl.multiple_of(start, SUBLANE)
-        return _outer_src(ispec, pos_outer), start, r - start
-
-    def _decode(lin):
-        """Canonical outer positions and row-grid index of the
-        ``lin``-th step that computes (the nest's steps, last dimension
-        fastest; steps that compute nothing are not counted)."""
-        j_id = jax.lax.rem(lin, geo.steps)
-        rest = jax.lax.div(lin, geo.steps)
-        pos = [None] * n_out
-        for d in reversed(range(n_out)):
-            pos[d] = jax.lax.rem(rest, gsz[d]) + o_lo[d]
-            rest = jax.lax.div(rest, gsz[d])
-        return pos, j_id
 
     def kernel(*refs):
         nin = len(call.inputs)
@@ -474,83 +411,17 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
                         ref[pl.ds(at + R - keep, keep), :]
 
             # 1b. stream each array input's new rows into its VMEM window
-            if double_buffer and arr_ins:
-                dma_stage = dict(zip(
-                    (i.name for i in arr_ins),
-                    scratch[n_scratch:n_scratch + len(arr_ins)]))
-                dma_sems = scratch[n_scratch + len(arr_ins)]
-                cur_slot = scratch[n_scratch + len(arr_ins) + 1]
-                # Linear odometer: `lin` enumerates the steps that compute,
-                # in execution order; the neighbours decide whether the row
-                # group changes at this step (wait) or at the next (prefetch).
-                lin = jid
-                mult = geo.steps
-                for d in reversed(range(n_out)):
-                    lin = lin + outer_ids[d] * mult
-                    mult *= gsz[d]
-                prv = _decode(jnp.maximum(lin - 1, 0))
-                nxt = _decode(jnp.minimum(lin + 1, total_steps - 1))
-
-                def _copy(ai, ispec, group, to_slot):
-                    """The group DMA descriptor for one input (start and
-                    wait must agree on shape)."""
-                    idx, start, _ = group
-                    src_idx = tuple(pl.ds(i, 1) for i in idx)
-                    src_idx += (pl.ds(start, rb[ispec.name]),
-                                pl.ds(0, dma_w[ispec.name]))
-                    return pltpu.make_async_copy(
-                        in_refs[ref_idx[ispec.name]].at[src_idx],
-                        dma_stage[ispec.name].at[to_slot],
-                        dma_sems.at[ai, to_slot],
-                    )
-
-                def _moved(g0, g1):
-                    """Whether two steps' row groups differ."""
-                    return functools.reduce(
-                        operator.or_,
-                        [a != b for a, b in zip(g0[0], g1[0])] + [g0[1] != g1[1]])
-
-                for ai, ispec in enumerate(arr_ins):
-                    here = _group(ispec, opos, jid)
-                    fresh = (lin == 0) | _moved(here, _group(ispec, *prv))
-
-                    @pl.when(lin == 0)
-                    def _prime(_ai=ai, _sp=ispec, _g=here):
-                        cur_slot[_ai] = 0
-                        _copy(_ai, _sp, _g, 0).start()
-
-                    @pl.when((lin > 0) & fresh)
-                    def _flip(_ai=ai):
-                        cur_slot[_ai] = 1 - cur_slot[_ai]
-
-                    slot = cur_slot[ai]
-
-                    @pl.when(fresh)
-                    def _wait(_ai=ai, _sp=ispec, _g=here, _s=slot):
-                        _copy(_ai, _sp, _g, _s).wait()
-
-                    ahead = _group(ispec, *nxt)
-
-                    @pl.when((lin + 1 < total_steps) & _moved(ahead, here))
-                    def _prefetch(_ai=ai, _sp=ispec, _g=ahead, _s=slot):
-                        _copy(_ai, _sp, _g, 1 - _s).start()
-
-                    rows = dma_stage[ispec.name][
-                        (slot,) + (0,) * ispec.n_outer
-                        + (pl.ds(here[2], 1), pl.ds(0, in_w[ispec.name]))]
-                    _store_rows(ispec, rows, here[1] + here[2])
-            else:
-                for ispec in arr_ins:
-                    src = in_refs[ref_idx[ispec.name]]
-                    r = _src_row(ispec, jid)
-                    if R == 1:
-                        rows = src[(0,) * ispec.n_outer
-                                   + (pl.ds(jax.lax.rem(r, rb[ispec.name]), 1),
-                                      slice(None))]
-                    else:
-                        rows = src[(0,) * ispec.n_outer
-                                   + (slice(None), slice(None))]
-                    _store_rows(ispec, rows, r)
+            for ispec in arr_ins:
+                src = in_refs[ref_idx[ispec.name]]
+                r = _src_row(ispec, jid)
+                if R == 1:
+                    rows = src[(0,) * ispec.n_outer
+                               + (pl.ds(jax.lax.rem(r, rb[ispec.name]), 1),
+                                  slice(None))]
+                else:
+                    rows = src[(0,) * ispec.n_outer
+                               + (slice(None), slice(None))]
+                _store_rows(ispec, rows, r)
 
             # 2. fused steps, in dataflow order, at their leads, each over
             # the step's R rows
@@ -705,9 +576,6 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         if ispec.scalar:
             in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
             continue
-        if double_buffer:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            continue
         in_specs.append(pl.BlockSpec(
             (1,) * ispec.n_outer + (rb[ispec.name], in_w[ispec.name]),
             functools.partial(_in_index, ispec)))
@@ -735,33 +603,25 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
 
     scratch_shapes = [
         pltpu.VMEM((geo.height[w.name],
-                    _pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
+                    pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
         for w in roll_wins
     ] + [
         pltpu.VMEM((i.p_stages, geo.height[f"in_{i.name}"],
-                    _pad_to_lane(in_w[i.name])), dtype)
+                    pad_to_lane(in_w[i.name])), dtype)
         for i in plane_ins
     ] + [
         pltpu.VMEM((w.p_stages, geo.height[w.name],
-                    _pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
+                    pad_to_lane(ni + (w.i_hi - w.i_lo))), dtype)
         for w in plane_wins
     ] + [
-        pltpu.VMEM((1, _pad_to_lane(ni + a.w_off)), dtype)
+        pltpu.VMEM((1, pad_to_lane(ni + a.w_off)), dtype)
         for a in call.accs
     ] + [
         pltpu.VMEM((-(-(seats[oi].pad + R) // geo.tile) * geo.tile,
-                    _pad_to_lane(ni)), dtype)
+                    pad_to_lane(ni)), dtype)
         for oi in carried
     ]
-    if double_buffer and arr_ins:
-        scratch_shapes += [
-            pltpu.VMEM((2,) + (1,) * i.n_outer + (rb[i.name], dma_w[i.name]),
-                       dtype)
-            for i in arr_ins
-        ]
-        scratch_shapes.append(pltpu.SemaphoreType.DMA((len(arr_ins), 2)))
-        scratch_shapes.append(pltpu.SMEM((len(arr_ins),), jnp.int32))
-    need = call_vmem(call, nj, ni, itemsize, double_buffer, rows=R)["total"]
+    need = tiling.vmem_need
     if need > VMEM_CAPACITY:
         raise PallasUnsupported(
             f"call {call.name} needs {need} B of VMEM at sizes {sizes}; "
@@ -777,7 +637,7 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
         # may be split across cores
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
-            vmem_limit_bytes=scoped_vmem_limit(need)),
+            vmem_limit_bytes=tiling.vmem_limit),
         interpret=interpret,
         name=f"hfav_{call.name}",
     )
@@ -791,26 +651,6 @@ def build_call(call: CallPlan, sizes: tuple[int, ...], dtype,
 # only the Pallas build_call.
 # ---------------------------------------------------------------------------
 
-def execute_plan(kplan: KernelPlan, *, dtype=jnp.float32,
-                 interpret: Optional[bool] = None,
-                 double_buffer: bool = False):
-    """Build the host callable executing a full :class:`KernelPlan` on
-    the Pallas stencil interpreter.
-
-    A thin wrapper over the shared host half
-    (:func:`repro.core.interpreters.execute_plan` with
-    ``interpreter="pallas"``): the returned function takes the
-    program's external arrays as keyword arguments and returns
-    ``{store name: array}`` for every goal.  ``interpret`` defaults to
-    interpret mode exactly where the default backend is not a TPU
-    (:func:`repro.core.interpreters.resolve_interpret`);
-    ``double_buffer=True`` selects the explicit two-slot async-DMA input
-    pipeline."""
-    from ...core.interpreters import execute_plan as _execute_plan
-    return _execute_plan(kplan, interpreter="pallas", dtype=dtype,
-                         interpret=interpret, double_buffer=double_buffer)
-
-
 register_interpreter(InterpreterSpec(
     name="pallas",
     build_call=build_call,
@@ -820,7 +660,7 @@ register_interpreter(InterpreterSpec(
     # carried-vector slots, padded windows, lane-blocked accumulators
     capabilities=PLAN_FEATURES - frozenset({
         "strided_reads", "vec_loads", "align_pad", "lane_block"}),
-    flags=frozenset({"interpret", "double_buffer"}),
+    flags=frozenset({"interpret"}),
     description="Pallas TPU stencil interpreter (VMEM windows, "
-                "BlockSpec or double-buffered DMA row streaming)",
+                "BlockSpec row streaming)",
 ))

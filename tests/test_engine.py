@@ -336,7 +336,7 @@ def test_plan_cache_isolated_per_interpreter():
     the plan-level cache: the key carries the interpreter name, so each
     gets its own executor artifact tagged with its own name."""
     from repro.core import clear_compile_cache, compile_program
-    from repro.core import engine
+    from repro.core import engine, interpreters
     from repro.core.engine import plan_cache_size
 
     prog = _plan_cache_prog(2.0, "iso_interp")
@@ -353,13 +353,14 @@ def test_plan_cache_isolated_per_interpreter():
         assert compile_program(prog, backend="pallas") is gp
         assert compile_program(prog, backend="interp_jax") is gj
         # flags an interpreter does not honor are normalized out of its
-        # key: a pure-JAX compile with double_buffer=True is the same
-        # cache entry, while pallas (which honors the flag) is not
+        # key: a pure-JAX compile with the other interpret value is the
+        # same cache entry, while pallas (which honors the flag) is not
+        other = not interpreters.resolve_interpret(None)
         engine._CACHE.clear()
         assert compile_program(prog, backend="interp_jax",
-                               double_buffer=True) is gj
+                               interpret=other) is gj
         assert compile_program(prog, backend="pallas",
-                               double_buffer=True) is not gp
+                               interpret=other) is not gp
     finally:
         clear_compile_cache()
 
@@ -397,7 +398,7 @@ def test_plan_cache_lru_evicts_across_interpreters():
     yields a fresh artifact."""
     from repro.core import (clear_compile_cache, compile_program,
                             set_plan_cache_cap)
-    from repro.core import engine
+    from repro.core import engine, interpreters
 
     prog = _plan_cache_prog(3.0, "lru_interp")
     clear_compile_cache()
@@ -407,7 +408,8 @@ def test_plan_cache_lru_evicts_across_interpreters():
         gj = compile_program(prog, backend="interp_jax")
         # pallas is now the LRU victim: one more distinct entry (a new
         # pallas flag combination) evicts it
-        compile_program(prog, backend="pallas", double_buffer=True)
+        compile_program(prog, backend="pallas",
+                        interpret=not interpreters.resolve_interpret(None))
         engine._CACHE.clear()
         assert compile_program(prog, backend="interp_jax") is gj
         assert compile_program(prog, backend="pallas") is not gp
@@ -446,8 +448,8 @@ def test_explain_matches_compile_program_routing():
         register_pallas_split_win(prog.name)
         # both the report and the compilation flip together, for every
         # flag combination
-        assert "auto backend: pallas" in explain(prog, double_buffer=True)
-        gen = compile_program(prog, backend="auto", double_buffer=True)
+        assert "auto backend: pallas" in explain(prog, interpret=False)
+        gen = compile_program(prog, backend="auto", interpret=False)
         assert isinstance(gen, PallasGenerated)
     finally:
         PALLAS_SPLIT_WINS.discard(prog.name)

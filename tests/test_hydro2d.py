@@ -17,10 +17,10 @@ from bench.generators.sweep_state import physical_state
 from bench.spec import Bench
 from repro import trace
 from repro.core import compile_program
-from repro.core.plancheck import (DEFAULT_VMEM_BUDGET, LANE, body_values,
-                                  call_vmem, check_plan, has_errors, row_tile,
-                                  scoped_vmem_limit)
+from repro.core.plancheck import check_plan, has_errors
 from repro.core.programs import hydro2d_program
+from repro.core.tiling import (DEFAULT_VMEM_BUDGET, LANE, body_values,
+                               call_vmem, row_tile, scoped_vmem_limit)
 from repro.core.unfused import build_unfused
 
 #: The benchmark configuration's state parameters (bench/configs/hydro2d_8k.json).
@@ -150,14 +150,14 @@ def test_call_vmem_counts_the_body(call, n, rows, limit):
     """``call_vmem``'s body term is the body values at R rows by the
     padded lanes; with it the row tile and the scoped limit follow."""
     lanes = -(-n // LANE) * LANE
-    R = row_tile(call, n, n, 4, False)
+    R = row_tile(call, n, n, 4)
     assert R == rows
-    report = call_vmem(call, n, n, 4, False, rows=R)
+    report = call_vmem(call, n, n, 4, rows=R)
     assert report["body"] == 25 * R * lanes * 4
     assert scoped_vmem_limit(report["total"]) == limit
     without = report["total"] - report["body"]
     if limit is None:  # the body term is what keeps R from the next tile
-        bigger = call_vmem(call, n, n, 4, False, rows=R + 8)
+        bigger = call_vmem(call, n, n, 4, rows=R + 8)
         assert bigger["total"] + bigger["total"] // 4 > DEFAULT_VMEM_BUDGET
         assert without + without // 4 <= DEFAULT_VMEM_BUDGET
 
@@ -172,7 +172,7 @@ def test_vmem_counters_per_traced_call(fused):
                   for k in ("rho", "mu", "mv", "en")}
         with trace.recording() as rec:
             jax.eval_shape(lambda a: fused.fn(**a), shapes)
-        need = call_vmem(c, n, n, 4, False)["total"]
+        need = call_vmem(c, n, n, 4)["total"]
         assert rec.counters["hfav.vmem_need_bytes"] == need
         assert rec.counters["hfav.vmem_limit_bytes"] == limit
         (build,) = rec.named("hfav.build_call")

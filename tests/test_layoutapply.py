@@ -3,7 +3,7 @@ VecScan's layout hints, and its engine/interpreter wiring.
 
 Covers, in order: the corpus-wide conformance sweep (auto-mode
 transformed plans execute bit-identically to the untransformed plan on
-every layout-aware interpreter, in both streaming modes, and are
+every layout-aware interpreter, at two row counts, and are
 *refused* by capability-gated interpreters), force mode with
 tolerances, one unit test per handled hint kind on hand-built plans
 (including the strided-reads-become-executable DLT path), the
@@ -17,7 +17,7 @@ import pathlib
 import numpy as np
 import pytest
 
-from _interp_utils import arrays_for, sizes_for
+from _interp_utils import DIM, arrays_for, sizes_for
 from repro.core import (KernelPlan, apply_layout, clear_compile_cache,
                         compile_cache_size, compile_program, explain)
 from repro.core.interpreters import (PlanUnsupported, _lane_permute,
@@ -28,8 +28,9 @@ from repro.core.layoutapply import (APPLY_LAYOUT_ENV, EXACT_HINTS,
 from repro.core.plan import (AxiomPlan, CallPlan, GridDim, InputPlan,
                              LanePass, LayoutHint, OutputPlan, ReadPlan,
                              StepPlan)
-from repro.core.plancheck import LANE, check_plan, has_errors
+from repro.core.plancheck import check_plan, has_errors
 from repro.core.programs import ALL_PROGRAMS
+from repro.core.tiling import LANE
 
 GOLDEN_DIR = pathlib.Path(__file__).resolve().parent / "goldens" / "plans"
 GOLDENS = sorted(p.stem for p in GOLDEN_DIR.glob("*.json"))
@@ -48,39 +49,39 @@ def _golden(name: str) -> KernelPlan:
         json.loads((GOLDEN_DIR / f"{name}.json").read_text()))
 
 
-def _run(kplan, interp, rng_seed=7, **flags):
-    arrs = arrays_for(kplan, np.random.default_rng(rng_seed))
+def _run(kplan, interp, rng_seed=7, dims=DIM):
+    arrs = arrays_for(kplan, np.random.default_rng(rng_seed), dims)
     return {k: np.asarray(v) for k, v in
-            execute_plan(kplan, interpreter=interp, **flags)(**arrs).items()}
+            execute_plan(kplan, interpreter=interp)(**arrs).items()}
 
 
 # ---------------------------------------------------------------------------
 # The conformance sweep: transformed == untransformed, whole corpus,
-# every registered interpreter, both streaming modes
+# every registered interpreter, rows below one tile and a ragged R-row tile
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("double_buffer", [False, True])
+@pytest.mark.parametrize("nj", [DIM["j"], 37])
 @pytest.mark.parametrize("name", GOLDENS)
 @pytest.mark.parametrize("interp", INTERPRETERS)
-def test_auto_transform_conformance(interp, name, double_buffer):
+def test_auto_transform_conformance(interp, name, nj):
     """Auto mode is bit-exact: on every golden whose hints apply, a
     layout-aware interpreter must produce bit-identical outputs for
     the transformed and untransformed plan; an interpreter without the
     new capabilities must refuse the transformed plan with the typed
     PlanUnsupported rather than miscompile."""
     kplan = _golden(name)
-    res = apply_layout(kplan, mode="auto", sizes=sizes_for(kplan))
+    dims = dict(DIM, j=nj)
+    res = apply_layout(kplan, mode="auto", sizes=sizes_for(kplan, dims))
     if not res.applied:
         pytest.skip("no exact hint applies to this plan")
     assert res.plan.cache_key() != kplan.cache_key()
     assert not has_errors(check_plan(res.plan))
     if not res.plan.features() <= get_interpreter(interp).capabilities:
         with pytest.raises(PlanUnsupported):
-            execute_plan(res.plan, interpreter=interp,
-                         double_buffer=double_buffer)
+            execute_plan(res.plan, interpreter=interp)
         return
-    want = _run(kplan, interp, double_buffer=double_buffer)
-    got = _run(res.plan, interp, double_buffer=double_buffer)
+    want = _run(kplan, interp, dims=dims)
+    got = _run(res.plan, interp, dims=dims)
     assert set(got) == set(want)
     for k in want:
         assert np.array_equal(got[k], want[k]), (name, k)

@@ -18,7 +18,7 @@ unfused oracle in interpret mode:
   strict leading subset of the outer dims (``(l, k, j, i) -> out[l]``) —
   on both backends;
 * cross-row (j-offset) reads of same-nest materialized variables;
-* double-buffered input DMA in the executor hot loop.
+* each of these with R rows a grid step and with one (R capped at 1).
 
 Plus regression tests pinning the *remaining* restrictions to the
 improved ``PallasUnsupported`` messages (the table in docs/BACKENDS.md)
@@ -31,12 +31,12 @@ import pytest
 
 from repro.core import (Generated, PallasGenerated, PallasUnsupported,
                         Program, axiom, clear_compile_cache, compile_program,
-                        goal, kernel, register_pallas_split_win)
+                        goal, kernel, register_pallas_split_win, tiling)
 from repro.core.engine import PALLAS_SPLIT_WINS
 from repro.core.programs import (advect4d_halo_program, cosmo_program,
                                  energy3d_program, heat3d_program,
                                  heat3d_residual_norm_program,
-                                 heat3d_stage_program, laplace5_program,
+                                 heat3d_stage_program,
                                  plane_sum_program, pyramid4d_program,
                                  row_sum_program, smooth_norm_program,
                                  subset_sum_program)
@@ -48,6 +48,15 @@ def _fresh_cache():
     clear_compile_cache()
     yield
     clear_compile_cache()
+
+
+@pytest.fixture(params=["rows", "one_row"])
+def streaming(request, monkeypatch):
+    """R rows a grid step (``rows``), or one (``one_row``: R capped at
+    1, the path that accumulator calls take)."""
+    if request.param == "one_row":
+        monkeypatch.setattr(tiling, "ROW_TILE_CAP", 1)
+    return request.param
 
 
 def _u(rng, shape):
@@ -72,13 +81,10 @@ LIFTED = [
 
 @pytest.mark.parametrize("build,out,shape,_why", LIFTED,
                          ids=[c[3] for c in LIFTED])
-@pytest.mark.parametrize("double_buffer", [False, True],
-                         ids=["blockspec", "double_buffer"])
 def test_lifted_restriction_matches_oracle(rng, build, out, shape, _why,
-                                           double_buffer):
+                                           streaming):
     prog = build()
-    gen = compile_program(prog, backend="pallas",
-                          double_buffer=double_buffer)
+    gen = compile_program(prog, backend="pallas")
     assert isinstance(gen, PallasGenerated)
     u = _u(rng, shape)
     got = gen.fn(u=u)[out]
@@ -113,14 +119,11 @@ def _broadcast_coeff_program():
     )
 
 
-@pytest.mark.parametrize("double_buffer", [False, True],
-                         ids=["blockspec", "double_buffer"])
-def test_broadcast_suffix_input_matches_oracle(rng, double_buffer):
+def test_broadcast_suffix_input_matches_oracle(rng, streaming):
     """Streamed inputs over a dim *suffix* broadcast across the leading
-    outer grid dims in both streaming modes."""
+    outer grid dims, R rows or one row a grid step."""
     prog = _broadcast_coeff_program()
-    gen = compile_program(prog, backend="pallas",
-                          double_buffer=double_buffer)
+    gen = compile_program(prog, backend="pallas")
     (ispec_u, ispec_c) = [i for i in gen.call.inputs if not i.scalar]
     assert {ispec_u.name: ispec_u.n_outer,
             ispec_c.name: ispec_c.n_outer} == {"u": 1, "c": 0}
@@ -263,9 +266,10 @@ def test_kept_dim_reductions_on_jax_backend(rng, build, out, shape):
                                atol=2e-4, rtol=1e-4)
 
 
-def test_row_kept_reduction_with_outer_dims(rng):
+def test_row_kept_reduction_with_outer_dims(rng, monkeypatch):
     """A (k, j)-keeping i-reduction on a 3-D grid: acc_rows output with
-    outer trimming, in both streaming modes and on the JAX backend."""
+    outer trimming, R rows or one row a grid step, and on the JAX
+    backend."""
     k_sum = kernel("psum", [("x", "u[k?][j?][i]")],
                    [("acc", "psum(u[k?][j?])")],
                    fn=lambda acc, x: acc + x, kind="reduce", init=0.0)
@@ -279,8 +283,9 @@ def test_row_kept_reduction_with_outer_dims(rng):
     )
     u = _u(rng, (3, 6, 17))
     want = build_unfused(prog).fn(u=u)["psum"]
-    for dbuf in (False, True):
-        gen = compile_program(prog, backend="pallas", double_buffer=dbuf)
+    gen = compile_program(prog, backend="pallas")
+    for cap in (tiling.ROW_TILE_CAP, 1):
+        monkeypatch.setattr(tiling, "ROW_TILE_CAP", cap)
         got = gen.fn(u=u)["psum"]
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-4, rtol=1e-4)
@@ -309,14 +314,12 @@ def _same_nest_koff_program():
     )
 
 
-@pytest.mark.parametrize("double_buffer", [False, True],
-                         ids=["blockspec", "double_buffer"])
-def test_same_nest_plane_offset_lifted(rng, double_buffer):
+def test_same_nest_plane_offset_lifted(rng, streaming):
     """The producer-plane-window lift: a same-nest variable read at a
     backward plane offset compiles on the stencil interpreter (2 planes
     resident, producer lead 0) and matches the oracle."""
     prog = _same_nest_koff_program()
-    gen = compile_program(prog, backend="pallas", double_buffer=double_buffer)
+    gen = compile_program(prog, backend="pallas")
     assert isinstance(gen, PallasGenerated)
     (w,) = gen.call.windows
     assert w.plane and (w.p_stages, w.p_lead) == (2, 0)
@@ -363,14 +366,12 @@ def _cross_call_halo_program():
     )
 
 
-@pytest.mark.parametrize("double_buffer", [False, True],
-                         ids=["blockspec", "double_buffer"])
-def test_cross_call_materialized_plane_window(rng, double_buffer):
+def test_cross_call_materialized_plane_window(rng, streaming):
     """Plane windows also serve cross-call *materialized* inputs: fx is
     produced by nest 0 (which the reduction splits off), then streamed
     into nest 1 with a 3-plane window and one k warm-up tile."""
     prog = _cross_call_halo_program()
-    gen = compile_program(prog, backend="pallas", double_buffer=double_buffer)
+    gen = compile_program(prog, backend="pallas")
     assert len(gen.calls) == 2
     (fx_in,) = [i for i in gen.calls[1].inputs if not i.scalar]
     assert fx_in.name == "fx_u" and (fx_in.p_stages, fx_in.p_lead) == (3, 1)
@@ -403,15 +404,13 @@ def _narrowed_axiom_program():
     )
 
 
-@pytest.mark.parametrize("double_buffer", [False, True],
-                         ids=["blockspec", "double_buffer"])
-def test_narrowed_axiom_stream_origin(rng, double_buffer):
+def test_narrowed_axiom_stream_origin(rng, streaming):
     """Regression: the planner used to size the fetched window from the
     axiom extents but the grid range from the variable extent — a
     narrowed axiom row extent misaligned the stream.  Both now come
     from the same frame."""
     prog = _narrowed_axiom_program()
-    gen = compile_program(prog, backend="pallas", double_buffer=double_buffer)
+    gen = compile_program(prog, backend="pallas")
     (ispec,) = gen.call.inputs
     assert (ispec.j_lo, ispec.j_hi) == (1, -1)
     # grid start = array origin minus the streaming lead: rows stream
@@ -446,14 +445,6 @@ def test_auto_split_schedule_routing():
     # the default program name would reroute every anonymous program
     with pytest.raises(ValueError, match="default program name"):
         register_pallas_split_win("program")
-
-
-def test_double_buffer_distinct_cache_entry():
-    prog = laplace5_program()
-    g1 = compile_program(prog, backend="pallas")
-    g2 = compile_program(prog, backend="pallas", double_buffer=True)
-    assert g1 is not g2
-    assert compile_program(prog, backend="pallas", double_buffer=True) is g2
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,7 @@ from repro.core.engine import plan_cache_size
 from repro.core.fusion import fuse_inest_dag
 from repro.core.infer import infer
 from repro.core.interpreters import execute_plan as registry_execute_plan
-from repro.core.interpreters import registered_interpreters
+from repro.core.interpreters import registered_interpreters, resolve_interpret
 from repro.core.plan import (CallPlan, GridDim, InputPlan, OutputPlan,
                              PallasUnsupported, ReadPlan, StepPlan)
 from repro.core.programs import (ALL_PROGRAMS, heat3d_program,
@@ -187,7 +187,7 @@ def test_plan_inequality_distinct_cache_entries():
     assert plan_cache_size() == 3
     # different execution flags: distinct entry for the same plan
     compile_program(laplace5_program(), backend="pallas",
-                    double_buffer=True)
+                    interpret=not resolve_interpret(None))
     assert plan_cache_size() == 4
 
 

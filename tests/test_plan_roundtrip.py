@@ -70,13 +70,16 @@ if HAVE_HYPOTHESIS:
 
 
 @pytest.mark.parametrize("seed", range(6))
-@pytest.mark.parametrize("double_buffer", [False, True])
-def test_roundtrip_executor_output_identical(seed, double_buffer):
+@pytest.mark.parametrize("cap", [None, 1], ids=["rows", "one_row"])
+def test_roundtrip_executor_output_identical(seed, cap, monkeypatch):
     """The deserialized plan *executes* bit-identically to the original
-    (same re-linked callables, same IR, same interpreter), in both
-    streaming modes."""
-    from repro.kernels.stencil2d.kernel import execute_plan
+    (same re-linked callables, same IR, same interpreter), with R rows
+    a grid step and with one (R capped at 1)."""
+    from repro.core import tiling
+    from repro.core.interpreters import execute_plan
 
+    if cap is not None:
+        monkeypatch.setattr(tiling, "ROW_TILE_CAP", cap)
     desc = random_chain(seed)
     name = f"rtx_{seed}"
     prog = build_chain_program(desc, name=name, register=True)
@@ -85,10 +88,8 @@ def test_roundtrip_executor_output_identical(seed, double_buffer):
         kplan2 = _roundtrip(kplan)
         rng = np.random.default_rng(seed)
         u = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
-        got1 = execute_plan(kplan, interpret=True,
-                            double_buffer=double_buffer)(u=u)["out"]
-        got2 = execute_plan(kplan2, interpret=True,
-                            double_buffer=double_buffer)(u=u)["out"]
+        got1 = execute_plan(kplan, interpret=True)(u=u)["out"]
+        got2 = execute_plan(kplan2, interpret=True)(u=u)["out"]
         np.testing.assert_array_equal(np.asarray(got1), np.asarray(got2))
     finally:
         unregister_chain(name)

@@ -20,9 +20,9 @@ from repro.core import (KernelPlan, PlanCache, PlanCheckError,
                         sizes_from_arrays, vmem_bytes, vmem_report)
 from repro.core.codegen_jax import Generated
 from repro.core.engine import _emit_plan
-from repro.core.plancheck import (DEFAULT_VMEM_BUDGET, Diagnostic,
-                                  call_vmem, resolve_check_mode, vmem_budget)
+from repro.core.plancheck import Diagnostic, resolve_check_mode, vmem_budget
 from repro.core.programs import ALL_PROGRAMS, heat3d_program
+from repro.core.tiling import DEFAULT_VMEM_BUDGET
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 GOLDEN_DIR = ROOT / "tests" / "goldens" / "plans"
@@ -195,22 +195,6 @@ def test_vmem_bytes_mirrors_scratch_shapes():
     assert rep["heat3d_n0"]["total"] == 294912
 
 
-def test_vmem_bytes_double_buffer_adds_staging():
-    """The explicit two-slot DMA staging takes the place of the
-    pipeline's two input blocks, at the same size, for the one-row grid
-    steps that ``double_buffer`` keeps (R-row steps stream R-row
-    blocks instead)."""
-    kp = load_golden("cosmo")
-    sizes = sizes_from_arrays(kp, {"u": (4, 12, 100)})
-    plain = vmem_report(kp, sizes)["cosmo_n0"]
-    dbuf = vmem_report(kp, sizes, double_buffer=True)["cosmo_n0"]
-    one_row = call_vmem(kp.calls[0], 12, 100, 4, False, rows=1)
-    assert dbuf["dma_u"] == one_row["blk_u"] == 2 * 8 * 128 * 4
-    assert plain["blk_u"] == 2 * 16 * 128 * 4
-    assert "blk_u" not in dbuf and "dma_u" not in plain
-    assert dbuf["total"] == one_row["total"]
-
-
 def test_vmem_budget_resolution(monkeypatch):
     assert vmem_budget(None) == DEFAULT_VMEM_BUDGET
     assert vmem_budget(4096) == 4096
@@ -248,7 +232,7 @@ def test_emit_plan_error_mode_rejects_hazard():
     with pytest.raises(PlanCheckError) as ei:
         _emit_plan(_hazard_plan(), None, interpreter="pallas",
                    dtype=jnp.float32, interpret=True,
-                   double_buffer=False, use_cache=False, check="error")
+                   use_cache=False, check="error")
     assert any(d.code == "PC005" for d in ei.value.diagnostics)
 
 
@@ -260,7 +244,7 @@ def test_emit_plan_warn_mode_warns_then_off_is_silent():
         try:
             _emit_plan(_hazard_plan(), None, interpreter="pallas",
                        dtype=jnp.float32,
-                       interpret=True, double_buffer=False,
+                       interpret=True,
                        use_cache=False, check="warn")
         except ValueError:
             pass
@@ -270,7 +254,7 @@ def test_emit_plan_warn_mode_warns_then_off_is_silent():
         try:
             _emit_plan(_hazard_plan(), None, interpreter="pallas",
                        dtype=jnp.float32,
-                       interpret=True, double_buffer=False,
+                       interpret=True,
                        use_cache=False, check="off")
         except ValueError:
             pass

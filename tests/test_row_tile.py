@@ -1,14 +1,16 @@
 """R-row grid steps of the Pallas stencil interpreter.
 
 Each grid step of ``kernels/stencil2d`` computes a tile of R rows
-(:func:`repro.core.plancheck.row_tile`), R following from the plan, the
+(:func:`repro.core.tiling.row_tile`), R following from the plan, the
 dtype and the shape.  Every output element is made by the same
 arithmetic as with one row a step, so the interpreter's outputs equal
 the ``jax`` backend's bit for bit, with rows ragged against R.  Calls
-with accumulators, and ``double_buffer=True``, keep one row a step.
-The VMEM model (:func:`repro.core.plancheck.call_vmem`) and the
-``hfav.grid_steps``/``hfav.row_tile`` counters are checked against the
-grid, blocks and scratch that ``build_call`` builds.
+with accumulators keep one row a step, and a cap of 1 on R
+(``tiling.ROW_TILE_CAP``) puts every call on the one-row path.  The
+tiling (:func:`repro.core.tiling.call_tiling`: the VMEM model and the
+seated grid) and the ``hfav.grid_steps``/``hfav.row_tile`` counters are
+checked against the grid, blocks and scratch that ``build_call``
+builds, for every grid call of every program.
 """
 import dataclasses
 import math
@@ -20,18 +22,19 @@ import pytest
 
 from _interp_utils import DIM, arrays_for
 from repro import trace
-from repro.core import compile_program
-from repro.core import plancheck
+from repro.core import compile_program, tiling
 from repro.core.interpreters import execute_plan
 from repro.core.plan import GridDim
-from repro.core.plancheck import (LANE, body_values, call_vmem, row_geometry,
-                                  row_tile, scoped_vmem_limit, seat_geometry)
 from repro.core.programs import ALL_PROGRAMS
+from repro.core.tiling import (LANE, body_values, call_tiling, call_vmem,
+                               row_geometry, row_tile, scoped_vmem_limit,
+                               seat_geometry)
 from repro.kernels.stencil2d import build_call
 
 #: (rows Nj, cap of R): rows below one tile; one ragged 40-row tile;
-#: three 16-row steps, the last ragged.
-ROWS = [(7, None), (37, None), (37, 16)]
+#: three 16-row steps, the last ragged; one row a step, at both row
+#: counts.
+ROWS = [(7, None), (37, None), (37, 16), (7, 1), (37, 1)]
 
 #: The programs compared bit for bit below.  hydro2d's step bodies are
 #: long enough that XLA's CPU compiler fuses a multiply and an add into
@@ -48,34 +51,31 @@ def _bits(got: dict, want: dict, tag: str) -> None:
                                       err_msg=f"{tag}:{k}")
 
 
-@pytest.mark.parametrize("double_buffer", [False, True])
 @pytest.mark.parametrize("nj,cap", ROWS, ids=[f"nj{n}-cap{c}" for n, c in ROWS])
 @pytest.mark.parametrize("name", BIT_EXACT)
-def test_row_tiled_pallas_matches_jax_bit_for_bit(name, nj, cap, double_buffer,
-                                                  monkeypatch):
-    """R-row steps give the ``jax`` backend's outputs bit for bit;
-    calls with accumulators and the double-buffered path run one row a
-    step (``hfav.row_tile``), as before R-row steps existed."""
+def test_row_tiled_pallas_matches_jax_bit_for_bit(name, nj, cap, monkeypatch):
+    """R-row steps give the ``jax`` backend's outputs bit for bit, and
+    so does the one-row path (calls with accumulators, or a cap of 1),
+    which runs one row a step (``hfav.row_tile``)."""
     if cap is not None:
-        monkeypatch.setattr(plancheck, "ROW_TILE_CAP", cap)
+        monkeypatch.setattr(tiling, "ROW_TILE_CAP", cap)
     prog = ALL_PROGRAMS[name]()
-    gen = compile_program(prog, backend="pallas", double_buffer=double_buffer,
-                          use_cache=False)
+    gen = compile_program(prog, backend="pallas", use_cache=False)
     arrs = arrays_for(gen.kernel_plan, np.random.default_rng(11), dict(DIM, j=nj))
     with trace.recording() as rec:
         got = gen.fn(**arrs)
     _bits(got, compile_program(prog, backend="jax").fn(**arrs),
-          f"{name}/nj={nj}/cap={cap}/db={double_buffer}")
+          f"{name}/nj={nj}/cap={cap}")
     tiles = {s.attrs["call"]: s.attrs["row_tile"]
              for s in rec.named("hfav.build_call")}
     grid_calls = [c for c in gen.kernel_plan.calls if c.has_grid]
     assert set(tiles) == {c.name for c in grid_calls}
     assert rec.counters["hfav.row_tile"] == sum(tiles.values())
     for c in grid_calls:
-        if c.accs or double_buffer:
+        if c.accs or cap == 1:
             assert tiles[c.name] == 1, c.name
         else:
-            assert tiles[c.name] == min(cap or plancheck.ROW_TILE_CAP,
+            assert tiles[c.name] == min(cap or tiling.ROW_TILE_CAP,
                                         -(-nj // 8) * 8), c.name
 
 
@@ -94,7 +94,7 @@ def test_row_tile_absorbs_an_input_start_off_the_tile(name, shift):
         GridDim(row.dim, row.lo - shift, row.hi_off),))
     arrs = arrays_for(kplan, np.random.default_rng(5), dict(DIM, j=37))
     nj = 37
-    R = row_tile(early, nj, DIM["i"], 4, False)
+    R = row_tile(early, nj, DIM["i"], 4)
     (ispec,) = early.inputs
     assert (early.x_lo + ispec.lead - ispec.j_lo) % R == R - shift
     assert row_geometry(early, nj, R, 4).first_row[ispec.name] == 0
@@ -107,11 +107,10 @@ def test_row_tile_absorbs_an_input_start_off_the_tile(name, shift):
 # The VMEM model and the counters against what build_call builds
 # ---------------------------------------------------------------------------
 
-def _built(call, sizes, double_buffer=False):
+def _built(call, sizes):
     """The grid, the stream blocks and the scratch of one built call, as
     the traced ``pallas_call`` holds them."""
-    fn, _ = build_call(call, sizes, jnp.float32, interpret=True,
-                       double_buffer=double_buffer)
+    fn, _ = build_call(call, sizes, jnp.float32, interpret=True)
     *outer, nj, ni = sizes
     args = []
     for i in call.inputs:
@@ -139,77 +138,99 @@ def _bytes(shape) -> int:
     return math.prod(lead) * -(-rows // 8) * 8 * -(-lanes // LANE) * LANE * 4
 
 
-#: (program, sizes, double_buffer): rolling input and producer windows,
-#: a plane input, a producer plane window, an accumulator, the DMA path
-MIRROR = [("cosmo", (4, 37, 130), False), ("heat3d", (5, 21, 130), False),
-          ("heat3d_stage", (5, 21, 130), False), ("pyramid4d", (2, 3, 19, 140), False),
-          ("energy3d", (4, 21, 130), False), ("laplace5", (37, 130), True)]
+def _grid_calls(kplan, dims):
+    """Every grid call of ``kplan`` with its ``(*outer, Nj, Ni)`` at the
+    loop dims ``dims``."""
+    sym = dict(kplan.dim_sizes)
+    size = {sym[d]: n for d, n in dims.items() if d in sym}
+    return [(c, tuple(size[sym[g.dim]] for g in c.grid) + (size[sym[c.vec_dim]],))
+            for c in kplan.calls if c.has_grid]
 
 
-@pytest.mark.parametrize("name,sizes,double_buffer", MIRROR,
-                         ids=[f"{n}-db{int(d)}" for n, _, d in MIRROR])
-def test_call_vmem_mirrors_built_blocks_and_scratch(name, sizes, double_buffer):
-    """``call_vmem`` counts what ``build_call`` allocates: two buffers
-    of every stream block (R rows, or the 8-row groups of one-row
-    steps) and every VMEM scratch buffer (windows of ``R + stages - 1``
-    rows, plane windows with their margins, accumulators, DMA slots),
-    plus the step bodies' values of R rows (8 for one-row steps) by
-    ``Ni`` lanes."""
-    (call,) = [c for c in compile_program(ALL_PROGRAMS[name](), backend="pallas")
-               .kernel_plan.calls if c.has_grid]
-    *outer, nj, ni = sizes
-    R = row_tile(call, nj, ni, 4, double_buffer)
-    grid, blocks, vmem = _built(call, sizes, double_buffer)
-    seats = seat_geometry(call, outer, nj, R, 4)
-    assert grid == (seats.steps,)
-    assert seats.radix[-1] == -(-(nj + call.x_hi_off - call.x_lo) // R)
-    geo = row_geometry(call, nj, R, 4)
-    for w in call.windows:
-        if not w.plane:  # the kept rows, rounded up to the tile for R > 1
-            keep = w.stages - 1
-            assert geo.height[w.name] == R + (keep if R == 1 else -(-keep // 8) * 8)
-    want = sum(2 * _bytes(b) for b in blocks) + sum(_bytes(s) for s in vmem)
-    report = call_vmem(call, nj, ni, 4, double_buffer)
-    assert report["body"] == _bytes((body_values(call) * max(R, 8), ni))
-    assert report["total"] == want + report["body"]
+#: (program, Nj, cap of R): every program at rows below one tile and at
+#: a ragged 40-row tile, and the one-row path
+MIRROR = [(n, nj, None) for n in sorted(ALL_PROGRAMS) for nj in (7, 37)] \
+    + [("laplace5", 37, 1)]
+
+
+@pytest.mark.parametrize("name,nj,cap", MIRROR,
+                         ids=[f"{n}-nj{j}-cap{c}" for n, j, c in MIRROR])
+def test_call_vmem_mirrors_built_blocks_and_scratch(name, nj, cap, monkeypatch):
+    """For every grid call, ``call_tiling`` is what ``build_call``
+    builds: the seated grid, and a VMEM need that counts two buffers of
+    every stream block (R rows, or the 8-row groups of one-row steps)
+    and every VMEM scratch buffer (windows of ``R + stages - 1`` rows,
+    plane windows with their margins, accumulators, seat carry
+    buffers), plus the step bodies' values of R rows (8 for one-row
+    steps) by ``Ni`` lanes."""
+    if cap is not None:
+        monkeypatch.setattr(tiling, "ROW_TILE_CAP", cap)
+    kplan = compile_program(ALL_PROGRAMS[name](), backend="pallas").kernel_plan
+    for call, sizes in _grid_calls(kplan, dict(DIM, j=nj, i=130)):
+        *outer, nj, ni = sizes
+        t = call_tiling(call, sizes, 4)
+        R = t.rows
+        assert R == (1 if cap == 1 else row_tile(call, nj, ni, 4)), call.name
+        grid, blocks, vmem = _built(call, sizes)
+        assert t.seats == seat_geometry(call, outer, nj, R, 4)
+        assert grid == (t.seats.steps,), call.name
+        assert t.seats.radix[-1] == -(-(nj + call.x_hi_off - call.x_lo) // R)
+        assert t.geometry == row_geometry(call, nj, R, 4)
+        for w in call.windows:
+            if not w.plane:  # the kept rows, rounded up to the tile for R > 1
+                keep = w.stages - 1
+                assert t.geometry.height[w.name] == \
+                    R + (keep if R == 1 else -(-keep // 8) * 8)
+        want = sum(2 * _bytes(b) for b in blocks) + sum(_bytes(s) for s in vmem)
+        report = call_vmem(call, nj, ni, 4)
+        assert report["body"] == _bytes((body_values(call) * max(R, 8), ni))
+        assert report["total"] == want + report["body"] == t.vmem_need, call.name
+        assert t.vmem_limit == scoped_vmem_limit(t.vmem_need)
 
 
 def test_vmem_limit_forces_the_row_tile_down(monkeypatch):
     """Three resident 1024x1024 planes leave too little of the default
     scoped VMEM limit for the largest row tile: R drops to the largest
     one that fits, and the built blocks are R rows."""
-    monkeypatch.setattr(plancheck, "ROW_TILE_CAP", 128)
+    monkeypatch.setattr(tiling, "ROW_TILE_CAP", 128)
     (call,) = compile_program(ALL_PROGRAMS["heat3d"](), backend="pallas").kernel_plan.calls
-    R = row_tile(call, 1024, 1024, 4, False)
+    R = row_tile(call, 1024, 1024, 4)
     assert 8 <= R < 128
-    assert scoped_vmem_limit(call_vmem(call, 1024, 1024, 4, False, rows=R)["total"]) is None
-    assert scoped_vmem_limit(call_vmem(call, 1024, 1024, 4, False, rows=R + 8)["total"])
+    assert scoped_vmem_limit(call_vmem(call, 1024, 1024, 4, rows=R)["total"]) is None
+    assert scoped_vmem_limit(call_vmem(call, 1024, 1024, 4, rows=R + 8)["total"])
     grid, blocks, vmem = _built(call, (4, 1024, 1024))
     # four planes of 1024 // R row steps, and one step after the last
     # that writes the last goal block
     assert grid == (4 * (1024 // R) + 1,)
     assert all(b[-2] == R for b in blocks)
     want = sum(2 * _bytes(b) for b in blocks) + sum(_bytes(s) for s in vmem)
-    report = call_vmem(call, 1024, 1024, 4, False)
+    report = call_vmem(call, 1024, 1024, 4)
     assert report["total"] == want + report["body"]
 
 
-@pytest.mark.parametrize("name", ["cosmo", "heat3d"])
+@pytest.mark.parametrize("name", sorted(ALL_PROGRAMS))
 def test_grid_step_counters_match_the_built_grid(name):
-    """``hfav.grid_steps`` is the built grid's product and
-    ``hfav.row_tile`` the R chosen, for a row-window plan (cosmo) and a
-    plane-window plan (heat3d)."""
+    """For every grid call, ``hfav.grid_steps`` is the built grid's
+    steps and ``hfav.row_tile`` the R chosen, both read from the one
+    ``call_tiling`` that ``build_call`` builds from: row-window plans
+    (cosmo), plane-window plans (heat3d), accumulators and split
+    schedules of several calls."""
     prog = ALL_PROGRAMS[name]()
     gen = compile_program(prog, backend="pallas", use_cache=False)
-    arrs = arrays_for(gen.kernel_plan, np.random.default_rng(0), dict(DIM, j=150))
+    dims = dict(DIM, j=150)
+    arrs = arrays_for(gen.kernel_plan, np.random.default_rng(0), dims)
     with trace.recording() as rec:
         jaxpr = jax.make_jaxpr(lambda a: gen.fn(**a))(arrs)
-    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    grid = eqn.params["grid_mapping"].grid
-    (call,) = gen.kernel_plan.calls
-    R = rec.counters["hfav.row_tile"]
-    assert rec.counters["hfav.grid_steps"] == math.prod(grid)
-    assert R == row_tile(call, 150, DIM["i"], 4, False)
-    seats = seat_geometry(call, (DIM["k"],) * call.n_outer, 150, R, 4)
-    assert grid == (seats.steps,)
-    assert seats.radix[-1] == -(-(150 + call.x_hi_off - call.x_lo) // R)
+    grids = [e.params["grid_mapping"].grid for e in jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    spans = rec.named("hfav.build_call")
+    calls = _grid_calls(gen.kernel_plan, dims)
+    assert len(grids) == len(spans) == len(calls)
+    assert rec.counters["hfav.grid_steps"] == sum(math.prod(g) for g in grids)
+    assert rec.counters["hfav.row_tile"] == sum(s.attrs["row_tile"] for s in spans)
+    for (call, sizes), grid, sp in zip(calls, grids, spans):
+        t = call_tiling(call, sizes, 4)
+        assert sp.attrs["call"] == call.name
+        assert grid == (t.seats.steps,) == (sp.attrs["grid_steps"],)
+        assert sp.attrs["row_tile"] == t.rows
+        assert t.seats.radix[-1] == -(-(sizes[-2] + call.x_hi_off - call.x_lo) // t.rows)

@@ -6,8 +6,8 @@ the fill (0.0) in every row, plane and lane outside the goal, so the host
 half hands it on as it is: the traced program holds no trim (``slice``)
 or re-seat (``pad``/``scatter``) around the ``pallas_call``.  The R-row
 path writes each goal block one grid step late from a VMEM carry buffer;
-the one-row paths (BlockSpec rows and ``double_buffer``) write each row
-at its goal row in a revisited 8-row block.  Rows are ragged against
+the one-row path writes each row at its goal row in a revisited 8-row
+block.  Rows are ragged against
 both tiles, and the outputs equal the ``interp_jax`` interpreter's and
 the plain unfused reference's.
 """
@@ -18,23 +18,20 @@ import pytest
 
 from _interp_utils import arrays_for, sizes_for
 from repro import trace
-from repro.core import compile_program
-from repro.core import plancheck
+from repro.core import compile_program, tiling
 from repro.core.interpreters import execute_plan
-from repro.core.plancheck import row_tile
 from repro.core.programs import ALL_PROGRAMS
+from repro.core.tiling import row_tile
 from repro.core.unfused import build_unfused
 
 #: program -> (outputs the kernel seats, outputs the host trims)
 COUNTS = {"cosmo": (1, 0), "heat3d": (1, 0), "hydro2d": (4, 0),
           "heat3d_residual_norm": (1, 1)}
 
-#: mode -> (cap of R, Nj, double_buffer): R-row steps over 37 rows (two
-#: 16-row steps and a ragged 5-row one), and one row a step over 21 rows
-#: (two 8-row blocks and a ragged 5-row one), through BlockSpecs or the
-#: explicit DMA pipeline
-MODES = {"rows": (16, 37, False), "one_row": (1, 21, False),
-         "double_buffer": (None, 21, True)}
+#: mode -> (cap of R, Nj): R-row steps over 37 rows (two 16-row steps
+#: and a ragged 5-row one), and one row a step over 21 rows (two 8-row
+#: blocks and a ragged 5-row one)
+MODES = {"rows": (16, 37), "one_row": (1, 21)}
 
 CASES = [pytest.param(name, mode, id=f"{name}-{mode}")
          for name in ("cosmo", "heat3d", "hydro2d") for mode in MODES] + [
@@ -61,12 +58,10 @@ def _goal_mask(call, oi, shape):
 
 @pytest.mark.parametrize("name,mode", CASES)
 def test_external_outputs_are_seated_by_the_kernel(monkeypatch, name, mode):
-    cap, nj, double_buffer = MODES[mode]
-    if cap is not None:
-        monkeypatch.setattr(plancheck, "ROW_TILE_CAP", cap)
+    cap, nj = MODES[mode]
+    monkeypatch.setattr(tiling, "ROW_TILE_CAP", cap)
     prog = ALL_PROGRAMS[name]()
-    gen = compile_program(prog, backend="pallas", double_buffer=double_buffer,
-                          use_cache=False)
+    gen = compile_program(prog, backend="pallas", use_cache=False)
     kp = gen.kernel_plan
     (call,) = [c for c in kp.calls if c.has_grid]
     dims = {"i": 20, "j": nj, "k": 5}
@@ -75,7 +70,7 @@ def test_external_outputs_are_seated_by_the_kernel(monkeypatch, name, mode):
         arrs = {k: jnp.abs(v) + 0.5 for k, v in arrs.items()}
     sizes = sizes_for(kp, dims)
     ni = sizes["Ni"]
-    R = row_tile(call, nj, ni, 4, double_buffer)
+    R = row_tile(call, nj, ni, 4)
     assert (R > 1) == (mode == "rows" and not call.accs)
 
     with trace.recording() as rec:

@@ -19,28 +19,28 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import compile_batched, compile_program
+from repro.core import compile_batched, compile_program, tiling
 from repro.core.programs import ALL_PROGRAMS
 
-#: (program, {size symbol: int}, double_buffer): the main path's
-#: programs at Ni=1024, each streaming mode on a 2-D and a 3-D grid
+#: (program, {size symbol: int}, cap of R): the main path's programs at
+#: Ni=1024, R rows and one row a grid step, on a 2-D and a 3-D grid
 CASES = [
-    pytest.param(name, sizes, db, id=f"{name}-db{int(db)}")
-    for name, sizes, db in [
-        ("laplace5", {"Nj": 1024, "Ni": 1024}, False),
-        ("cosmo", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
-        ("heat3d", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
-        ("normalization", {"Nj": 1024, "Ni": 1024}, False),
-        ("hydro1d", {"Nj": 1024, "Ni": 1024}, False),
-        ("plane_sum", {"Nk": 8, "Nj": 256, "Ni": 1024}, False),
-        # rows off the tile: the last DMA group reaches into its padding
-        ("laplace5", {"Nj": 1020, "Ni": 1024}, True),
-        ("heat3d", {"Nk": 8, "Nj": 254, "Ni": 1024}, True),
-        # the second nest streams a 1023-wide intermediate: whole lane tiles
-        ("normalization", {"Nj": 1020, "Ni": 1024}, True),
+    pytest.param(name, sizes, cap, id=f"{name}-{'one_row' if cap else 'rows'}")
+    for name, sizes, cap in [
+        ("laplace5", {"Nj": 1024, "Ni": 1024}, None),
+        ("cosmo", {"Nk": 8, "Nj": 256, "Ni": 1024}, None),
+        ("heat3d", {"Nk": 8, "Nj": 256, "Ni": 1024}, None),
+        ("normalization", {"Nj": 1024, "Ni": 1024}, None),
+        ("hydro1d", {"Nj": 1024, "Ni": 1024}, None),
+        ("plane_sum", {"Nk": 8, "Nj": 256, "Ni": 1024}, None),
+        # one row a step, rows off the tile: the last 8-row block is partial
+        ("laplace5", {"Nj": 1020, "Ni": 1024}, 1),
+        ("heat3d", {"Nk": 8, "Nj": 254, "Ni": 1024}, 1),
+        # the second nest streams a 1023-wide intermediate
+        ("normalization", {"Nj": 1020, "Ni": 1024}, 1),
     ]
 ] + [
-    pytest.param(name, sizes, False, id=tag)
+    pytest.param(name, sizes, None, id=tag)
     for tag, name, sizes in [
         # the chip benchmark's two cells at their real shapes, as
         # R-row tiles (cosmo's 774 rows end in a partial row block)
@@ -50,7 +50,7 @@ CASES = [
         ("laplace5-rows-ragged", "laplace5", {"Nj": 1020, "Ni": 1024}),
         ("heat3d-rows-below-tile", "heat3d", {"Nk": 8, "Nj": 12, "Ni": 1024}),
         # a producer plane window stored R rows at a time
-        ("heat3d_stage-db0", "heat3d_stage",
+        ("heat3d_stage-rows", "heat3d_stage",
          {"Nk": 8, "Nj": 256, "Ni": 1024}),
         # a body whose own values outgrow the buffers: without counting
         # them the VMEM model chose R = 72 here, and Mosaic refused the
@@ -88,11 +88,13 @@ def _inputs(prog, sizes, sharding, batch=()):
     return out
 
 
-@pytest.mark.parametrize("name,sizes,double_buffer", CASES)
-def test_kernel_compiles_for_v5e(one_chip, name, sizes, double_buffer):
+@pytest.mark.parametrize("name,sizes,cap", CASES)
+def test_kernel_compiles_for_v5e(one_chip, name, sizes, cap, monkeypatch):
+    if cap is not None:
+        monkeypatch.setattr(tiling, "ROW_TILE_CAP", cap)
     prog = ALL_PROGRAMS[name]()
     gen = compile_program(prog, backend="pallas", interpret=False,
-                          double_buffer=double_buffer, use_cache=False)
+                          use_cache=False)
     compiled = jax.jit(lambda a: gen.fn(**a)).lower(
         _inputs(prog, sizes, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -152,7 +152,7 @@ def test_compile_program_spans_and_cache_counters(fresh_cache):
     # no span.  One grid step per k plane of the 5x11x132 field: its 11
     # rows fit one 16-row tile; one more step after the last plane
     # writes that plane's goal rows, which each step writes one step
-    # late.  The VMEM model's need at that tile (plancheck.call_vmem:
+    # late.  The VMEM model's need at that tile (tiling.call_vmem:
     # the 3-plane window, two stream blocks of each of input and output,
     # the output's 24-row carry buffer, 8 body values of 16 x 256) stays
     # under the default scoped limit, so no limit is passed (0).  The
