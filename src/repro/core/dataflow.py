@@ -21,6 +21,16 @@ from .rules import Extent, KernelRule, Program
 from .terms import Term
 
 
+def var_name(term: Term) -> str:
+    """The name of a term's variable, and of the array a goal on it is
+    stored to by default: its functors, innermost first, then its
+    array's name, ``_``-joined (``unew(u[k][j][i])`` -> ``unew_u``)."""
+    n = term.ref.name
+    for f in term.functors:
+        n = f"{f}_{n}"
+    return n
+
+
 def _disp_of(rap: RAP) -> dict[str, int]:
     """Displacement of a RAP instance = offsets of its anchor term."""
     anchor = rap.out_terms[0] if rap.out_terms else rap.in_terms[0]
@@ -88,10 +98,7 @@ class Var:
 
     @property
     def name(self) -> str:
-        n = self.key.ref.name
-        for f in self.key.functors:
-            n = f"{f}_{n}"
-        return n
+        return var_name(self.key)
 
 
 @dataclass

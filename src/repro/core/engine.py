@@ -51,6 +51,7 @@ the loaded IR and never invokes the analysis pipeline at all.
 """
 from __future__ import annotations
 
+import functools
 import os
 import warnings
 from collections import OrderedDict
@@ -64,8 +65,9 @@ from .codegen_jax import Generated, generate
 from .codegen_pallas import (PallasGenerated, PallasUnsupported,
                              plan_pallas)
 from .dataflow import build_dataflow
-from .fusion import fuse_inest_dag
-from .infer import infer
+from .dimorder import device_ordered, permute_program
+from .fusion import Unfusable, fuse_inest_dag
+from .infer import InferenceError, infer
 from .interpreters import (get_interpreter, registered_interpreters,
                            resolve_interpret)
 from .layoutapply import render_apply, resolve_apply_mode
@@ -240,9 +242,10 @@ def _run_plancheck(kplan: KernelPlan, mode: str, *, dtype,
         warnings.warn(str(d), PlanCheckWarning, stacklevel=3)
 
 
-def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
-               interpreter, dtype, interpret, use_cache=True, check="warn",
-               dim_sizes=None, apply_mode="off") -> PallasGenerated:
+def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan],
+               program: Optional[Program] = None, *, interpreter, dtype,
+               interpret, use_cache=True, check="warn", dim_sizes=None,
+               apply_mode="off", permuted=False) -> PallasGenerated:
     """Build (or fetch) the named registered interpreter for a finished
     kernel plan.
 
@@ -265,7 +268,14 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
     untransformed builds never share a plan-cache entry; the original
     plan is kept on the artifact (``.base_plan``) so the on-disk cache
     always persists the *untransformed* form (the pass re-runs per
-    compilation, keeping cached plans mode-agnostic)."""
+    compilation, keeping cached plans mode-agnostic).
+
+    Given the ``program`` the plan was made from, the executor runs each
+    call in the loop order the device keeps the call's inputs in
+    (:func:`repro.core.dimorder.device_ordered`): the program permuted
+    to that order is planned and built on first need
+    (:func:`_emit_permuted`), through this function with
+    ``permuted=True``, which the plan-level cache key holds."""
     spec = get_interpreter(interpreter)
     base_plan = kplan
     layout_result = None
@@ -275,7 +285,7 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
             sizes=dict(dim_sizes) if dim_sizes else None)
         kplan = layout_result.plan
     pkey = (interpreter, kplan.cache_key(), jnp.dtype(dtype).name,
-            bool(interpret) and "interpret" in spec.flags)
+            bool(interpret) and "interpret" in spec.flags, permuted)
     if use_cache:
         hit = _PLAN_CACHE.get(pkey)
         count("hfav.plan_cache.hit" if hit is not None
@@ -295,7 +305,13 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
     from .interpreters import execute_plan
     with span("hfav.emit"):
         fn = execute_plan(kplan, interpreter=interpreter, dtype=dtype,
-                          interpret=interpret)
+                          interpret=interpret, permuted=permuted)
+        if program is not None and not permuted:
+            build = functools.partial(
+                _emit_permuted, program, interpreter=interpreter,
+                dtype=dtype, interpret=interpret, use_cache=use_cache,
+                check=check, dim_sizes=dim_sizes, apply_mode=apply_mode)
+            fn = device_ordered(program, kplan, fn, build, dtype=dtype)
         gen = PallasGenerated(kplan, fn, plan, interpreter=interpreter)
     gen.base_plan = base_plan
     gen.layout_result = layout_result
@@ -304,6 +320,23 @@ def _emit_plan(kplan: KernelPlan, plan: Optional[StoragePlan], *,
         while len(_PLAN_CACHE) > _PLAN_CACHE_CAP:
             _PLAN_CACHE.popitem(last=False)
     return gen
+
+
+def _emit_permuted(program: Program, order: tuple[str, ...], **emit):
+    """The ``(plan, host half)`` of ``program`` permuted to the loop
+    order ``order`` (:func:`repro.core.dimorder.permute_program`),
+    planned and built through :func:`_emit_plan` with ``emit``'s flags;
+    ``None`` where the pipeline or the planner refuses it."""
+    try:
+        pprog = permute_program(program, order)
+        idag, plan = _build_plan(pprog)
+        with span("hfav.plan_pallas"):
+            kplan = plan_pallas(plan, idag)
+        gen = _emit_plan(kplan, plan, pprog, permuted=True, **emit)
+    except (ValueError, InferenceError, Unfusable, PallasUnsupported,
+            PlanCheckError):
+        return None
+    return gen.kernel_plan, gen.fn
 
 
 def _emit_pallas(plan, idag, *, interpreter, dtype, interpret,
@@ -316,7 +349,8 @@ def _emit_pallas(plan, idag, *, interpreter, dtype, interpret,
     construction is memoized by :func:`_emit_plan`."""
     with span("hfav.plan_pallas"):
         kplan = plan_pallas(plan, idag)
-    return _emit_plan(kplan, plan, interpreter=interpreter, dtype=dtype,
+    return _emit_plan(kplan, plan, plan.schedule.program,
+                      interpreter=interpreter, dtype=dtype,
                       interpret=interpret, use_cache=use_cache, check=check,
                       dim_sizes=dim_sizes, apply_mode=apply_mode)
 
@@ -393,7 +427,8 @@ def _pallas_auto_probe(plan, idag, *, dtype, interpret,
                                dtype_bytes=jnp.dtype(dtype).itemsize):
                 return None
     try:
-        return _emit_plan(kplan, plan, interpreter="pallas", dtype=dtype,
+        return _emit_plan(kplan, plan, plan.schedule.program,
+                          interpreter="pallas", dtype=dtype,
                           interpret=interpret, use_cache=use_cache,
                           check=check, dim_sizes=dim_sizes,
                           apply_mode=apply_mode)
@@ -542,7 +577,7 @@ def compile_program(
                                               dtype)
             kplan = _load_plan_from_disk(program, backend, plan_cache_dir)
             if kplan is not None:
-                gen = _emit_plan(kplan, None,
+                gen = _emit_plan(kplan, None, program,
                                  interpreter="pallas" if backend == "auto"
                                  else backend,
                                  dtype=dtype, interpret=interpret,

@@ -397,8 +397,24 @@ def _grid_steps(call: CallPlan, sizes: tuple[int, ...],
     return t.seats.steps, t.rows, t.vmem_need, t.vmem_limit or 0
 
 
+def resolve_sizes(kplan: KernelPlan, arrays: dict) -> dict[str, int]:
+    """Concrete ``{size symbol: int}`` of a call's ``arrays`` (the
+    program's external arrays by name), through the plan's axiom shape
+    contracts."""
+    sizes: dict[str, int] = {}
+    for ax in kplan.axioms:
+        shape = jnp.shape(arrays[ax.array])
+        ext = {d: (sym, lo, hi) for d, sym, lo, hi in ax.extents}
+        for axis, d in enumerate(ax.dims):
+            e = ext.get(d)
+            if e is not None and e[0] not in sizes:
+                sizes[e[0]] = shape[axis] - (e[2] - e[1])
+    return sizes
+
+
 def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
-                 dtype=jnp.float32, interpret: Optional[bool] = None):
+                 dtype=jnp.float32, interpret: Optional[bool] = None,
+                 permuted: bool = False):
     """Build the host callable executing a full :class:`KernelPlan` on
     the named registered interpreter.
 
@@ -422,7 +438,10 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     passed, 0 for the compiler's default), ``hfav.outputs_seated`` (the
     call's ``external`` outputs, which the interpreter writes at their
     goal seat) and ``hfav.outputs_trimmed`` (its other outputs, which
-    :func:`_assemble` trims), each added once per built call
+    :func:`_assemble` trims) and ``hfav.nest_permuted`` (1 where
+    ``permuted`` says the plan is its program's under the loop order the
+    device keeps the inputs in, :mod:`repro.core.dimorder`, else 0),
+    each added once per built call
     (:mod:`repro.trace`), mark each stencil call as ``fn``'s Python
     runs: under ``jax.jit`` once per trace, never per compiled call."""
     spec = get_interpreter(interpreter)
@@ -435,14 +454,7 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     input_names = sorted({ax.array for ax in kplan.axioms})
 
     def fn(**arrays):
-        sizes: dict[str, int] = {}
-        for ax in kplan.axioms:
-            arr = arrays[ax.array]
-            ext = {d: (sym, lo, hi) for d, sym, lo, hi in ax.extents}
-            for axis, d in enumerate(ax.dims):
-                e = ext.get(d)
-                if e is not None and e[0] not in sizes:
-                    sizes[e[0]] = arr.shape[axis] - (e[2] - e[1])
+        sizes = resolve_sizes(kplan, arrays)
         nj = sizes[dim_sym[jdim]]
         ni = sizes[dim_sym[inner]]
         n_outs = tuple(sizes[dim_sym[d]] for d in outer_dims)
@@ -465,6 +477,7 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
                 seated = sum(o.kind == "external" for o in cp.outputs)
                 count("hfav.outputs_seated", seated)
                 count("hfav.outputs_trimmed", len(cp.outputs) - seated)
+                count("hfav.nest_permuted", int(permuted))
                 with span("hfav.build_call", call=cp.name, grid_steps=steps,
                           row_tile=rows, vmem_need_bytes=need,
                           vmem_limit_bytes=limit):

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import compile_batched, compile_program, tiling
+from repro import trace
+from repro.core import compile_batched, compile_program, dimorder, tiling
 from repro.core.programs import ALL_PROGRAMS
 
 #: (program, {size symbol: int}, cap of R): the main path's programs at
@@ -121,17 +122,27 @@ CHAIN = [
 _OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\((.*)$")
 
 
+@pytest.fixture
+def chip_layouts(one_chip, monkeypatch):
+    """The host half follows the described chip's default layouts, as
+    it follows the attached chip's on a TPU host."""
+    device, = one_chip.device_set
+    monkeypatch.setattr(dimorder, "layout_device", lambda: device)
+
+
 @pytest.mark.parametrize("name,sizes", CHAIN)
-def test_donated_chain_link_holds_no_glue_for_v5e(one_chip, name, sizes):
+def test_donated_chain_link_holds_no_glue_for_v5e(one_chip, chip_layouts,
+                                                  name, sizes):
     """One link of the benchmark's sweep chain, built as
     ``bench/generators/sweep.py`` ``_chained`` builds it (the previous
     output donated, one element returned as a marker), compiles to the
     kernel alone: no ``pad`` or ``dynamic-update-slice``, no ``slice``
-    but the one-element marker, and no ``copy`` that keeps its layout.
-    cosmo1's input and output each take one ``copy`` that changes the
-    layout: the device keeps ``f32[80,774,1158]`` with the 774-row dim
-    major (no row padding), and the Mosaic kernel takes row-major
-    arrays."""
+    but the one-element marker, and no ``copy``.  The device keeps
+    cosmo1's ``f32[80,774,1158]`` with the 774-row dim major (no row
+    padding), so the host half runs cosmo's nest with ``j`` outer and
+    hands the kernel transposed views, which are bitcasts; a nest in the
+    array's own order took one layout-changing ``copy`` in and one
+    out."""
     prog = ALL_PROGRAMS[name]()
     gen = compile_program(prog, backend="pallas", interpret=False, use_cache=False)
     inputs = _inputs(prog, sizes, one_chip)
@@ -147,20 +158,29 @@ def test_donated_chain_link_holds_no_glue_for_v5e(one_chip, name, sizes):
     text = jax.jit(link, donate_argnums=1, keep_unused=True).lower(
         inputs, shapes).compile().as_text()
     assert "tpu_custom_call" in text
-    types, copies = {}, []
     for line in text.splitlines():
         m = _OP.match(line)
         if not m:
             continue
-        op_name, rtype, opcode, rest = m.groups()
-        types[op_name] = rtype
+        _, rtype, opcode, _ = m.groups()
         assert opcode not in ("pad", "dynamic-update-slice"), line
+        assert not opcode.startswith("copy"), line
         if opcode == "slice":
             dims = re.match(r"\w+\[([\d,]*)\]", rtype).group(1)
             assert math.prod(int(d) for d in dims.split(",") if d) == 1, line
-        if opcode.startswith("copy"):
-            copies.append((rtype, rest.split(")")[0].lstrip("%")))
-    for rtype, operand in copies:
-        layout = re.search(r"\{[\d,]*", rtype).group(0)
-        assert layout != re.search(r"\{[\d,]*", types[operand]).group(0), (rtype, operand)
-    assert len(copies) == (2 if name == "cosmo" else 0)
+
+
+def test_permuted_cosmo1_call_compiles_for_v5e(one_chip, chip_layouts):
+    """cosmo1's nest in the device's dim order: ``j`` outer, the 80 k
+    rows one row tile of R = 80, 774 planes and one more grid step."""
+    prog = ALL_PROGRAMS["cosmo"]()
+    gen = compile_program(prog, backend="pallas", interpret=False,
+                          use_cache=False)
+    sizes = {"Nk": 80, "Nj": 774, "Ni": 1158}
+    with trace.recording() as rec:
+        compiled = jax.jit(lambda a: gen.fn(**a)).lower(
+            _inputs(prog, sizes, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert rec.counters["hfav.nest_permuted"] == 1
+    assert rec.counters["hfav.row_tile"] == 80
+    assert rec.counters["hfav.grid_steps"] == 775

@@ -168,6 +168,28 @@ def test_compile_program_spans_and_cache_counters(fresh_cache):
     assert rec.counters["hfav.vmem_limit_bytes"] == 0
     assert rec.counters["hfav.outputs_seated"] == 1
     assert rec.counters["hfav.outputs_trimmed"] == 0
+    assert rec.counters["hfav.nest_permuted"] == 0
+
+
+@pytest.mark.parametrize("m2m,permuted", [((1, 0, 2), 1), ((0, 1, 2), 0)],
+                         ids=["j-major", "row-major"])
+def test_nest_permuted_counter(fresh_cache, monkeypatch, m2m, permuted):
+    """``hfav.nest_permuted`` reads 1 where the device keeps cosmo's field
+    with its j dim major (the layout query patched to say so, as a TPU
+    v5e says of cosmo1's 80 x 774 x 1158 field), and 0 where it keeps
+    it row-major, as every device off the TPU does."""
+    from repro.core import dimorder
+    from repro.core.programs import cosmo_program
+
+    monkeypatch.setattr(dimorder, "device_major_to_minor",
+                        lambda shape, dtype: m2m)
+    gen = compile_program(cosmo_program(), backend="pallas")
+    u = jnp.asarray(np.random.default_rng(0).standard_normal((16, 21, 150)),
+                    jnp.float32)
+    with trace.recording() as rec:
+        jax.block_until_ready(jax.jit(lambda a: gen.fn(u=a))(u))
+    assert rec.counters["hfav.nest_permuted"] == permuted
+    assert rec.counters["hfav.outputs_seated"] == 1
 
 
 def test_plan_disk_spans_and_hits(fresh_cache, tmp_path):
