@@ -438,7 +438,10 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
     passed, 0 for the compiler's default), ``hfav.outputs_seated`` (the
     call's ``external`` outputs, which the interpreter writes at their
     goal seat) and ``hfav.outputs_trimmed`` (its other outputs, which
-    :func:`_assemble` trims) and ``hfav.nest_permuted`` (1 where
+    :func:`_assemble` trims), ``hfav.accumulators`` (the call's carried
+    accumulator rows), ``hfav.outputs_lane_folded`` (its ``acc`` and
+    ``acc_rows`` outputs whose lanes :func:`_assemble` folds on the host)
+    and ``hfav.nest_permuted`` (1 where
     ``permuted`` says the plan is its program's under the loop order the
     device keeps the inputs in, :mod:`repro.core.dimorder`, else 0),
     each added once per built call
@@ -477,6 +480,9 @@ def execute_plan(kplan: KernelPlan, *, interpreter: str = "pallas",
                 seated = sum(o.kind == "external" for o in cp.outputs)
                 count("hfav.outputs_seated", seated)
                 count("hfav.outputs_trimmed", len(cp.outputs) - seated)
+                count("hfav.accumulators", len(cp.accs))
+                count("hfav.outputs_lane_folded",
+                      sum(o.reduce_idx is not None for o in cp.outputs))
                 count("hfav.nest_permuted", int(permuted))
                 with span("hfav.build_call", call=cp.name, grid_steps=steps,
                           row_tile=rows, vmem_need_bytes=need,

@@ -18,6 +18,10 @@
   a seven-kernel chain (primitive conversion, EOS, slope, trace, interface
   state, flux, update) over one conserved variable, with a toy interface
   state in place of a Riemann solver.  It is not Hydro2D.
+* :func:`mg_resid_program` — NPB MG's finest-level residual ``r = v - A u``
+  (the 27-point ``A`` of ``mg.f``'s ``resid``) and its two norms
+  (``norm2u3``): box reads of ``u`` out of a plane window beside a second
+  input, and two carried accumulators in one call.
 
 Executor coverage programs (one per lifted Pallas restriction — see
 docs/BACKENDS.md):
@@ -372,6 +376,94 @@ def heat3d_residual_norm_program(name: str = "heat3d_residual_norm") -> Program:
                  k=("Nk", 1, -1), j=("Nj", 1, -1), i=("Ni", 1, -1)),
             goal("rnorm(u)", store_as="rnorm"),
         ],
+        loop_order=("k", "j", "i"),
+        name=name,
+    )
+
+
+#: NPB MG's residual operator ``A``: ``a(0..3)`` of ``mg.f``, the weight
+#: of the centre, a face, an edge and a corner neighbour.  ``a(1)`` is 0,
+#: so ``resid`` leaves the six face reads out.
+MG_A = (-8.0 / 3.0, 0.0, 1.0 / 6.0, 1.0 / 12.0)
+#: Grid points of NPB MG class C's finest level, 512^3: ``norm2u3``
+#: divides the sum of squares by them.
+MG_C_POINTS = 512 ** 3
+
+
+def _mg_resid(v, c, *nb):
+    """``mg.f``'s ``resid`` at one point, grouped as ``mg.f`` groups it:
+    ``nb`` holds ``u2(i)``'s four ``(j, k)`` edges, then the face sums
+    ``u1(i-1)`` and ``u1(i+1)`` (four edges each), then the corner sums
+    ``u2(i-1)`` and ``u2(i+1)``."""
+    def four(t):
+        return t[0] + t[1] + t[2] + t[3]
+
+    edges = four(nb[0:4]) + four(nb[4:8]) + four(nb[8:12])
+    corners = four(nb[12:16]) + four(nb[16:20])
+    return v - MG_A[0] * c - MG_A[2] * edges - MG_A[3] * corners
+
+
+def _abs1(a):
+    return jnp.abs(a)
+
+
+def _max2(acc, x):
+    return jnp.maximum(acc, x)
+
+
+def _mg_rnm2(s):
+    return jnp.sqrt(s / MG_C_POINTS)
+
+
+def _mg_reads(i_off: int, pairs) -> list:
+    """``u`` reads at lane offset ``i_off`` and each ``(j, k)`` offset."""
+    def at(d, off):
+        return f"{d}?{off:+d}" if off else f"{d}?"
+
+    return [f"u[{at('k', dk)}][{at('j', dj)}][{at('i', i_off)}]"
+            for dj, dk in pairs]
+
+
+def mg_resid_program(name: str = "mg_resid") -> Program:
+    """NPB MG's finest-level residual and its norms (``resid`` and
+    ``norm2u3`` of ``mg.f``): ``r = v - A u`` on the interior of every
+    dim, with the 27-point ``A`` of :data:`MG_A` (the centre, 12 edges
+    and 8 corners live), then ``rnm2 = sqrt(sum r^2 / 512^3)`` and
+    ``rnmu = max |r|`` over that interior.
+
+    ``u`` streams through a plane window whose ``k +/- 1`` planes serve
+    the ``j +/- 1`` rows and ``i +/- 1`` lanes, beside a second input,
+    ``v``; the two reductions are carried accumulators of one call,
+    and the square root runs on the host after the lane fold."""
+    diag = [(-1, -1), (1, -1), (-1, 1), (1, 1)]   # u2: j and k both off
+    face = [(-1, 0), (1, 0), (0, -1), (0, 1)]     # u1: j or k off
+    reads = (_mg_reads(0, diag) + _mg_reads(-1, face) + _mg_reads(1, face)
+             + _mg_reads(-1, diag) + _mg_reads(1, diag))
+    k_resid = kernel(
+        "resid",
+        inputs=[("v", "v[k?][j?][i?]"), ("c", "u[k?][j?][i?]")]
+        + [(f"n{n}", r) for n, r in enumerate(reads)],
+        outputs=[("r", "r(u[k?][j?][i?])")],
+        fn=_mg_resid,
+    )
+    k_sq = kernel("rsq", [("a", "r(u[k?][j?][i?])")],
+                  [("o", "rsq(u[k?][j?][i?])")], fn=_sq1)
+    k_abs = kernel("rabs", [("a", "r(u[k?][j?][i?])")],
+                   [("o", "rabs(u[k?][j?][i?])")], fn=_abs1)
+    k_sum = kernel("rsq_sum", [("x", "rsq(u[k][j][i])")],
+                   [("acc", "ssq(u)")], fn=_sum2, kind="reduce", init=0.0)
+    k_max = kernel("rabs_max", [("x", "rabs(u[k][j][i])")],
+                   [("acc", "rnmu(u)")], fn=_max2, kind="reduce", init=0.0)
+    k_rnm2 = kernel("rnm2", [("s", "ssq(u)")], [("o", "rnm2(u)")],
+                    fn=_mg_rnm2)
+    return Program(
+        rules=[k_resid, k_sq, k_abs, k_sum, k_max, k_rnm2],
+        axioms=[axiom(f"{a}[k?][j?][i?]", k="Nk", j="Nj", i="Ni")
+                for a in ("u", "v")],
+        goals=[goal("r(u[k][j][i])", store_as="r",
+                    k=("Nk", 1, -1), j=("Nj", 1, -1), i=("Ni", 1, -1)),
+               goal("rnm2(u)", store_as="rnm2"),
+               goal("rnmu(u)", store_as="rnmu")],
         loop_order=("k", "j", "i"),
         name=name,
     )
@@ -979,4 +1071,5 @@ ALL_PROGRAMS = {
     "cosmo": cosmo_program,
     "hydro1d": hydro1d_program,
     "hydro2d": hydro2d_program,
+    "mg_resid": mg_resid_program,
 }

@@ -346,7 +346,7 @@ def test_pc008_interpreter_capability_mismatch():
 def test_cli_goldens_exit_zero():
     res = _run_lint(str(GOLDEN_DIR), "-q")
     assert res.returncode == 0, res.stdout + res.stderr
-    assert "16 target(s), 0 error(s), 0 warning(s)" in res.stdout
+    assert "17 target(s), 0 error(s), 0 warning(s)" in res.stdout
 
 
 @pytest.mark.slow

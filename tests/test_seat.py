@@ -26,7 +26,7 @@ from repro.core.unfused import build_unfused
 
 #: program -> (outputs the kernel seats, outputs the host trims)
 COUNTS = {"cosmo": (1, 0), "heat3d": (1, 0), "hydro2d": (4, 0),
-          "heat3d_residual_norm": (1, 1)}
+          "heat3d_residual_norm": (1, 1), "mg_resid": (1, 2)}
 
 #: mode -> (cap of R, Nj): R-row steps over 37 rows (two 16-row steps
 #: and a ragged 5-row one), and one row a step over 21 rows (two 8-row
@@ -36,7 +36,9 @@ MODES = {"rows": (16, 37), "one_row": (1, 21)}
 CASES = [pytest.param(name, mode, id=f"{name}-{mode}")
          for name in ("cosmo", "heat3d", "hydro2d") for mode in MODES] + [
     # an accumulator keeps R = 1 beside a seated goal
-    pytest.param("heat3d_residual_norm", "rows", id="heat3d_residual_norm")]
+    pytest.param("heat3d_residual_norm", "rows", id="heat3d_residual_norm"),
+    # two accumulators beside a goal read through a box plane window
+    pytest.param("mg_resid", "rows", id="mg_resid")]
 
 #: ops that trim or re-seat an array outside the kernel
 GLUE = {"pad", "slice", "dynamic_slice", "dynamic_update_slice", "scatter"}

@@ -57,6 +57,10 @@ CASES = [
         # them the VMEM model chose R = 72 here, and Mosaic refused the
         # kernel (an 18.67 MiB scoped allocation over the 16 MiB limit)
         ("hydro2d-1028sq", "hydro2d", {"Nj": 1028, "Ni": 1028}),
+        # NPB MG class C's cell: box reads out of a plane window beside a
+        # second input, two carried accumulators, one row a step, and
+        # 514 lanes (4 * 128 + 2)
+        ("npb-mg-c-514cubed", "mg_resid", {"Nk": 514, "Nj": 514, "Ni": 514}),
     ]
 ]
 

@@ -168,7 +168,34 @@ def test_compile_program_spans_and_cache_counters(fresh_cache):
     assert rec.counters["hfav.vmem_limit_bytes"] == 0
     assert rec.counters["hfav.outputs_seated"] == 1
     assert rec.counters["hfav.outputs_trimmed"] == 0
+    assert rec.counters["hfav.accumulators"] == 0
+    assert rec.counters["hfav.outputs_lane_folded"] == 0
     assert rec.counters["hfav.nest_permuted"] == 0
+
+
+@pytest.mark.parametrize("name,accs,folded", [
+    ("energy3d", 1, 1),   # one row carried across the whole grid
+    ("plane_sum", 1, 1),  # one row re-initialized per k tile
+    ("row_sum", 0, 1),    # a partial row per grid step, no carried row
+    ("mg_resid", 2, 2),   # two carried rows in one call
+])
+def test_accumulator_counters(fresh_cache, name, accs, folded):
+    """``hfav.accumulators`` counts a traced call's carried accumulator
+    rows and ``hfav.outputs_lane_folded`` the outputs whose lanes the
+    host half folds."""
+    from repro.core.programs import ALL_PROGRAMS
+
+    prog = ALL_PROGRAMS[name]()
+    gen = compile_program(prog, backend="pallas")
+    rng = np.random.default_rng(1)
+    shape = {2: (9, 130), 3: (4, 9, 130)}
+    arrs = {ax.term.ref.name: jnp.asarray(
+        rng.standard_normal(shape[len(ax.term.ref.dims)]), jnp.float32)
+        for ax in prog.axioms}
+    with trace.recording() as rec:
+        jax.make_jaxpr(lambda a: gen.fn(**a))(arrs)
+    assert rec.counters["hfav.accumulators"] == accs
+    assert rec.counters["hfav.outputs_lane_folded"] == folded
 
 
 @pytest.mark.parametrize("m2m,permuted", [((1, 0, 2), 1), ((0, 1, 2), 0)],

@@ -80,7 +80,7 @@ def _codes(rep: VecReport) -> set:
 
 def test_golden_corpus_classifies_totally():
     goldens = sorted(GOLDEN_DIR.glob("*.json"))
-    assert len(goldens) == 16
+    assert len(goldens) == 17
     for path in goldens:
         kp = KernelPlan.from_dict(json.loads(path.read_text()))
         rep = scan_plan(kp)
@@ -333,7 +333,7 @@ def test_plan_lint_vec_json_over_goldens():
         capture_output=True, text=True, cwd=ROOT, env=env)
     assert out.returncode == 0, out.stderr
     records = [json.loads(line) for line in out.stdout.splitlines()]
-    assert len(records) == 16
+    assert len(records) == 17
     baseline = json.loads(
         (ROOT / "tests" / "goldens" /
          "vec_lint_baseline.json").read_text())["errors"]
